@@ -36,6 +36,7 @@ import argparse
 import dataclasses
 import json
 import sys
+from functools import partial
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -46,7 +47,7 @@ from repro.configs import ANALYSIS_SMOKE_CONFIGS, ARCH_IDS, get_config
 from repro.kernels.quant_matmul.ops import force_impl
 from repro.models.config import ModelConfig
 from repro.models.model import decode_many_batched, init_decode_state, \
-    init_params, prefill, quantize_model
+    init_quantized_params, prefill
 from repro.quant.qtensor import QuantizedTensor
 from repro.serving.scheduler import live_cap_for
 
@@ -83,10 +84,11 @@ def _mix_label(cfg: ModelConfig) -> str:
 
 
 def _abstract_state(cfg: ModelConfig) -> Tuple[Any, Any]:
-    """(params, qparams) as ShapeDtypeStruct pytrees — full size, 0 bytes."""
-    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    qparams = jax.eval_shape(lambda p: quantize_model(p, cfg), params)
-    return params, qparams
+    """(params, qparams) as ShapeDtypeStruct pytrees — full size, 0 bytes
+    — as the engine holds them: no dense routed experts beside the packed
+    store."""
+    return jax.eval_shape(
+        lambda: init_quantized_params(cfg, jax.random.PRNGKey(0)))
 
 
 def forbidden_shapes_from_qparams(qparams) -> frozenset:
@@ -166,12 +168,15 @@ def _abstract_mesh():
     partitioned programs on any backend — same zero-allocation property
     as the rest of the linter."""
     from jax.sharding import AbstractMesh
-    return AbstractMesh((("data", 1), ("model", _SHARD_N)))
+    return AbstractMesh((1, _SHARD_N), ("data", "model"))
 
 
 def _trace_sharded(cfg, params, qparams, f, extra_avals, extra_shardings):
-    """Trace ``f(params, qparams, *extras)`` jitted with expert-parallel
-    param/qparam shardings over the abstract mesh."""
+    """Trace ``f(ep_cfg, params, qparams, *extras)`` jitted with
+    expert-parallel param/qparam shardings over the abstract mesh;
+    ``ep_cfg`` carries that mesh as ``expert_mesh``, as an
+    expert-parallel engine's programs do, so the MoE layers run their
+    kernels under shard_map over the expert axis."""
     from jax.sharding import NamedSharding, PartitionSpec as P
     from repro.sharding.partition import param_shardings
 
@@ -181,15 +186,17 @@ def _trace_sharded(cfg, params, qparams, f, extra_avals, extra_shardings):
              param_shardings(qparams, mesh, expert_parallel=True),
              *(repl if s is None else s(mesh)
                for s in extra_shardings))
-    jf = jax.jit(f, in_shardings=in_sh)
+    jf = jax.jit(partial(f, dataclasses.replace(cfg, expert_mesh=mesh)),
+                 in_shardings=in_sh)
     return _trace(jf, params, qparams, *extra_avals)
 
 
 def _trace_prefill_ep(cfg, params, qparams):
     toks = _sds((1, _PREFILL_S), jnp.int32)
 
-    def f(p, q, tok):
-        return prefill(p, cfg, tok, qparams=q, cache_slots=_DECODE_SLOTS)
+    def f(ep_cfg, p, q, tok):
+        return prefill(p, ep_cfg, tok, qparams=q,
+                       cache_slots=_DECODE_SLOTS)
 
     return _trace_sharded(cfg, params, qparams, f, (toks,), (None,))
 
@@ -204,16 +211,16 @@ def _trace_decode_chunk_ep(cfg, params, qparams):
     done = _sds((b,), jnp.bool_)
     counts = _sds((b,), jnp.int32)
 
-    def f(p, q, tok, cch, dn, em, lim, eos):
+    def f(ep_cfg, p, q, tok, cch, dn, em, lim, eos):
         return decode_many_batched(
-            p, cfg, tok, cch, num_steps=_DECODE_CHUNK, done=dn,
+            p, ep_cfg, tok, cch, num_steps=_DECODE_CHUNK, done=dn,
             n_emitted=em, limits=lim, eos_tokens=eos, qparams=q,
             live_cap=live_cap_for(b, b))
 
     return _trace_sharded(
         cfg, params, qparams, f,
         (toks, caches, done, counts, counts, counts),
-        (None, lambda m: cache_shardings(caches, m),
+        (None, lambda m: cache_shardings(caches, m, expert_parallel=True),
          None, None, None, None))
 
 

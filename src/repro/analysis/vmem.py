@@ -94,11 +94,11 @@ def estimate_pallas_vmem(eqn: Any) -> Optional[PallasVmemEstimate]:
     block_total = 0
     blocks: List[tuple] = []
     for bm in gm.block_mappings:
-        sd = bm.array_shape_dtype
-        b = _bytes(bm.block_shape, sd.dtype)
+        dtype = bm.array_aval.dtype
+        b = _bytes(bm.block_shape, dtype)
         block_total += b
         blocks.append((tuple(_dim(d) for d in bm.block_shape),
-                       jnp.dtype(sd.dtype).name, b))
+                       jnp.dtype(dtype).name, b))
 
     # kernel jaxpr invars: [scalar-prefetch..., in blocks..., out blocks...,
     # scratch...] — scratch avals (accumulators) come from the tail,

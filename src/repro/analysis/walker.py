@@ -21,7 +21,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable, Iterator, List, Optional, Tuple
 
-import jax
+from jax.extend import core as jex_core
 
 __all__ = ["EqnSite", "subjaxprs", "iter_eqns", "intermediate_avals",
            "count_primitive", "count_pallas_calls", "find_eqns"]
@@ -52,10 +52,9 @@ class EqnSite:
 
 
 def _as_jaxpr(v: Any) -> Optional[Any]:
-    core = jax.core
-    if isinstance(v, core.ClosedJaxpr):
+    if isinstance(v, jex_core.ClosedJaxpr):
         return v.jaxpr
-    if isinstance(v, core.Jaxpr):
+    if isinstance(v, jex_core.Jaxpr):
         return v
     return None
 

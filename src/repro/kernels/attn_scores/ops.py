@@ -16,10 +16,7 @@ __all__ = ["flash_attention_with_scores"]
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def flash_attention_with_scores(q: jnp.ndarray, k: jnp.ndarray,

@@ -141,6 +141,15 @@ def _grouped_skip_kernel(nb_ref, x_ref, hp_ref, hs_ref, o_ref, acc_ref, *,
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _row_block(block_m: int, rows: int, *dtypes) -> int:
+    """Row tile of the M axis: at most ``block_m`` (rounded up), no taller
+    than ``rows`` needs, and a whole number of sublane tiles of every
+    dtype that rides in it (8 rows of f32, 16 of bf16). Mosaic requires
+    that of a block's second-minor dim; the wrappers zero-pad M up to it."""
+    sub = max(32 // jnp.dtype(d).itemsize for d in dtypes)
+    return -(-min(block_m, rows) // sub) * sub
+
+
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
     pad = (-x.shape[axis]) % mult
     if pad == 0:
@@ -183,7 +192,8 @@ def expert_quant_matmul_pallas(
         assert lo_packed.shape == (e, n, k // vpb_lo)
         assert lo_scales.shape == (e, k // group_size, n)
 
-    bm, bn, bk = min(block_m, m), min(block_n, n), min(block_k, k)
+    bm = _row_block(block_m, m, x.dtype, out_dtype)
+    bn, bk = min(block_n, n), min(block_k, k)
     bk = max(group_size, (bk // group_size) * group_size)
     assert k % group_size == 0, (k, group_size)
 
@@ -314,7 +324,8 @@ def expert_quant_matmul_grouped_pallas(
     p_ = 2 if has_lo else 1
 
     cap = max(cap_hi, cap_lo)
-    bm, bn, bk = min(block_m, cap), min(block_n, n), min(block_k, k)
+    bm = _row_block(block_m, cap, x.dtype, out_dtype)
+    bn, bk = min(block_n, n), min(block_k, k)
     bk = max(group_size, (bk // group_size) * group_size)
     assert k % group_size == 0, (k, group_size)
 

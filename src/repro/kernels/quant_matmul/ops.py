@@ -34,10 +34,7 @@ __all__ = ["quant_matmul", "expert_quant_matmul",
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except RuntimeError:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 _FORCED_IMPL: Optional[str] = None

@@ -33,25 +33,25 @@ __all__ = ["quant_matmul_pallas"]
 
 def _unpack_dequant(packed_tile: jnp.ndarray, scales_tile: jnp.ndarray,
                     bits: int, group_size: int) -> jnp.ndarray:
-    """(bn, bk/vpb) uint8 codes + (bk/gs, bn) scales -> (bk, bn) f32 weights."""
-    bn, bkp = packed_tile.shape
+    """(bn, bk/vpb) uint8 codes + (bk/gs, bn) scales -> (bk, bn) f32 weights.
+
+    The codes are transposed first, so K runs along sublanes and N stays
+    on the 128 lanes: unpacking interleaves whole sublane rows and the
+    per-group scale is a sublane split ``(g, gs, bn)``. No step splits or
+    merges the lane dim, which Mosaic cannot relayout.
+    """
     offset = 1 << (bits - 1)
-    if bits == 8:
-        q = packed_tile.astype(jnp.int32) - offset  # (bn, bk)
-    else:
-        vpb = 8 // bits
+    codes = packed_tile.astype(jnp.int32).T                   # (bk/vpb, bn)
+    bkp, bn = codes.shape
+    vpb = 8 // bits
+    if vpb > 1:
         mask = (1 << bits) - 1
-        parts = [
-            ((packed_tile >> (bits * j)) & mask).astype(jnp.int32)
-            for j in range(vpb)
-        ]
-        q = jnp.stack(parts, axis=-1).reshape(bn, bkp * vpb) - offset
-    bk = q.shape[-1]
+        parts = [(codes >> (bits * j)) & mask for j in range(vpb)]
+        codes = jnp.stack(parts, axis=1).reshape(bkp * vpb, bn)
+    bk = bkp * vpb
     g = bk // group_size
-    qg = q.reshape(bn, g, group_size).astype(jnp.float32)
-    s = scales_tile.T.reshape(bn, g, 1)  # (bn, g, 1)
-    w = (qg * s).reshape(bn, bk)
-    return w.T  # (bk, bn)
+    q = (codes - offset).astype(jnp.float32).reshape(g, group_size, bn)
+    return (q * scales_tile[:, None, :]).reshape(bk, bn)
 
 
 def _kernel(x_ref, p_ref, s_ref, o_ref, acc_ref, *, bits, group_size, nk):

@@ -34,6 +34,7 @@ from repro.launch.mesh import make_production_mesh
 from repro.models.config import ModelConfig
 from repro.models.model import (
     decode_step,
+    drop_dense_experts,
     init_decode_state,
     init_params,
     loss_fn,
@@ -81,13 +82,12 @@ def shape_adapted_config(cfg: ModelConfig, shape: str) -> ModelConfig:
 def strip_expert_weights(params_tree, cfg: ModelConfig):
     """Serving keeps experts ONLY in the quantized store (the paper's whole
     point); drop the bf16 masters from the serve-step inputs."""
-    params_tree = dict(params_tree)
-    layers = dict(params_tree["layers"])
     kind = cfg.block_kinds()[0]
     if kind == "attn_moe":
-        layers["moe"] = {k: v for k, v in layers["moe"].items()
-                         if k not in ("w_gate", "w_up", "w_down")}
-    elif kind == "attn_dense":
+        return drop_dense_experts(params_tree)
+    params_tree = dict(params_tree)
+    layers = dict(params_tree["layers"])
+    if kind == "attn_dense":
         layers["mlp"] = {}
     else:
         layers["ssm"] = {k: v for k, v in layers["ssm"].items()

@@ -9,21 +9,21 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
 
-__all__ = ["make_production_mesh", "make_local_mesh", "make_sim_mesh",
-           "ensure_sim_devices"]
+__all__ = ["make_production_mesh", "make_local_mesh", "make_chip_mesh",
+           "make_sim_mesh", "ensure_sim_devices"]
 
 _SIM_FLAG = "--xla_force_host_platform_device_count"
 
 
 def _jax_backend_initialized() -> bool:
     """True once any XLA backend has been created (after which
-    ``xla_force_host_platform_device_count`` can no longer take effect)."""
-    try:
-        from jax._src import xla_bridge
-        return bool(xla_bridge._backends)
-    except Exception:          # private API moved — assume initialized
-        return True
+    ``xla_force_host_platform_device_count`` can no longer take effect).
+    Reads a private JAX field: if it moves, this fails loudly instead of
+    guessing."""
+    from jax._src import xla_bridge
+    return bool(xla_bridge._backends)
 
 
 def ensure_sim_devices(n: int) -> bool:
@@ -49,17 +49,37 @@ def ensure_sim_devices(n: int) -> bool:
     return pinned >= n
 
 
+def _auto_mesh(shape, axes, devices=None):
+    """``jax.make_mesh`` with every axis ``AxisType.Auto``: the partitioner
+    propagates shardings from the placed arguments, as the sharding rules
+    in ``repro.sharding.partition`` assume (``make_mesh`` now defaults to
+    ``Explicit`` axes, which type-check shardings instead)."""
+    return jax.make_mesh(shape, axes, (AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     """16×16 single pod (256 chips) or 2×16×16 two-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_local_mesh():
     """1×1 mesh over the real local device(s) — for smoke tests."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
+
+
+def make_chip_mesh(n: int):
+    """(1, n) ("data", "model") mesh over the first ``n`` accelerator
+    chips — the expert-parallel serving layout. Raises when fewer than
+    ``n`` devices are visible."""
+    avail = len(jax.devices())
+    if avail < n:
+        raise RuntimeError(f"make_chip_mesh({n}) needs {n} devices but "
+                           f"only {avail} are visible")
+    return _auto_mesh((1, n), ("data", "model"), jax.devices()[:n])
 
 
 def make_sim_mesh(n: int):
@@ -86,4 +106,4 @@ def make_sim_mesh(n: int):
             f"start. Refusing to degrade to a {avail}-device mesh: its "
             f"shardings would all guard down to replication and the "
             f"sharded code paths would silently not be exercised.")
-    return jax.make_mesh((1, n), ("data", "model"))
+    return _auto_mesh((1, n), ("data", "model"), jax.devices()[:n])

@@ -49,13 +49,16 @@ from __future__ import annotations
 import argparse
 import dataclasses
 import json
+import sys
 import time
 
 import jax
 
 from repro.configs import get_config
-from repro.launch.mesh import ensure_sim_devices, make_sim_mesh
-from repro.models import init_params
+from repro.launch.compile_cache import enable_compile_cache
+from repro.launch.mesh import ensure_sim_devices, make_chip_mesh, \
+    make_sim_mesh
+from repro.models import init_params, init_quantized_params
 from repro.models.config import DyMoEPolicy
 from repro.serving import ClusterRouter, DyMoEEngine, EngineConfig, \
     Request, SamplingParams, submit_with_retry
@@ -116,9 +119,12 @@ def main() -> None:
     ap.add_argument("--no-prefetch", action="store_true")
     args = ap.parse_args()
 
+    enable_compile_cache()
     mesh = None
     if args.expert_parallel:
-        # must happen before the first jax init for the flag to count
+        # CPU only: simulated host devices for the mesh. The flag must be
+        # set before the first jax init, and a TPU backend ignores it (the
+        # mesh is then made of the real chips)
         ensure_sim_devices(4)
 
     cfg = get_config(args.arch)
@@ -130,15 +136,21 @@ def main() -> None:
         retention=args.retention)
     cfg = dataclasses.replace(cfg, dymoe=pol)
     if args.expert_parallel:
-        mesh = make_sim_mesh(len(jax.devices()))
-    params = init_params(cfg, jax.random.PRNGKey(0))
+        make = make_sim_mesh if jax.default_backend() == "cpu" \
+            else make_chip_mesh
+        mesh = make(len(jax.devices()))
+    qparams = None
+    if args.mode == "off":
+        params = init_params(cfg, jax.random.PRNGKey(0))
+    else:   # packed stores built layer by layer, no dense expert stack
+        params, qparams = init_quantized_params(cfg, jax.random.PRNGKey(0))
     engine = DyMoEEngine(cfg, params, EngineConfig(
         profile=EdgeProfile().with_vram(args.vram_gb),
         use_dymoe=args.mode != "off",
         enable_cache=not args.no_cache,
         enable_prefetch=not args.no_prefetch,
         enable_dyquant=args.mode != "off"),
-        mesh=mesh, expert_parallel=args.expert_parallel)
+        mesh=mesh, expert_parallel=args.expert_parallel, qparams=qparams)
     sampling = SamplingParams(temperature=args.temperature,
                               top_k=args.top_k, seed=args.seed)
 
@@ -231,6 +243,10 @@ def main() -> None:
         n_devices=len(jax.devices()),
         health=dataclasses.asdict(health),
         requests=[row(h) for h in handles]), indent=2))
+    failed = [h.request_id for h in handles if h.error is not None]
+    if failed:
+        sys.exit(f"{len(failed)} request(s) resolved with an error: "
+                 f"{', '.join(failed)}")
 
 
 if __name__ == "__main__":

@@ -4,6 +4,8 @@ model definitions in functional JAX (pure pytrees, no framework deps).
 from repro.models.config import ModelConfig, DyMoEPolicy
 from repro.models.model import (
     init_params,
+    init_quantized_params,
+    drop_dense_experts,
     quantize_model,
     forward,
     loss_fn,
@@ -20,6 +22,8 @@ __all__ = [
     "ModelConfig",
     "DyMoEPolicy",
     "init_params",
+    "init_quantized_params",
+    "drop_dense_experts",
     "quantize_model",
     "forward",
     "loss_fn",

@@ -8,7 +8,7 @@ restriction).
 from __future__ import annotations
 
 import dataclasses
-from typing import Optional, Tuple
+from typing import Any, Optional, Tuple
 
 __all__ = ["ModelConfig", "DyMoEPolicy"]
 
@@ -72,6 +72,11 @@ class ModelConfig:
                                      # into this many shards so capacity
                                      # buffers shard along the data axis
     moe_dispatch_axes: Tuple[str, ...] = ()  # mesh axes of those shards
+    expert_mesh: Any = None          # expert parallelism: a (Abstract)Mesh
+                                     # whose "model" axis holds the routed
+                                     # experts; the quantized expert FFN
+                                     # then runs under shard_map over it
+                                     # (set by an expert-parallel engine)
     scan_layers: bool = True         # lax.scan over the stacked layers; the
                                      # dry-run also compiles an UNROLLED
                                      # shallow copy to recover per-layer

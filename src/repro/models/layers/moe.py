@@ -33,6 +33,7 @@ import jax.numpy as jnp
 from repro.models.config import ModelConfig
 from repro.quant.mixed import mixed_precision_matmul
 from repro.quant.qtensor import MixedPrecisionWeights
+from repro.sharding.partition import MODEL_AXIS
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_rows",
            "moe_apply_prefill_rows", "moe_apply_sharded", "quantize_moe",
@@ -122,6 +123,23 @@ def _moe_blocks(cfg: ModelConfig) -> dict:
                 block_k=pol.block_k)
 
 
+def _over_local_experts(mesh, f, *args):
+    """``f(*args)`` where every leaf of ``args`` has a leading expert dim E.
+
+    With an expert-parallel ``mesh`` (``ModelConfig.expert_mesh``), ``f``
+    runs under ``shard_map`` over its ``"model"`` axis: each device applies
+    the expert kernels to its own experts' slice of the capacity buffer,
+    next to its own shard of the packed stores. A Pallas kernel cannot be
+    partitioned by the compiler, which would otherwise gather every
+    expert's packed codes onto every device. Only the (E, M, dm)
+    activations cross chips. With ``mesh=None`` ``f`` runs as is."""
+    if mesh is None:
+        return f(*args)
+    spec = jax.sharding.PartitionSpec(MODEL_AXIS)
+    return jax.shard_map(f, mesh=mesh, in_specs=(spec,) * len(args),
+                         out_specs=spec, check_vma=False)(*args)
+
+
 def _expert_ffn_fixed(qweights: dict, prec: str, xb: jnp.ndarray,
                       blocks: Optional[dict] = None) -> jnp.ndarray:
     """SwiGLU with EVERY expert at one fixed precision (``prec`` ∈
@@ -143,23 +161,28 @@ def _expert_ffn_fixed(qweights: dict, prec: str, xb: jnp.ndarray,
 
 def _expert_ffn_grouped(qweights: dict, xb: jnp.ndarray,
                         counts: jnp.ndarray, *, cap_hi: int,
-                        blocks: Optional[dict] = None) -> jnp.ndarray:
+                        blocks: Optional[dict] = None,
+                        mesh=None) -> jnp.ndarray:
     """SwiGLU over ONE combined dual-precision capacity buffer: each
     matmul is a single fused grouped dispatch walking the high region
     ``[0, cap_hi)`` and the low region ``[cap_hi, M)`` in one grid —
     instead of one dispatch per precision — and ``counts`` (E, 2)
     live-slot watermarks let the kernel skip dead row blocks outright
-    (finished/evicted/padded slots cost no FLOPs and no weight I/O)."""
+    (finished/evicted/padded slots cost no FLOPs and no weight I/O).
+    ``mesh``: see :func:`_over_local_experts`."""
     from repro.kernels.quant_matmul.ops import expert_quant_matmul_grouped
 
-    def mm(name, h):
-        return expert_quant_matmul_grouped(h, qweights[name], counts,
-                                           cap_hi=cap_hi,
-                                           out_dtype=xb.dtype,
-                                           **(blocks or {}))
+    def ffn(qw, xb, counts):
+        def mm(name, h):
+            return expert_quant_matmul_grouped(h, qw[name], counts,
+                                               cap_hi=cap_hi,
+                                               out_dtype=xb.dtype,
+                                               **(blocks or {}))
 
-    h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
-    return mm("w_down", h)
+        h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
+        return mm("w_down", h)
+
+    return _over_local_experts(mesh, ffn, qweights, xb, counts)
 
 
 def _shared_experts(p, x: jnp.ndarray) -> jnp.ndarray:
@@ -170,18 +193,24 @@ def _shared_experts(p, x: jnp.ndarray) -> jnp.ndarray:
 
 
 def _expert_ffn_quantized(qw: dict, critical: jnp.ndarray, xb: jnp.ndarray,
-                          blocks: Optional[dict] = None) -> jnp.ndarray:
+                          blocks: Optional[dict] = None,
+                          mesh=None) -> jnp.ndarray:
     """xb: (E, C, dm) -> (E, C, dm), every matmul executed straight from the
     packed buffer ``critical`` selects (grouped expert quant-matmul) — no
     dense (E, dm, dff) dequantized weight is ever materialized. In the
     "4/0" deployment sub-critical experts' outputs are zeroed inside the
-    kernel, so a skipped expert contributes exactly nothing."""
-    def mm(name, h):
-        return mixed_precision_matmul(h, qw[name], critical,
-                                      skip_to_zero=True, out_dtype=xb.dtype,
-                                      **(blocks or {}))
-    h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
-    return mm("w_down", h)
+    kernel, so a skipped expert contributes exactly nothing. ``mesh``: see
+    :func:`_over_local_experts`."""
+    def ffn(qw, critical, xb):
+        def mm(name, h):
+            return mixed_precision_matmul(h, qw[name], critical,
+                                          skip_to_zero=True,
+                                          out_dtype=xb.dtype,
+                                          **(blocks or {}))
+        h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
+        return mm("w_down", h)
+
+    return _over_local_experts(mesh, ffn, qw, critical, xb)
 
 
 def moe_apply(p, cfg: ModelConfig, x: jnp.ndarray, *,
@@ -234,7 +263,8 @@ def moe_apply(p, cfg: ModelConfig, x: jnp.ndarray, *,
     if critical_mask is not None:
         assert qweights is not None
         yb = _expert_ffn_quantized(qweights, critical_mask, buf,
-                                   _moe_blocks(cfg))          # (E, C, dm)
+                                   _moe_blocks(cfg),
+                                   mesh=cfg.expert_mesh)      # (E, C, dm)
     else:
         yb = _expert_ffn(p["w_gate"], p["w_up"], p["w_down"], buf)
 
@@ -369,7 +399,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         if skip_low:
             counts = jnp.stack([n_hi, jnp.zeros_like(n_hi)], axis=1)
             yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
-                                     blocks=blocks)
+                                     blocks=blocks, mesh=cfg.expert_mesh)
             ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi], 0.0)
         else:
             slot_lo, n_lo = place(sel_lo)
@@ -378,7 +408,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                                                   mode="drop")
             counts = jnp.stack([n_hi, n_lo], axis=1)
             yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
-                                     blocks=blocks)
+                                     blocks=blocks, mesh=cfg.expert_mesh)
             ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi],
                            jnp.where(sel_lo[:, None],
                                      yb[flat_e, c + slot_lo], 0.0))
@@ -541,7 +571,8 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             counts = jnp.stack([watermark(keep_hi, slot_hi),
                                 jnp.zeros((e,), jnp.int32)], axis=1)
             y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
-                                        blocks=blocks)
+                                        blocks=blocks,
+                                        mesh=cfg.expert_mesh)
             ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi], 0.0)
             _, keep_lo = stream_pos(sel_lo)  # stats only: solo counts these
         else:
@@ -553,7 +584,8 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             counts = jnp.stack([watermark(keep_hi, slot_hi),
                                 watermark(keep_lo, slot_lo)], axis=1)
             y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
-                                        blocks=blocks)
+                                        blocks=blocks,
+                                        mesh=cfg.expert_mesh)
             ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
                            jnp.where(keep_lo[:, None],
                                      y_all[flat_e, cap + slot_lo], 0.0))

@@ -29,6 +29,7 @@ from __future__ import annotations
 
 import dataclasses
 import warnings
+from functools import partial
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -53,7 +54,8 @@ from repro.models.layers.ssm import init_mamba, init_ssm_cache, \
 from repro.quant.qtensor import MixedPrecisionWeights
 
 __all__ = [
-    "init_params", "quantize_model", "forward", "loss_fn", "train_step_fn",
+    "init_params", "quantize_model", "init_quantized_params",
+    "drop_dense_experts", "forward", "loss_fn", "train_step_fn",
     "prefill", "decode_step", "decode_many", "decode_many_batched",
     "init_decode_state", "DyMoEInfo",
 ]
@@ -107,21 +109,21 @@ def _init_block(cfg: ModelConfig, key, kind: str, dtype) -> Dict[str, Any]:
     return lp
 
 
-def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
-    """Parameters with layer stack STACKED along a leading L dim."""
-    cfg.validate()
-    dt = _dtype(cfg)
-    kinds = cfg.block_kinds()
-    assert len(set(kinds)) == 1, "block kinds are uniform per arch"
-    kind = kinds[0]
+def _layer_keys(cfg: ModelConfig, key):
+    """(embed, head, per-layer, shared) keys; the per-layer keys feed
+    :func:`_init_block` one layer at a time."""
     k_embed, k_head, k_layers, k_shared = jax.random.split(key, 4)
+    return k_embed, k_head, jax.random.split(k_layers, cfg.num_layers), \
+        k_shared
+
+
+def _init_outer(cfg: ModelConfig, k_embed, k_head, k_shared, dt) -> dict:
+    """Everything but the layer stack: embedding, final norm, LM head and
+    the hybrid's shared attention block."""
     params: Dict[str, Any] = {
         "embed": (jax.random.normal(k_embed, (cfg.vocab_size, cfg.d_model))
                   * cfg.d_model ** -0.5).astype(dt),
         "final_norm": init_rmsnorm(cfg.d_model, dt),
-        "layers": jax.vmap(
-            lambda k: _init_block(cfg, k, kind, dt)
-        )(jax.random.split(k_layers, cfg.num_layers)),
     }
     if not cfg.tie_embeddings:
         params["lm_head"] = (jax.random.normal(
@@ -138,26 +140,84 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     return params
 
 
+def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
+    """Parameters with layer stack STACKED along a leading L dim. Layers
+    are drawn one at a time (``lax.map``), so no intermediate spans the
+    whole stack: at full width a vmapped draw would hold every layer's
+    f32 expert weights at once."""
+    cfg.validate()
+    dt = _dtype(cfg)
+    kinds = cfg.block_kinds()
+    assert len(set(kinds)) == 1, "block kinds are uniform per arch"
+    k_embed, k_head, layer_keys, k_shared = _layer_keys(cfg, key)
+    params = _init_outer(cfg, k_embed, k_head, k_shared, dt)
+    params["layers"] = jax.lax.map(
+        lambda k: _init_block(cfg, k, kinds[0], dt), layer_keys)
+    return params
+
+
+def _quantize_block(cfg: ModelConfig, lp: dict) -> dict:
+    """One layer's DyMoE mixed-precision store (see :func:`quantize_model`)."""
+    pol = cfg.dymoe
+    kind = cfg.block_kinds()[0]
+    if kind == "attn_moe":
+        return {"moe": quantize_moe(lp["moe"], cfg)}
+    if kind == "attn_dense":
+        return {"mlp": quantize_mlp(lp["mlp"], cfg)}
+    return {"ssm": {
+        name: MixedPrecisionWeights.build(
+            lp["ssm"][name], pol.high_bits, pol.low_bits or None,
+            pol.group_size)
+        for name in ("in_proj", "out_proj")
+    }}
+
+
 def quantize_model(params, cfg: ModelConfig) -> Dict[str, Any]:
     """DyMoE mixed-precision store (paper §5: experts only — on non-MoE
-    archs the FFN / SSM projections, the closest analogue). Operates on the
-    stacked layer weights, so quantized leaves keep the leading L dim and
-    scan alongside the layer stack."""
-    pol = cfg.dymoe
-    low = pol.low_bits or None
+    archs the FFN / SSM projections, the closest analogue). Quantized
+    leaves keep the leading L dim and scan alongside the layer stack; each
+    layer is quantized on its own (``lax.map``), so the f32 intermediates
+    of the group-wise quantizer never span the whole stack."""
+    return {"layers": jax.lax.map(partial(_quantize_block, cfg),
+                                  params["layers"])}
+
+
+_DENSE_EXPERTS = ("w_gate", "w_up", "w_down")
+
+
+def drop_dense_experts(params) -> Dict[str, Any]:
+    """``params`` without the dense routed-expert leaves. The quantized
+    serving path reads routed experts only from the packed store, so
+    keeping the bf16 copies beside it would only take device memory (at
+    OLMoE-1B-7B's width, 13 GB of a 16 GB chip)."""
+    moe = params["layers"].get("moe")
+    if moe is None:
+        return params
+    moe = {k: v for k, v in moe.items() if k not in _DENSE_EXPERTS}
+    return dict(params, layers=dict(params["layers"], moe=moe))
+
+
+def init_quantized_params(cfg: ModelConfig, key
+                          ) -> Tuple[Dict[str, Any], Dict[str, Any]]:
+    """``(drop_dense_experts(init_params(cfg, key)), quantize_model(...))``
+    built one layer at a time: each layer is drawn, quantized and stripped
+    of its dense routed experts before the next, so device memory holds
+    the packed stores plus ONE layer's dense weights — never the dense
+    stack. Same values as the two-step route."""
+    cfg.validate()
+    dt = _dtype(cfg)
     kind = cfg.block_kinds()[0]
-    lp = params["layers"]
-    if kind == "attn_moe":
-        q = {"moe": quantize_moe(lp["moe"], cfg)}
-    elif kind == "attn_dense":
-        q = {"mlp": quantize_mlp(lp["mlp"], cfg)}
-    else:
-        q = {"ssm": {
-            name: MixedPrecisionWeights.build(
-                lp["ssm"][name], pol.high_bits, low, pol.group_size)
-            for name in ("in_proj", "out_proj")
-        }}
-    return {"layers": q}
+    k_embed, k_head, layer_keys, k_shared = _layer_keys(cfg, key)
+    params = _init_outer(cfg, k_embed, k_head, k_shared, dt)
+
+    def one(k):
+        lp = _init_block(cfg, k, kind, dt)
+        q = _quantize_block(cfg, lp)
+        return drop_dense_experts({"layers": lp})["layers"], q
+
+    params["layers"], q = jax.jit(lambda ks: jax.lax.map(one, ks))(
+        layer_keys)
+    return params, {"layers": q}
 
 
 # ------------------------------------------------------------------ helpers

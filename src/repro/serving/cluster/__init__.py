@@ -68,6 +68,12 @@ Failure semantics:
     Traffic on the other replicas never stops; the router's ``health()``
     reports ``"degraded"`` while any replica is impaired and the
     ``restarts`` counter afterwards.
+  * A real compile or device error escaping a replica's session (the
+    session has already failed the requests it touched, typed) takes that
+    replica out of service for good (``Replica.fail``): no cold restart,
+    which would compile the same failing program; its remaining requests
+    resolve with ``SessionClosed`` caused by the error, placement skips
+    it, and the router's ``health()`` reports ``"degraded"``.
   * ``close()`` stops every driver and closes every session — each
     resolves its outstanding handles with ``SessionClosed``; no waiter
     is left blocked.

@@ -81,11 +81,11 @@ class _Driver(threading.Thread):
                         break
                 r.session.flush()
                 r.maintain()
-            except Exception:     # noqa: BLE001 — a dying driver would
-                progressed = False  # strand its replica's handles; the
-                #                     session absorbs faults itself, so
-                #                     anything reaching here is unexpected
-                #                     — back off and retry
+            except Exception as exc:   # noqa: BLE001 — a real compile or
+                # device error: the session already failed the requests it
+                # touched; take the replica out of service and fail the rest
+                r.fail(exc)
+                return
             if not progressed and not self._halt.is_set():
                 self._wake.wait(timeout=0.02)
                 self._wake.clear()
@@ -113,6 +113,7 @@ class Replica:
         self.engine = engine
         self.restarts = 0
         self.quarantined = False
+        self.error: Optional[BaseException] = None   # set by fail()
         self._retired: Optional[SessionHealth] = None  # summed, restarts
         self._faults = faults
         self._knobs = dict(num_slots=num_slots, slots_len=slots_len,
@@ -151,7 +152,8 @@ class Replica:
             except SessionClosed:
                 with self._lock:
                     swapped = self.session is not s
-                if not swapped and not self.quarantined:
+                if not swapped and (not self.quarantined
+                                    or self.error is not None):
                     raise        # genuinely closed, not mid-restart
                 time.sleep(0 if swapped else 0.002)
         self.notify()
@@ -214,6 +216,26 @@ class Replica:
         finally:
             self.quarantined = False
         return True
+
+    def step(self) -> bool:
+        """One chunk boundary of the session (sync mode). A real compile
+        or device error escaping it takes the replica out of service
+        (:meth:`fail`) instead of propagating into the router's loop."""
+        try:
+            return self.session.step()
+        except Exception as exc:   # noqa: BLE001 — see fail()
+            self.fail(exc)
+            return True
+
+    def fail(self, exc: BaseException) -> None:
+        """Quarantine this replica for good after a real compile or device
+        error ``exc`` escaped its session: placement skips it from now on
+        and every request it still holds resolves with
+        :class:`SessionClosed` whose ``__cause__`` is ``exc``. A cold
+        restart would compile the same failing program again."""
+        self.error = exc
+        self.quarantined = True
+        self.session.close(cause=exc)
 
     # ---------------------------------------------------------- teardown
     def stop(self) -> None:

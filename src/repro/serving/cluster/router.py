@@ -256,6 +256,11 @@ class ClusterRouter:
             raise SessionClosed("cluster router is closed")
         with self._lock:
             live = [r for r in self.replicas if r.available]
+            failed = [r for r in self.replicas if r.error is not None]
+            if len(failed) == len(self.replicas):
+                raise SessionClosed(
+                    "every replica was taken out of service") \
+                    from failed[0].error
             if not live:
                 # every replica is mid-restart: same contract as a full
                 # queue — typed, retryable, no handle created
@@ -299,7 +304,7 @@ class ClusterRouter:
             if self.auto_restart:
                 rep.maintain()
             if not rep.session.closed:
-                progressed |= rep.session.step()
+                progressed |= rep.step()
         return progressed
 
     def flush(self) -> None:

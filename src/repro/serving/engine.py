@@ -78,8 +78,8 @@ from repro.core.orchestrator import (
     StepTiming,
 )
 from repro.models import ModelConfig
-from repro.models.model import decode_many, decode_many_batched, prefill, \
-    quantize_model
+from repro.models.model import decode_many, decode_many_batched, \
+    drop_dense_experts, prefill, quantize_model
 from repro.serving.cost_model import EdgeCostModel, EdgeProfile, expert_bytes
 from repro.serving.request import Request, RequestHandle
 from repro.serving.sampler import raw_key_data, resolve_sampling, \
@@ -237,15 +237,19 @@ class DyMoEEngine:
         #
         # ``mesh``: optional jax.sharding.Mesh. The bf16 params and the
         # packed/scales quantized stores are device_put sharded over it at
-        # load (``sharding/partition.py`` rules; ``expert_parallel=True``
-        # shards routed expert weights over E instead of intra-expert TP)
-        # and every serving session's KV slot state is laid out with
-        # ``cache_shardings`` — GSPMD then partitions the jitted
-        # prefill/decode programs along the same axes.
+        # load (``sharding/partition.py`` rules) and every serving
+        # session's KV slot state is laid out with ``cache_shardings`` —
+        # GSPMD then partitions the jitted prefill/decode programs along
+        # the same axes. ``expert_parallel=True`` shards only the routed
+        # experts, over E, and replicates the rest; the jitted programs
+        # get ``cfg.expert_mesh = mesh``, which runs the expert kernels
+        # under shard_map (``models.layers.moe._over_local_experts``).
         #
         # ``qparams``: reuse an already-quantized packed store (e.g. a
-        # sibling replica engine's) instead of re-running quantize_model —
-        # cluster replicas share one copy of the weights.
+        # sibling replica engine's, or one from ``init_quantized_params``)
+        # instead of re-running quantize_model — cluster replicas share
+        # one copy of the weights. With DyMoE on, ``params`` may then come without
+        # the dense routed experts.
         assert engine_cfg.decode_chunk >= 1, engine_cfg.decode_chunk
         self.cfg = cfg
         self.ecfg = engine_cfg
@@ -254,6 +258,10 @@ class DyMoEEngine:
         self.expert_parallel = expert_parallel
         if qparams is None and engine_cfg.use_dymoe:
             qparams = quantize_model(params, cfg)
+        if engine_cfg.use_dymoe and cfg.dymoe.enabled:
+            # the quantized path reads routed experts from the packed
+            # store only: the dense copies are not kept, nor passed to jit
+            params = drop_dense_experts(params)
         if mesh is not None:
             from repro.sharding.partition import param_shardings, shard_tree
             params = shard_tree(
@@ -266,6 +274,14 @@ class DyMoEEngine:
         self.params = params
         self.qparams = qparams if engine_cfg.use_dymoe else None
         self.cost = EdgeCostModel(cfg, engine_cfg.profile)
+        if mesh is not None and expert_parallel and cfg.is_moe:
+            from repro.sharding.partition import MODEL_AXIS
+            if cfg.num_experts % mesh.shape[MODEL_AXIS]:
+                raise ValueError(
+                    f"expert_parallel: {cfg.num_experts} experts do not "
+                    f"divide the {mesh.shape[MODEL_AXIS]}-way "
+                    f"{MODEL_AXIS!r} mesh axis")
+            cfg = dataclasses.replace(cfg, expert_mesh=mesh)
         self._prefill = jax.jit(partial(prefill, cfg=cfg),
                                 static_argnames=("cache_slots",
                                                  "row_local"))
@@ -288,12 +304,14 @@ class DyMoEEngine:
     def shard_decode_state(self, caches):
         """Lay a freshly initialized decode-state pytree out on the
         engine's mesh (``cache_shardings``: KV slots flash-decode sharded
-        over "model", batch over "data"). Identity on an unsharded
-        engine, so the scheduler calls it unconditionally."""
+        over "model", batch over "data"; replicated when expert-parallel).
+        Identity on an unsharded engine, so the scheduler calls it
+        unconditionally."""
         if self.mesh is None:
             return caches
         from repro.sharding.partition import cache_shardings, shard_tree
-        return shard_tree(caches, cache_shardings(caches, self.mesh))
+        return shard_tree(caches, cache_shardings(
+            caches, self.mesh, expert_parallel=self.expert_parallel))
 
     def _make_orchestrator(self) -> Optional[DynamicExpertOrchestrator]:
         cfg, e = self.cfg, self.ecfg

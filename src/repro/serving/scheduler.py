@@ -150,12 +150,6 @@ def live_cap_for(n_live: int, slots: int) -> int:
     """
     return min(slots, 1 << max(0, n_live - 1).bit_length())
 
-# what counts as a recoverable device/allocation failure in the dispatch
-# and admission ladders: injected faults, XLA runtime errors (RuntimeError
-# subclasses) and allocation failures. Tracing/shape errors (TypeError,
-# ValueError) are bugs and propagate.
-_DISPATCH_ERRORS = (InjectedFault, RuntimeError, MemoryError)
-
 
 @dataclasses.dataclass(frozen=True)
 class SchedulerConfig:
@@ -240,6 +234,18 @@ class ContinuousBatchingScheduler:
         replay/compute overlap, and their modeled numbers restart from a
         cold expert cache. ``health().status`` reports ``"degraded"``
         from then on.
+
+      The dispatch and admission ladders below retry
+      :class:`~repro.serving.faults.InjectedFault` only. A real compile
+      refusal or device error (``XlaRuntimeError``, ``RESOURCE_EXHAUSTED``)
+      is not retried, since a smaller retry would only compile another
+      program that fails the same way. The requests it touched — the
+      admission boundary's popped candidates, or every in-flight row of
+      a dispatch — resolve with :class:`AdmissionError` /
+      :class:`DispatchError` whose ``__cause__`` is the XLA error, the
+      session turns ``degraded``, and the error propagates out of
+      :meth:`step`.
+
       * **Dispatch fault** (the fused decode dispatch or its boundary
         sync raises): retried through a degradation ladder — halve the
         chunk length down to 1 step (bit-identical by the
@@ -466,14 +472,14 @@ class ContinuousBatchingScheduler:
             pass
         self.flush()
 
-    def close(self) -> None:
+    def close(self, cause: Optional[BaseException] = None) -> None:
         """Tear the session down. Replay jobs already submitted are
         drained first (requests whose device work completed finalize
         normally); EVERY handle still unresolved after that — queued, in
         flight, or lost to a fault — resolves with a typed
-        :class:`~repro.serving.faults.SessionClosed`, so no
-        ``result(drive=False)`` / ``stream(drive=False)`` waiter is ever
-        left blocked."""
+        :class:`~repro.serving.faults.SessionClosed` (its ``__cause__``
+        is ``cause``, when given), so no ``result(drive=False)`` /
+        ``stream(drive=False)`` waiter is ever left blocked."""
         if self._started and not self.closed:
             try:
                 self._stream.drain()
@@ -485,7 +491,9 @@ class ContinuousBatchingScheduler:
             self._queue.clear()
             handles = list(self._handles)
         err = SessionClosed(
-            "serving session closed before this request resolved")
+            "serving session closed before this request resolved"
+            + (f" ({cause!r})" if cause is not None else ""))
+        err.__cause__ = cause
         for h in handles:
             if not h.done:
                 h._finish_error(err)
@@ -907,7 +915,7 @@ class ContinuousBatchingScheduler:
                     first = np.asarray(
                         jax.device_get(jnp.argmax(logits, axis=-1)),
                         np.int32)
-            except _DISPATCH_ERRORS as e:
+            except InjectedFault as e:
                 # --- admission degradation ladder: requeue the wave and
                 # retry at half size; a single candidate that still fails
                 # resolves with a typed AdmissionError. Splitting a wave
@@ -929,6 +937,14 @@ class ContinuousBatchingScheduler:
                 err.__cause__ = e
                 cands[0]._finish_error(err)
                 continue
+            except Exception as e:
+                # a real compile/device error: fail this wave and the
+                # earlier waves' survivors (popped, not yet in a slot)
+                popped = cands + [st.handle for w in waves for st in w[3]]
+                self._health.admission_failures += len(popped)
+                self._fail_unretried(e, popped, AdmissionError,
+                                     "admission prefill")
+                raise
             cap = None   # a clean wave resets the ladder
             wave_states: List[_SlotState] = []
             wave_src: List[int] = []
@@ -1041,7 +1057,7 @@ class ContinuousBatchingScheduler:
                 # the (T, L, B, E) telemetry stays behind for the worker
                 done_h, emitted_h = jax.device_get((done_d, emitted_d))
                 break
-            except _DISPATCH_ERRORS as e:
+            except InjectedFault as e:
                 self._health.dispatch_retries += 1
                 self._health.last_fault = repr(e)
                 self._last_fault = e
@@ -1065,6 +1081,18 @@ class ContinuousBatchingScheduler:
                 err.__cause__ = e
                 st.handle._finish_error(err)
                 continue
+            except Exception as e:
+                # a real compile/device error: fail every in-flight row
+                failed = []
+                for r in range(self._b):
+                    if self._states[r] is not None:
+                        failed.append(self._states[r].handle)
+                        self._states[r] = None
+                        self._done[r] = True
+                self._health.dispatch_failures += len(failed)
+                self._fail_unretried(e, failed, DispatchError,
+                                     "decode dispatch")
+                raise
         self._caches = caches
         self._tok_d = toks_d[-1]  # next chunk's data dep: ON DEVICE
         new_done = np.array(done_h)  # device_get views are read-only
@@ -1095,6 +1123,19 @@ class ContinuousBatchingScheduler:
              infos.predicted_next), rows),
             [st.handle for _, st, _, _, _ in rows])
         self._n_chunks += 1
+
+    def _fail_unretried(self, exc: BaseException, handles, err_cls,
+                        what: str) -> None:
+        """Resolve ``handles`` with ``err_cls`` caused by a real
+        (non-injected) compile or device error and mark the session
+        degraded. The caller re-raises ``exc`` out of :meth:`step`."""
+        self._degraded = True
+        self._last_fault = exc
+        self._health.last_fault = repr(exc)
+        for h in handles:
+            err = err_cls(f"{h.request_id}: {what} raised {exc!r}")
+            err.__cause__ = exc
+            h._finish_error(err)
 
     # ------------------------------------------- replay fault tolerance
     def _submit_replay(self, fn, handles) -> None:

@@ -10,8 +10,11 @@ Baseline layout (see DESIGN.md §6):
   * MoE experts: tensor-parallel *within* each expert (d_ff over "model") as
     the universal baseline — expert-parallel ("model" over E) is available
     via ``expert_parallel=True`` for archs whose expert count divides the
-    axis (olmoe 64, qwen3-30b-a3b 128); it is one of the §Perf hillclimb
-    levers.
+    axis (olmoe 64, qwen3-30b-a3b 128). Expert parallelism shards the
+    routed experts only and replicates every other weight and the KV
+    state: the expert kernels run under ``shard_map`` (see
+    ``models.layers.moe._over_local_experts``), and the rest computes as
+    on one device.
   * KV caches: batch over ("pod","data"), sequence slots over "model"
     (flash-decode style sharded-KV, avoids the kv_heads<16 GQA wall).
   * Quantized tensors: packed/scales sharded along their N dim, mirroring
@@ -144,9 +147,13 @@ def _spec_for(path_s: str, shape, mesh: Mesh, expert_parallel: bool) -> P:
     # the stacked-layer layout with a leading L dim
     lead_pad = 1 if "/layers/" in path_s else 0
     if expert_parallel:
+        # pure expert parallelism: only the routed experts are split; the
+        # rest is replicated, so every device runs attention, router and
+        # head on the whole batch and only expert outputs cross chips
         for pat, rule in _EP_RULES:
             if re.search(pat, path_s):
                 return guard_spec(_align(rule, shape, lead_pad), shape, mesh)
+        return P()
     for pat, rule in _RULES:
         if re.search(pat, path_s):
             return guard_spec(_align(rule, shape, lead_pad), shape, mesh)
@@ -176,15 +183,16 @@ def param_shardings(tree: Any, mesh: Mesh, *, expert_parallel: bool = False):
 # --------------------------------------------------------------- activations
 
 
-def cache_shardings(tree: Any, mesh: Mesh):
+def cache_shardings(tree: Any, mesh: Mesh, *, expert_parallel: bool = False):
     """Decode-state shardings for the STACKED cache layout (leading L or
     n_sites dim): KV k/v (L, B, Hkv, slots, D) — batch over (pod, data),
     slots over model (flash-decode style); positions (L, B, slots); SSM
-    conv/ssm state sharded over the channel/head dim."""
+    conv/ssm state sharded over the channel/head dim. Replicated under
+    ``expert_parallel``, whose attention runs whole on every device."""
     b_axes = batch_spec(mesh)
 
     def leaf_spec(path, leaf):
-        if not hasattr(leaf, "shape"):
+        if not hasattr(leaf, "shape") or expert_parallel:
             return NamedSharding(mesh, P())
         path_s = _path_str(path)
         nd = len(leaf.shape)

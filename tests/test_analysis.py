@@ -154,9 +154,8 @@ def test_vmem_footprint_fires_on_oversized_block_override():
 
 
 def test_dtype_discipline_fires_on_traced_f64_leak():
-    from jax.experimental import enable_x64
     cfg = _cfg()
-    with enable_x64():
+    with jax.enable_x64(True):
         jaxpr = jax.make_jaxpr(
             lambda v: (v.astype(jnp.float64) * 2.0).sum()
         )(jnp.zeros((4,), jnp.float32))

@@ -24,9 +24,10 @@ import threading
 
 import jax
 import pytest
+from jax.sharding import AxisType
 
 from repro.configs import get_config
-from repro.launch.mesh import make_sim_mesh
+from repro.launch.mesh import make_chip_mesh, make_sim_mesh
 from repro.models import init_params
 from repro.serving import ClusterRouter, ContinuousBatchingScheduler, \
     DyMoEEngine, EngineConfig, FaultInjector, FaultSpec, QueueFull, \
@@ -370,6 +371,49 @@ def test_threaded_replica_fault_recovers(cfg, params):
         router.close()
 
 
+@pytest.mark.timeout(300)
+def test_threaded_replica_device_error_takes_it_out_of_service(
+        cfg, params, engine):
+    """A real (non-injected) device error on one replica's decode
+    dispatch: its driver takes the replica out of service instead of
+    retrying the failing program forever. Every handle resolves, the
+    ones on the failed replica with a typed error caused by the device
+    error; the healthy replica keeps solo-identical tokens and takes the
+    traffic submitted afterwards."""
+    boom = RuntimeError("INTERNAL: device program failed")
+    broken = DyMoEEngine(cfg, params, EngineConfig(
+        profile=EdgeProfile().with_vram(12), decode_chunk=4),
+        qparams=engine.qparams)
+
+    def fail(*args, **kwargs):
+        raise boom
+
+    broken._decode_batched = fail
+    solo = {i: engine.generate(req(i)).tokens for i in range(8)}
+    router = ClusterRouter([engine, broken], num_slots=1, slots_len=64,
+                           threaded=True)
+    try:
+        first = [router.submit(req(i)) for i in range(6)]
+        for h in first:
+            try:
+                assert h.result().tokens == solo[int(h.request_id[4:])]
+                assert h.replica == 0
+            except ServingError as e:
+                assert h.replica == 1
+                assert e.__cause__ is boom
+        assert all(h.done for h in first)
+        assert any(h.replica == 1 for h in first)
+        health = router.health()
+        assert health.status == "degraded"
+        assert health.quarantined == (1,)
+        assert router.replicas[1].error is boom
+        later = [router.submit(req(6 + i)) for i in range(2)]
+        assert {h.replica for h in later} == {0}
+        assert [h.result().tokens for h in later] == [solo[6], solo[7]]
+    finally:
+        router.close()
+
+
 # ------------------------------------------------------- sim mesh + shard
 
 
@@ -388,6 +432,17 @@ def test_make_sim_mesh_errors_clearly_when_flag_missing():
 def test_make_sim_mesh_shape():
     mesh = make_sim_mesh(N_DEVICES)
     assert mesh.shape == {"data": 1, "model": N_DEVICES}
+
+
+def test_make_chip_mesh_shape_and_refusal():
+    """The real-device expert-parallel mesh: (1, n) over the first n
+    devices with Auto axes, and a plain refusal when fewer are visible."""
+    mesh = make_chip_mesh(N_DEVICES)
+    assert mesh.shape == {"data": 1, "model": N_DEVICES}
+    assert list(mesh.devices.flat) == jax.devices()[:N_DEVICES]
+    assert set(mesh.axis_types) == {AxisType.Auto}
+    with pytest.raises(RuntimeError, match="make_chip_mesh"):
+        make_chip_mesh(N_DEVICES + 1)
 
 
 needs_mesh = pytest.mark.skipif(
@@ -417,7 +472,9 @@ def test_expert_parallel_engine_matches_unsharded(cfg, params, engine):
 @needs_mesh
 def test_sharded_cluster_token_parity(cfg, params, engine):
     """Replicas over a sharded engine: solo-identical tokens through the
-    router, and the session's KV slot state is laid out on the mesh."""
+    router, and the session's KV slot state is laid out on the mesh
+    (replicated on every device: expert parallelism runs attention whole
+    on each)."""
     mesh = make_sim_mesh(4)
     sharded = DyMoEEngine(cfg, params, EngineConfig(
         profile=EdgeProfile().with_vram(12), decode_chunk=4),
@@ -427,8 +484,8 @@ def test_sharded_cluster_token_parity(cfg, params, engine):
                                  slots_len=64) as router:
         kv = jax.tree_util.tree_leaves(
             router.replicas[0].session._caches)
-        assert any(not x.sharding.is_fully_replicated for x in kv
-                   if hasattr(x, "sharding"))
+        assert all(x.sharding.device_set == set(mesh.devices.flat)
+                   for x in kv if hasattr(x, "sharding"))
         results = [router.submit(req(i)).result() for i in range(6)]
     assert [r.tokens for r in results] == solo
     assert solo == [engine.generate(req(i)).tokens for i in range(6)]

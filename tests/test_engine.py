@@ -243,3 +243,42 @@ def test_dense_arch_engine_fallback():
                                max_new_tokens=4))
     assert len(res.tokens) == 4
     assert res.cache_stats is None
+
+
+# -------------------------------------------- weights built layer by layer
+
+
+def test_init_quantized_params_matches_two_step_route(moe_setup):
+    """Building the packed store layer by layer from the seed gives the
+    same weights as drawing the dense stack, quantizing it, and dropping
+    the dense routed experts."""
+    from repro.models import drop_dense_experts, init_quantized_params, \
+        quantize_model
+
+    cfg, params = moe_setup
+    lean, qparams = init_quantized_params(cfg, jax.random.PRNGKey(0))
+    assert not {"w_gate", "w_up", "w_down"} & set(lean["layers"]["moe"])
+    want = (drop_dense_experts(params), quantize_model(params, cfg))
+    got_leaves, got_def = jax.tree.flatten((lean, qparams))
+    want_leaves, want_def = jax.tree.flatten(want)
+    assert got_def == want_def
+    for a, b in zip(got_leaves, want_leaves):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+
+
+@pytest.mark.parametrize("use_dymoe", [True, False])
+def test_engine_keeps_dense_experts_only_without_dymoe(moe_setup, use_dymoe):
+    """With DyMoE on, the engine serves routed experts from the packed
+    store alone: no dense expert leaf stays resident or reaches jit. The
+    "off" mode keeps (and needs) them."""
+    from repro.models import init_quantized_params
+
+    cfg, params = moe_setup
+    eng = DyMoEEngine(cfg, params, EngineConfig(use_dymoe=use_dymoe))
+    assert ("w_gate" in eng.params["layers"]["moe"]) == (not use_dymoe)
+    req = Request(prompt_tokens=list(range(1, 13)), max_new_tokens=5)
+    tokens = eng.generate(req).tokens
+    if use_dymoe:   # the lean seeded build serves the same tokens
+        lean, q = init_quantized_params(cfg, jax.random.PRNGKey(0))
+        assert DyMoEEngine(cfg, lean, EngineConfig(),
+                           qparams=q).generate(req).tokens == tokens

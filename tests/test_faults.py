@@ -303,6 +303,44 @@ def test_admission_ladder_splits_then_fails_typed(moe_setup, baseline):
                     == baseline[h.request_id].tokens)
 
 
+@pytest.mark.parametrize("site,err_cls", [("_prefill", AdmissionError),
+                                           ("_decode_batched", DispatchError)])
+def test_real_device_error_fails_touched_requests_and_raises(
+        moe_setup, site, err_cls):
+    """A real (non-injected) compile or device error is not retried: the
+    requests it touched resolve with the typed error caused by it, the
+    session turns degraded, step() raises, and close() resolves the
+    rest — nothing is left pending."""
+    cfg, params = moe_setup
+    eng = _engine(cfg, params)
+    boom = RuntimeError("RESOURCE_EXHAUSTED: out of device memory")
+
+    def fail(*args, **kwargs):
+        raise boom
+
+    setattr(eng, site, fail)
+    session = eng.serve(num_slots=2, slots_len=64)
+    handles = [session.submit(r) for r in _script()]
+    with pytest.raises(RuntimeError) as e:
+        while session.step():
+            pass
+    assert e.value is boom
+    health = session.health()
+    touched = [h for h in handles if h.done]
+    assert len(touched) == 2                     # one wave / both slots
+    for h in touched:
+        assert isinstance(h.error, err_cls)
+        assert h.error.__cause__ is boom
+    assert health.status == "degraded"
+    assert health.admission_retries == health.dispatch_retries == 0
+    assert health.admission_failures + health.dispatch_failures == 2
+    session.close()
+    assert all(h.done for h in handles)
+    for h in handles:
+        if h not in touched:
+            assert isinstance(h.error, SessionClosed)
+
+
 # --------------------------------------------------------- cache faults
 
 

@@ -38,7 +38,7 @@ _SHARED = re.compile(r"/moe/shared_w_")
 
 
 def mesh4():
-    return AbstractMesh((("data", 1), ("model", MESH_N)))
+    return AbstractMesh((1, MESH_N), ("data", "model"))
 
 
 def _path_str(path):
