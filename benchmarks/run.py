@@ -11,7 +11,6 @@
 | bench_layer_similarity  | Fig. 6 (adjacent-layer similarity)     |
 | bench_e2e_latency       | Fig. 10 (TTFT/TPOT vs baselines)       |
 | bench_ablation          | Table 3 (component ablation)           |
-| bench_roofline          | §Roofline (from dry-run artifacts)     |
 """
 from __future__ import annotations
 
@@ -30,7 +29,6 @@ MODULES = [
     "bench_layer_similarity",
     "bench_e2e_latency",
     "bench_ablation",
-    "bench_roofline",
 ]
 
 

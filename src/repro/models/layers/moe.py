@@ -238,76 +238,85 @@ def moe_apply(p, cfg: ModelConfig, x: jnp.ndarray, *,
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     c = _capacity(cfg, t)
 
-    logits = x.astype(jnp.float32) @ p["wg_router"]      # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)                 # (T, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    with jax.named_scope("router"):
+        logits = x.astype(jnp.float32) @ p["wg_router"]      # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, k)                 # (T, k)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
-    flat_e = idx.reshape(-1)                             # (T*k,)
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # (T*k, E)
-    if token_valid is not None:
-        valid_rep = jnp.repeat(token_valid.astype(bool), k)   # (T*k,)
-        oh = oh * valid_rep[:, None].astype(oh.dtype)
-    pos = jnp.cumsum(oh, axis=0) - 1                     # running count
-    pos_in_e = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
-    keep = pos_in_e < c
-    if token_valid is not None:
-        keep = keep & valid_rep   # pads: no slot, no gathered output
-    slot = jnp.clip(pos_in_e, 0, c - 1)
+    with jax.named_scope("moe_dispatch"):
+        flat_e = idx.reshape(-1)                             # (T*k,)
+        oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # (T*k, E)
+        if token_valid is not None:
+            valid_rep = jnp.repeat(token_valid.astype(bool), k)  # (T*k,)
+            oh = oh * valid_rep[:, None].astype(oh.dtype)
+        pos = jnp.cumsum(oh, axis=0) - 1                     # running count
+        pos_in_e = jnp.take_along_axis(pos, flat_e[:, None], axis=1)[:, 0]
+        keep = pos_in_e < c
+        if token_valid is not None:
+            keep = keep & valid_rep   # pads: no slot, no gathered output
+        slot = jnp.clip(pos_in_e, 0, c - 1)
 
-    tok = jnp.repeat(jnp.arange(t), k)                   # (T*k,)
-    xb = jnp.where(keep[:, None], x[tok], 0)
-    buf = jnp.zeros((e, c, dm), x.dtype).at[flat_e, slot].add(
-        xb.astype(x.dtype), mode="drop")
+        tok = jnp.repeat(jnp.arange(t), k)                   # (T*k,)
+        xb = jnp.where(keep[:, None], x[tok], 0)
+        buf = jnp.zeros((e, c, dm), x.dtype).at[flat_e, slot].add(
+            xb.astype(x.dtype), mode="drop")
 
-    if critical_mask is not None:
-        assert qweights is not None
-        yb = _expert_ffn_quantized(qweights, critical_mask, buf,
-                                   _moe_blocks(cfg),
-                                   mesh=cfg.expert_mesh)      # (E, C, dm)
-    else:
-        yb = _expert_ffn(p["w_gate"], p["w_up"], p["w_down"], buf)
+    with jax.named_scope("experts"):
+        if critical_mask is not None:
+            assert qweights is not None
+            yb = _expert_ffn_quantized(qweights, critical_mask, buf,
+                                       _moe_blocks(cfg),
+                                       mesh=cfg.expert_mesh)  # (E, C, dm)
+        else:
+            yb = _expert_ffn(p["w_gate"], p["w_up"], p["w_down"], buf)
 
-    ye = yb[flat_e, slot]                                # (T*k, dm)
-    ye = jnp.where(keep[:, None], ye, 0) * gates.reshape(-1, 1).astype(x.dtype)
-    y = ye.reshape(t, k, dm).sum(axis=1)
+    with jax.named_scope("moe_combine"):
+        ye = yb[flat_e, slot]                                # (T*k, dm)
+        ye = jnp.where(keep[:, None], ye, 0) * gates.reshape(
+            -1, 1).astype(x.dtype)
+        y = ye.reshape(t, k, dm).sum(axis=1)
 
     if cfg.num_shared_experts:
-        y = y + _shared_experts(p, x)
+        with jax.named_scope("experts"):
+            y = y + _shared_experts(p, x)
 
     # ----- statistics / losses (over valid tokens only) -----
-    onehot_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)   # (T, k, E)
-    if token_valid is not None:
-        tv = token_valid.astype(jnp.float32)
-        onehot_top = onehot_top * tv[:, None, None]
-        n_valid = jnp.maximum(tv.sum(), 1.0)
-        frac_probs = jnp.einsum("te,t->e", probs, tv) / n_valid
-        z_loss = jnp.sum(jax.nn.logsumexp(logits, axis=-1) ** 2 * tv) \
-            / n_valid
-        dropped = 1.0 - keep.sum() / jnp.maximum(valid_rep.sum(), 1.0)
-    else:
-        frac_probs = probs.mean(axis=0)
-        z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-        dropped = 1.0 - keep.mean()
-    load = onehot_top.sum(axis=(0, 1))                       # (E,)
-    frac_tokens = load / jnp.maximum(load.sum(), 1.0)
-    lb_loss = e * jnp.sum(frac_tokens * frac_probs)
-    aux = cfg.router_aux_coef * lb_loss + cfg.router_z_coef * z_loss
+    with jax.named_scope("router"):
+        onehot_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # (T, k, E)
+        if token_valid is not None:
+            tv = token_valid.astype(jnp.float32)
+            onehot_top = onehot_top * tv[:, None, None]
+            n_valid = jnp.maximum(tv.sum(), 1.0)
+            frac_probs = jnp.einsum("te,t->e", probs, tv) / n_valid
+            z_loss = jnp.sum(jax.nn.logsumexp(logits, axis=-1) ** 2
+                             * tv) / n_valid
+            dropped = 1.0 - keep.sum() / jnp.maximum(valid_rep.sum(), 1.0)
+        else:
+            frac_probs = probs.mean(axis=0)
+            z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+            dropped = 1.0 - keep.mean()
+        load = onehot_top.sum(axis=(0, 1))                       # (E,)
+        frac_tokens = load / jnp.maximum(load.sum(), 1.0)
+        lb_loss = e * jnp.sum(frac_tokens * frac_probs)
+        aux = cfg.router_aux_coef * lb_loss + cfg.router_z_coef * z_loss
 
-    if hh_mask is None:
-        hh_mask = jnp.zeros((t,), jnp.float32)
-    hh_load = jnp.einsum("tke,t->e", onehot_top, hh_mask.astype(jnp.float32))
-    gate_sum = jnp.einsum("tke,tk->e", onehot_top, gates.astype(jnp.float32))
-    gate_mean = gate_sum / jnp.maximum(load, 1.0)
+        if hh_mask is None:
+            hh_mask = jnp.zeros((t,), jnp.float32)
+        hh_load = jnp.einsum("tke,t->e", onehot_top,
+                             hh_mask.astype(jnp.float32))
+        gate_sum = jnp.einsum("tke,tk->e", onehot_top,
+                              gates.astype(jnp.float32))
+        gate_mean = gate_sum / jnp.maximum(load, 1.0)
 
-    stats = MoEStats(
-        router_logits=logits,
-        expert_load=load,
-        expert_hh_load=hh_load,
-        gate_mean=gate_mean,
-        aux_loss=aux,
-        dropped_frac=dropped,
-    )
+        stats = MoEStats(
+            router_logits=logits,
+            expert_load=load,
+            expert_hh_load=hh_load,
+            gate_mean=gate_mean,
+            aux_loss=aux,
+            dropped_frac=dropped,
+        )
     return y, stats
 
 
@@ -362,22 +371,11 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             "capacity < B requires the live mask that bounds occupancy"
         c = max(1, min(int(capacity), b))
 
-    logits = x.astype(jnp.float32) @ p["wg_router"]      # (B, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)                 # (B, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-    crit_tok = jnp.take_along_axis(critical_rows.astype(bool), idx, axis=1)
-    flat_e = idx.reshape(-1)                             # (B*k,)
-    flat_c = crit_tok.reshape(-1)
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # (B*k, E)
-    if live is not None:
-        live_rep = jnp.repeat(jnp.asarray(live).astype(bool), k)
-        sel_hi = flat_c & live_rep
-        sel_lo = ~flat_c & live_rep
-    else:
-        sel_hi, sel_lo = flat_c, ~flat_c
-    tok = jnp.repeat(jnp.arange(b), k)
+    with jax.named_scope("router"):
+        logits = x.astype(jnp.float32) @ p["wg_router"]      # (B, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, k)                 # (B, k)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
     def place(select):
         """Slot index inside the (expert, precision-stream) capacity
@@ -390,28 +388,44 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
 
     skip_low = qweights["w_gate"].low is None            # "4/0"
     blocks = _moe_blocks(cfg)
-    slot_hi, n_hi = place(sel_hi)
-    xb_hi = jnp.where(sel_hi[:, None], x[tok], 0)
-    if fused:
-        width = c if skip_low else 2 * c
-        buf = jnp.zeros((e, width, dm), x.dtype).at[flat_e, slot_hi].add(
-            xb_hi.astype(x.dtype), mode="drop")
-        if skip_low:
-            counts = jnp.stack([n_hi, jnp.zeros_like(n_hi)], axis=1)
-            yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
-                                     blocks=blocks, mesh=cfg.expert_mesh)
-            ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi], 0.0)
+    with jax.named_scope("moe_dispatch"):
+        crit_tok = jnp.take_along_axis(critical_rows.astype(bool), idx,
+                                       axis=1)
+        flat_e = idx.reshape(-1)                             # (B*k,)
+        flat_c = crit_tok.reshape(-1)
+        oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # (B*k, E)
+        if live is not None:
+            live_rep = jnp.repeat(jnp.asarray(live).astype(bool), k)
+            sel_hi = flat_c & live_rep
+            sel_lo = ~flat_c & live_rep
         else:
-            slot_lo, n_lo = place(sel_lo)
-            xb_lo = jnp.where(sel_lo[:, None], x[tok], 0)
-            buf = buf.at[flat_e, c + slot_lo].add(xb_lo.astype(x.dtype),
-                                                  mode="drop")
-            counts = jnp.stack([n_hi, n_lo], axis=1)
+            sel_hi, sel_lo = flat_c, ~flat_c
+        tok = jnp.repeat(jnp.arange(b), k)
+        slot_hi, n_hi = place(sel_hi)
+        xb_hi = jnp.where(sel_hi[:, None], x[tok], 0)
+    if fused:
+        with jax.named_scope("moe_dispatch"):
+            width = c if skip_low else 2 * c
+            buf = jnp.zeros((e, width, dm), x.dtype).at[
+                flat_e, slot_hi].add(xb_hi.astype(x.dtype), mode="drop")
+            if skip_low:
+                counts = jnp.stack([n_hi, jnp.zeros_like(n_hi)], axis=1)
+            else:
+                slot_lo, n_lo = place(sel_lo)
+                xb_lo = jnp.where(sel_lo[:, None], x[tok], 0)
+                buf = buf.at[flat_e, c + slot_lo].add(
+                    xb_lo.astype(x.dtype), mode="drop")
+                counts = jnp.stack([n_hi, n_lo], axis=1)
+        with jax.named_scope("experts"):
             yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
                                      blocks=blocks, mesh=cfg.expert_mesh)
-            ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi],
-                           jnp.where(sel_lo[:, None],
-                                     yb[flat_e, c + slot_lo], 0.0))
+        with jax.named_scope("moe_combine"):
+            if skip_low:
+                ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi], 0.0)
+            else:
+                ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi],
+                               jnp.where(sel_lo[:, None],
+                                         yb[flat_e, c + slot_lo], 0.0))
     else:
         buf_hi = jnp.zeros((e, c, dm), x.dtype).at[flat_e, slot_hi].add(
             xb_hi.astype(x.dtype), mode="drop")
@@ -427,19 +441,22 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             ye = jnp.where(sel_hi[:, None], y_hi[flat_e, slot_hi],
                            jnp.where(sel_lo[:, None],
                                      y_lo[flat_e, slot_lo], 0.0))
-    ye = ye * gates.reshape(-1, 1).astype(x.dtype)
-    y = ye.reshape(b, k, dm).sum(axis=1)
+    with jax.named_scope("moe_combine"):
+        ye = ye * gates.reshape(-1, 1).astype(x.dtype)
+        y = ye.reshape(b, k, dm).sum(axis=1)
 
     if cfg.num_shared_experts:
-        y = y + _shared_experts(p, x)
+        with jax.named_scope("experts"):
+            y = y + _shared_experts(p, x)
 
-    onehot_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)    # (B, k, E)
-    load = onehot_top.sum(axis=1)                             # (B, E)
-    gate_sum = jnp.einsum("bke,bk->be", onehot_top,
-                          gates.astype(jnp.float32))
-    stats = dict(active=load > 0,
-                 gate_mean=gate_sum / jnp.maximum(load, 1.0),
-                 router_logits=logits)
+    with jax.named_scope("router"):
+        onehot_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)  # (B, k, E)
+        load = onehot_top.sum(axis=1)                           # (B, E)
+        gate_sum = jnp.einsum("bke,bk->be", onehot_top,
+                              gates.astype(jnp.float32))
+        stats = dict(active=load > 0,
+                     gate_mean=gate_sum / jnp.maximum(load, 1.0),
+                     router_logits=logits)
     return y, stats
 
 
@@ -499,33 +516,11 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
     e, k = cfg.num_experts, cfg.num_experts_per_tok
     cmax = _capacity(cfg, s)      # static per-row buffer stride (>= c_row)
 
-    logits = x.astype(jnp.float32) @ p["wg_router"]      # (T, E)
-    probs = jax.nn.softmax(logits, axis=-1)
-    gates, idx = jax.lax.top_k(probs, k)                 # (T, k)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-
-    flat_e = idx.reshape(-1)                             # (T*k,)
-    row_rep = jnp.repeat(jnp.arange(b), s * k)           # (T*k,) token's row
-    crit_tok = jnp.take_along_axis(
-        critical_rows.astype(bool)[jnp.repeat(jnp.arange(b), s)], idx,
-        axis=1)                                          # (T, k)
-    flat_c = crit_tok.reshape(-1)
-    if token_valid is not None:
-        valid_rep = jnp.repeat(token_valid.astype(bool), k)
-        lens = token_valid.astype(jnp.int32).reshape(b, s).sum(axis=1)
-    else:
-        valid_rep = jnp.ones((t * k,), bool)
-        lens = jnp.full((b,), s, jnp.int32)
-    # per-row solo capacity: same formula as _capacity at the row's own
-    # valid length, so batched drop behavior reproduces the solo prefill's
-    if row_capacities is not None:
-        c_row = jnp.asarray(row_capacities, jnp.int32)   # (B,) exact
-    else:
-        c_row = jnp.minimum(lens, jnp.maximum(8, (
-            jnp.float32(cfg.capacity_factor) * lens.astype(jnp.float32)
-            * k / e).astype(jnp.int32)))                 # (B,)
-    oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # (T*k, E)
-    tok_of = jnp.repeat(jnp.arange(t), k)
+    with jax.named_scope("router"):
+        logits = x.astype(jnp.float32) @ p["wg_router"]      # (T, E)
+        probs = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(probs, k)                 # (T, k)
+        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
 
     def stream_pos(select):
         """Within-ROW running slot index of each (token, k) pair inside the
@@ -546,8 +541,32 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             xb.astype(x.dtype), mode="drop")
         return buf, slot, keep
 
-    sel_hi = flat_c & valid_rep
-    sel_lo = ~flat_c & valid_rep
+    with jax.named_scope("moe_dispatch"):
+        flat_e = idx.reshape(-1)                             # (T*k,)
+        row_rep = jnp.repeat(jnp.arange(b), s * k)      # (T*k,) token's row
+        crit_tok = jnp.take_along_axis(
+            critical_rows.astype(bool)[jnp.repeat(jnp.arange(b), s)], idx,
+            axis=1)                                          # (T, k)
+        flat_c = crit_tok.reshape(-1)
+        if token_valid is not None:
+            valid_rep = jnp.repeat(token_valid.astype(bool), k)
+            lens = token_valid.astype(jnp.int32).reshape(b, s).sum(axis=1)
+        else:
+            valid_rep = jnp.ones((t * k,), bool)
+            lens = jnp.full((b,), s, jnp.int32)
+        # per-row solo capacity: same formula as _capacity at the row's
+        # own valid length, so batched drops reproduce the solo prefill's
+        if row_capacities is not None:
+            c_row = jnp.asarray(row_capacities, jnp.int32)   # (B,) exact
+        else:
+            c_row = jnp.minimum(lens, jnp.maximum(8, (
+                jnp.float32(cfg.capacity_factor) * lens.astype(jnp.float32)
+                * k / e).astype(jnp.int32)))                 # (B,)
+        oh = jax.nn.one_hot(flat_e, e, dtype=jnp.int32)      # (T*k, E)
+        tok_of = jnp.repeat(jnp.arange(t), k)
+
+        sel_hi = flat_c & valid_rep
+        sel_lo = ~flat_c & valid_rep
     skip_low = qweights["w_gate"].low is None            # "4/0"
     blocks = _moe_blocks(cfg)
     if fused:
@@ -561,34 +580,41 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                 jnp.where(keep, slot + 1, 0).astype(jnp.int32),
                 mode="drop")
 
-        pos_hi, keep_hi = stream_pos(sel_hi)
-        slot_hi = row_rep * cmax + jnp.clip(pos_hi, 0, cmax - 1)
-        xbh = jnp.where(keep_hi[:, None], x[tok_of], 0)
-        width = cap if skip_low else 2 * cap
-        buf = jnp.zeros((e, width, dm), x.dtype).at[flat_e, slot_hi].add(
-            xbh.astype(x.dtype), mode="drop")
+        with jax.named_scope("moe_dispatch"):
+            pos_hi, keep_hi = stream_pos(sel_hi)
+            slot_hi = row_rep * cmax + jnp.clip(pos_hi, 0, cmax - 1)
+            xbh = jnp.where(keep_hi[:, None], x[tok_of], 0)
+            width = cap if skip_low else 2 * cap
+            buf = jnp.zeros((e, width, dm), x.dtype).at[
+                flat_e, slot_hi].add(xbh.astype(x.dtype), mode="drop")
+            if skip_low:
+                counts = jnp.stack([watermark(keep_hi, slot_hi),
+                                    jnp.zeros((e,), jnp.int32)], axis=1)
+            else:
+                pos_lo, keep_lo = stream_pos(sel_lo)
+                slot_lo = row_rep * cmax + jnp.clip(pos_lo, 0, cmax - 1)
+                xbl = jnp.where(keep_lo[:, None], x[tok_of], 0)
+                buf = buf.at[flat_e, cap + slot_lo].add(
+                    xbl.astype(x.dtype), mode="drop")
+                counts = jnp.stack([watermark(keep_hi, slot_hi),
+                                    watermark(keep_lo, slot_lo)], axis=1)
+        with jax.named_scope("experts"):
+            y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
+                                        blocks=blocks,
+                                        mesh=cfg.expert_mesh)
+        with jax.named_scope("moe_combine"):
+            if skip_low:
+                ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
+                               0.0)
+            else:
+                ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
+                               jnp.where(keep_lo[:, None],
+                                         y_all[flat_e, cap + slot_lo],
+                                         0.0))
         if skip_low:
-            counts = jnp.stack([watermark(keep_hi, slot_hi),
-                                jnp.zeros((e,), jnp.int32)], axis=1)
-            y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
-                                        blocks=blocks,
-                                        mesh=cfg.expert_mesh)
-            ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi], 0.0)
-            _, keep_lo = stream_pos(sel_lo)  # stats only: solo counts these
-        else:
-            pos_lo, keep_lo = stream_pos(sel_lo)
-            slot_lo = row_rep * cmax + jnp.clip(pos_lo, 0, cmax - 1)
-            xbl = jnp.where(keep_lo[:, None], x[tok_of], 0)
-            buf = buf.at[flat_e, cap + slot_lo].add(xbl.astype(x.dtype),
-                                                    mode="drop")
-            counts = jnp.stack([watermark(keep_hi, slot_hi),
-                                watermark(keep_lo, slot_lo)], axis=1)
-            y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
-                                        blocks=blocks,
-                                        mesh=cfg.expert_mesh)
-            ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
-                           jnp.where(keep_lo[:, None],
-                                     y_all[flat_e, cap + slot_lo], 0.0))
+            with jax.named_scope("router"):
+                # stats only: solo counts these
+                _, keep_lo = stream_pos(sel_lo)
     else:
         buf_hi, slot_hi, keep_hi = dispatch(sel_hi)
         y_hi = _expert_ffn_fixed(qweights, "high", buf_hi, blocks)
@@ -602,42 +628,45 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             ye = jnp.where(flat_c[:, None], ye_hi,
                            jnp.where(keep_lo[:, None],
                                      y_lo[flat_e, slot_lo], 0.0))
-    ye = ye * gates.reshape(-1, 1).astype(x.dtype)
-    y = ye.reshape(t, k, dm).sum(axis=1)
+    with jax.named_scope("moe_combine"):
+        ye = ye * gates.reshape(-1, 1).astype(x.dtype)
+        y = ye.reshape(t, k, dm).sum(axis=1)
 
     if cfg.num_shared_experts:
-        y = y + _shared_experts(p, x)
+        with jax.named_scope("experts"):
+            y = y + _shared_experts(p, x)
 
     # ----- per-row statistics (each row's block == its solo stats) -----
-    onehot_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)   # (T, k, E)
-    if token_valid is not None:
-        tv = token_valid.astype(jnp.float32)
-        onehot_top = onehot_top * tv[:, None, None]
-        n_valid = jnp.maximum(tv.sum(), 1.0)
-        frac_probs = jnp.einsum("te,t->e", probs, tv) / n_valid
-        z_loss = jnp.sum(jax.nn.logsumexp(logits, axis=-1) ** 2 * tv) \
-            / n_valid
-    else:
-        frac_probs = probs.mean(axis=0)
-        z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
-    kept = keep_hi | keep_lo
-    dropped = 1.0 - kept.sum() / jnp.maximum(valid_rep.sum(), 1)
-    oh_r = onehot_top.reshape(b, s, k, e)
-    load = oh_r.sum(axis=(1, 2))                             # (B, E)
-    if hh_mask is None:
-        hh_mask = jnp.zeros((t,), jnp.float32)
-    hh_load = jnp.einsum("bske,bs->be", oh_r,
-                         hh_mask.astype(jnp.float32).reshape(b, s))
-    gate_sum = jnp.einsum("bske,bsk->be", oh_r,
-                          gates.astype(jnp.float32).reshape(b, s, k))
-    gate_mean = gate_sum / jnp.maximum(load, 1.0)
-    load_all = load.sum(axis=0)
-    frac_tokens = load_all / jnp.maximum(load_all.sum(), 1.0)
-    lb_loss = e * jnp.sum(frac_tokens * frac_probs)
-    aux = cfg.router_aux_coef * lb_loss + cfg.router_z_coef * z_loss
-    stats = dict(active=load > 0, load=load, hh_load=hh_load,
-                 gate_mean=gate_mean, router_logits=logits,
-                 aux_loss=aux, dropped_frac=dropped)
+    with jax.named_scope("router"):
+        onehot_top = jax.nn.one_hot(idx, e, dtype=jnp.float32)   # (T, k, E)
+        if token_valid is not None:
+            tv = token_valid.astype(jnp.float32)
+            onehot_top = onehot_top * tv[:, None, None]
+            n_valid = jnp.maximum(tv.sum(), 1.0)
+            frac_probs = jnp.einsum("te,t->e", probs, tv) / n_valid
+            z_loss = jnp.sum(jax.nn.logsumexp(logits, axis=-1) ** 2 * tv) \
+                / n_valid
+        else:
+            frac_probs = probs.mean(axis=0)
+            z_loss = jnp.mean(jax.nn.logsumexp(logits, axis=-1) ** 2)
+        kept = keep_hi | keep_lo
+        dropped = 1.0 - kept.sum() / jnp.maximum(valid_rep.sum(), 1)
+        oh_r = onehot_top.reshape(b, s, k, e)
+        load = oh_r.sum(axis=(1, 2))                             # (B, E)
+        if hh_mask is None:
+            hh_mask = jnp.zeros((t,), jnp.float32)
+        hh_load = jnp.einsum("bske,bs->be", oh_r,
+                             hh_mask.astype(jnp.float32).reshape(b, s))
+        gate_sum = jnp.einsum("bske,bsk->be", oh_r,
+                              gates.astype(jnp.float32).reshape(b, s, k))
+        gate_mean = gate_sum / jnp.maximum(load, 1.0)
+        load_all = load.sum(axis=0)
+        frac_tokens = load_all / jnp.maximum(load_all.sum(), 1.0)
+        lb_loss = e * jnp.sum(frac_tokens * frac_probs)
+        aux = cfg.router_aux_coef * lb_loss + cfg.router_z_coef * z_loss
+        stats = dict(active=load > 0, load=load, hh_load=hh_load,
+                     gate_mean=gate_mean, router_logits=logits,
+                     aux_loss=aux, dropped_frac=dropped)
     return y, stats
 
 

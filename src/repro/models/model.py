@@ -76,19 +76,26 @@ def _index_tree(tree, i):
 def _scan_blocks(cfg: ModelConfig, body, carry0, xs):
     """lax.scan over the layer stack, or an unrolled Python loop when
     ``cfg.scan_layers`` is False (used by the dry-run to recover per-layer
-    costs: XLA's cost_analysis counts a while-loop body once)."""
-    if cfg.scan_layers:
-        return jax.lax.scan(body, carry0, xs)
-    carry = carry0
-    ys = []
-    for l in range(cfg.num_layers):
-        carry, y = body(carry, _index_tree(xs, l))
-        ys.append(y)
-    if ys and ys[0] is not None:
-        ys = _tmap(lambda *zs: jnp.stack(zs), *ys)
-    else:
-        ys = None
-    return carry, ys
+    costs: XLA's cost_analysis counts a while-loop body once).
+
+    Runs under the ``layers`` named scope: the per-layer sub-scopes of the
+    body (``attention``, ``router``, ``moe_dispatch``, ``experts``,
+    ``moe_combine``) nest inside it, and the scan's own bookkeeping (the
+    per-layer slices of ``xs``, the stacking of ``ys``) reads as
+    ``layers`` with no inner scope."""
+    with jax.named_scope("layers"):
+        if cfg.scan_layers:
+            return jax.lax.scan(body, carry0, xs)
+        carry = carry0
+        ys = []
+        for l in range(cfg.num_layers):
+            carry, y = body(carry, _index_tree(xs, l))
+            ys.append(y)
+        if ys and ys[0] is not None:
+            ys = _tmap(lambda *zs: jnp.stack(zs), *ys)
+        else:
+            ys = None
+        return carry, ys
 
 
 # --------------------------------------------------------------------- init
@@ -298,7 +305,7 @@ class DyMoEInfo:
     active_masks: Optional[jnp.ndarray] = None     # (L, E) bool
     expert_load: Optional[jnp.ndarray] = None      # (L, E)
     expert_hh_load: Optional[jnp.ndarray] = None   # (L, E)
-    gate_mean: Optional[jnp.ndarray] = None        # (L, E)
+    gate_mean: Optional[jnp.ndarray] = None        # (L, E), prefill only
     predicted_next: Optional[jnp.ndarray] = None   # (L, E) Eq. 6–8 demand
     token_importance: Optional[jnp.ndarray] = None  # (B, S) Eq. 1, last layer
     aux_loss: Optional[jnp.ndarray] = None
@@ -511,59 +518,69 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
         telem: Dict[str, Any] = {}
         if kind in ("attn_dense", "attn_moe"):
             want_imp = kind == "attn_moe"
-            a, tok_imp, (k, v) = attention_train(
-                lp["attn"], cfg, rmsnorm(lp["norm1"], x, cfg.norm_eps),
-                positions=positions, kv_valid=valid,
-                want_token_importance=want_imp)
-            cache = fill_kv_cache(
-                init_kv_cache(b, cfg.num_kv_heads, slots, cfg.head_dim, dt,
-                              ring), k, v, lengths=lengths, offsets=offsets)
-            x = x + a
-            h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+            with jax.named_scope("attention"):
+                a, tok_imp, (k, v) = attention_train(
+                    lp["attn"], cfg, rmsnorm(lp["norm1"], x, cfg.norm_eps),
+                    positions=positions, kv_valid=valid,
+                    want_token_importance=want_imp)
+                cache = fill_kv_cache(
+                    init_kv_cache(b, cfg.num_kv_heads, slots, cfg.head_dim,
+                                  dt, ring), k, v, lengths=lengths,
+                    offsets=offsets)
+                x = x + a
             if kind == "attn_dense":
+                h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
                 if dymoe_on:
                     y = mlp_quantized(xs_l["q"]["mlp"], cfg, h, xs_l["tier"])
                 else:
                     y = mlp(lp["mlp"], cfg, h)
                 x = x + y
             else:
-                hflat = h.reshape(b * s, -1)
-                vflat = valid.reshape(b * s) if valid is not None else None
-                critical, hh = None, None
-                if dymoe_on:
-                    if valid is None:
-                        hh = heavy_hitter_mask(
-                            tok_imp, pol.heavy_hitter_frac).reshape(b * s)
-                    else:
-                        hh = _ragged_hh_mask(
-                            tok_imp, pol.heavy_hitter_frac, lengths,
-                            valid).reshape(b * s)
                 k_tok = cfg.num_experts_per_tok
-                if dymoe_on or row_local:
-                    # router pre-pass: pick the Critical set BEFORE expert
-                    # compute (Eq. 1-2 -> Eq. 5)
-                    logits_r = hflat.astype(jnp.float32) @ lp["moe"][
-                        "wg_router"]
-                    probs_r = jax.nn.softmax(logits_r, axis=-1)
-                    gates_r, idx_r = jax.lax.top_k(probs_r, k_tok)
-                    oh = jax.nn.one_hot(idx_r, e, dtype=jnp.float32)
-                    if vflat is not None:  # pads route nowhere
-                        oh = oh * vflat.astype(jnp.float32)[:, None, None]
-                if dymoe_on and not row_local:
-                    imp = prefill_expert_importance(
-                        jnp.einsum("tke,t->e", oh, hh), oh.sum(axis=(0, 1)))
-                    critical = select_critical(imp, xs_l["t_l"])
-                if row_local:
-                    # per-ROW Critical sets (batched-admission mode): each
-                    # row's Eq. 1-2 importance over ITS OWN tokens only
-                    oh_r = oh.reshape(b, s, k_tok, e)
-                    load_rows = oh_r.sum(axis=(1, 2))          # (B, E)
+                critical, hh = None, None
+                with jax.named_scope("router"):
+                    h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+                    hflat = h.reshape(b * s, -1)
+                    vflat = (valid.reshape(b * s) if valid is not None
+                             else None)
                     if dymoe_on:
-                        imp_rows = prefill_expert_importance_rows(
-                            jnp.einsum("bske,bs->be", oh_r,
-                                       hh.reshape(b, s)), load_rows)
-                        critical_rows = select_critical_rows(
-                            imp_rows, xs_l["t_l"])
+                        if valid is None:
+                            hh = heavy_hitter_mask(
+                                tok_imp, pol.heavy_hitter_frac
+                            ).reshape(b * s)
+                        else:
+                            hh = _ragged_hh_mask(
+                                tok_imp, pol.heavy_hitter_frac, lengths,
+                                valid).reshape(b * s)
+                    if dymoe_on or row_local:
+                        # router pre-pass: pick the Critical set BEFORE
+                        # expert compute (Eq. 1-2 -> Eq. 5)
+                        logits_r = hflat.astype(jnp.float32) @ lp["moe"][
+                            "wg_router"]
+                        probs_r = jax.nn.softmax(logits_r, axis=-1)
+                        gates_r, idx_r = jax.lax.top_k(probs_r, k_tok)
+                        oh = jax.nn.one_hot(idx_r, e, dtype=jnp.float32)
+                        if vflat is not None:  # pads route nowhere
+                            oh = oh * vflat.astype(jnp.float32)[:, None,
+                                                                None]
+                    if dymoe_on and not row_local:
+                        imp = prefill_expert_importance(
+                            jnp.einsum("tke,t->e", oh, hh),
+                            oh.sum(axis=(0, 1)))
+                        critical = select_critical(imp, xs_l["t_l"])
+                    if row_local:
+                        # per-ROW Critical sets (batched-admission mode):
+                        # each row's Eq. 1-2 importance over ITS OWN tokens
+                        oh_r = oh.reshape(b, s, k_tok, e)
+                        load_rows = oh_r.sum(axis=(1, 2))      # (B, E)
+                        if dymoe_on:
+                            imp_rows = prefill_expert_importance_rows(
+                                jnp.einsum("bske,bs->be", oh_r,
+                                           hh.reshape(b, s)), load_rows)
+                            critical_rows = select_critical_rows(
+                                imp_rows, xs_l["t_l"])
+                if row_local:
+                    if dymoe_on:
                         y, rstats = moe_apply_prefill_rows(
                             lp["moe"], cfg, hflat, critical_rows,
                             xs_l["q"]["moe"], rows=b, hh_mask=hh,
@@ -593,19 +610,26 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                         critical_mask=critical,
                         qweights=xs_l["q"]["moe"] if dymoe_on else None,
                         token_valid=vflat)
-                x = x + y.reshape(b, s, -1)
+                with jax.named_scope("moe_combine"):
+                    x = x + y.reshape(b, s, -1)
                 # look-ahead (Eq. 6-7) for the next layer's prefetcher
-                pg = predict_next_gates(hflat, xs_l["next_router"])
-                if row_local:
-                    # per-row Eq. 7: each admission's own predicted demand
-                    pg_r = pg.reshape(b, s, e)
-                    if valid is None:
-                        freq = jax.vmap(lambda g: prefetch_targets(
-                            g, k_tok, pol.prefetch_topk)[1])(pg_r)
+                with jax.named_scope("router"):
+                    pg = predict_next_gates(hflat, xs_l["next_router"])
+                    if row_local:
+                        # per-row Eq. 7: each admission's own demand
+                        pg_r = pg.reshape(b, s, e)
+                        if valid is None:
+                            freq = jax.vmap(lambda g: prefetch_targets(
+                                g, k_tok, pol.prefetch_topk)[1])(pg_r)
+                        else:
+                            freq = jax.vmap(lambda g, v: prefetch_targets(
+                                g, k_tok, pol.prefetch_topk,
+                                token_valid=v)[1])(pg_r, valid)
                     else:
-                        freq = jax.vmap(lambda g, v: prefetch_targets(
-                            g, k_tok, pol.prefetch_topk,
-                            token_valid=v)[1])(pg_r, valid)
+                        _, freq = prefetch_targets(pg, k_tok,
+                                                   pol.prefetch_topk,
+                                                   token_valid=vflat)
+                if row_local:
                     telem = dict(
                         critical=critical_rows, active=active_rows,
                         load=load_rows, hh_load=hh_load_rows,
@@ -614,9 +638,6 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                         tok_imp=(tok_imp if tok_imp is not None
                                  else jnp.zeros((b, s), jnp.float32)))
                 else:
-                    _, freq = prefetch_targets(pg, k_tok,
-                                               pol.prefetch_topk,
-                                               token_valid=vflat)
                     telem = dict(
                         critical=(critical if critical is not None
                                   else jnp.ones((e,), bool)),
@@ -644,8 +665,9 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
     carry0 = (x, shared_caches0) if hybrid else (x,)
     carry, ys = _scan_blocks(cfg, body, carry0, xs)
     x = carry[0]
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _lm_head(params, cfg, x if full_logits else x[:, -1])
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = _lm_head(params, cfg, x if full_logits else x[:, -1])
 
     caches: Dict[str, Any] = {"layers": ys["cache"]}
     if hybrid:
@@ -767,76 +789,73 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
 
         telem: Dict[str, Any] = {}
         if kind in ("attn_dense", "attn_moe"):
-            a, cache = attention_decode(
-                lp["attn"], cfg, rmsnorm(lp["norm1"], x, cfg.norm_eps),
-                cache, live=live_rows)
-            x = x + a
-            h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+            with jax.named_scope("attention"):
+                a, cache = attention_decode(
+                    lp["attn"], cfg, rmsnorm(lp["norm1"], x, cfg.norm_eps),
+                    cache, live=live_rows)
+                x = x + a
             if kind == "attn_dense":
+                h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
                 if dymoe_on:
                     y = mlp_quantized(xs_l["q"]["mlp"], cfg, h, xs_l["tier"])
                 else:
                     y = mlp(lp["mlp"], cfg, h)
                 x = x + y
             else:
-                hflat = h.reshape(b, -1)
                 critical = None
-                pg = None
+                with jax.named_scope("router"):
+                    h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+                    hflat = h.reshape(b, -1)
+                    if dymoe_on:
+                        # Eq. (3): gate-guided importance — per row (each
+                        # request's Critical set from ITS OWN gate scores,
+                        # the solo-parity contract) or from the batch-mean
+                        # gate
+                        logits_r = hflat.astype(jnp.float32) @ lp["moe"][
+                            "wg_router"]
+                        imp = jax.nn.softmax(logits_r, axis=-1)  # (B, E)
+                        critical = (
+                            select_critical_rows(imp, xs_l["t_l"])
+                            if per_row_moe
+                            else select_critical(imp.mean(axis=0),
+                                                 xs_l["t_l"]))
                 if per_row_moe and dymoe_on:
-                    # Eq. (3) per row: each request's Critical set comes
-                    # from ITS OWN gate scores (solo-parity contract)
-                    logits_r = hflat.astype(jnp.float32) @ lp["moe"][
-                        "wg_router"]
-                    imp = jax.nn.softmax(logits_r, axis=-1)      # (B, E)
-                    critical = select_critical_rows(imp, xs_l["t_l"])
                     y, rstats = moe_apply_rows(
                         lp["moe"], cfg, hflat, critical,
                         qweights=xs_l["q"]["moe"], live=live_rows,
                         capacity=moe_capacity)
                     active = rstats["active"]
-                    gate_mean = rstats["gate_mean"]
                 elif per_row_moe:
                     y, stats = moe_apply_sharded(lp["moe"], cfg, hflat)
                     # full precision: rows are independent already; only
                     # the telemetry needs the per-row shape
-                    oh = jax.nn.one_hot(
-                        jax.lax.top_k(stats.router_logits,
-                                      cfg.num_experts_per_tok)[1],
-                        e, dtype=jnp.float32)                    # (B, k, E)
-                    active = oh.sum(axis=1) > 0
-                    gate_mean = jnp.broadcast_to(stats.gate_mean[None],
-                                                 active.shape)
-                    critical = jnp.ones(active.shape, bool)
+                    with jax.named_scope("router"):
+                        oh = jax.nn.one_hot(
+                            jax.lax.top_k(stats.router_logits,
+                                          cfg.num_experts_per_tok)[1],
+                            e, dtype=jnp.float32)                # (B, k, E)
+                        active = oh.sum(axis=1) > 0
+                        critical = jnp.ones(active.shape, bool)
                 else:
-                    if dymoe_on:
-                        # Eq. (3): gate-guided importance (batch-mean gate)
-                        logits_r = hflat.astype(jnp.float32) @ lp["moe"][
-                            "wg_router"]
-                        imp = jax.nn.softmax(logits_r, axis=-1).mean(axis=0)
-                        critical = select_critical(imp, xs_l["t_l"])
                     y, stats = moe_apply_sharded(
                         lp["moe"], cfg, hflat, critical_mask=critical,
                         qweights=xs_l["q"]["moe"] if dymoe_on else None)
                     active = stats.expert_load > 0
-                    gate_mean = stats.gate_mean
                     if critical is None:
                         critical = jnp.ones((e,), bool)
-                x = x + y.reshape(b, 1, -1)
-                pg = predict_next_gates(hflat, xs_l["next_router"])
-                if per_row_moe:
-                    # per-row Eq. (8): each row's own predicted demand
-                    freq = jax.vmap(lambda g: prefetch_targets(
-                        g[None], cfg.num_experts_per_tok,
-                        pol.prefetch_topk)[1])(pg)               # (B, E)
-                else:
-                    _, freq = prefetch_targets(pg, cfg.num_experts_per_tok,
-                                               pol.prefetch_topk)
-                telem = dict(
-                    critical=critical,
-                    active=active,
-                    gate_mean=gate_mean,
-                    pred=freq,
-                )
+                with jax.named_scope("moe_combine"):
+                    x = x + y.reshape(b, 1, -1)
+                with jax.named_scope("router"):
+                    pg = predict_next_gates(hflat, xs_l["next_router"])
+                    if per_row_moe:
+                        # per-row Eq. (8): each row's own predicted demand
+                        freq = jax.vmap(lambda g: prefetch_targets(
+                            g[None], cfg.num_experts_per_tok,
+                            pol.prefetch_topk)[1])(pg)           # (B, E)
+                    else:
+                        _, freq = prefetch_targets(
+                            pg, cfg.num_experts_per_tok, pol.prefetch_topk)
+                telem = dict(critical=critical, active=active, pred=freq)
         else:  # ssm
             h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
             sp = lp["ssm"]
@@ -854,8 +873,9 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
         carry0 = (x,)
     carry, ys = _scan_blocks(cfg, body, carry0, xs)
     x = carry[0]
-    x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
-    logits = _lm_head(params, cfg, x[:, 0])
+    with jax.named_scope("lm_head"):
+        x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
+        logits = _lm_head(params, cfg, x[:, 0])
 
     new_caches: Dict[str, Any] = {"layers": ys["cache"]}
     if hybrid:
@@ -864,7 +884,6 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
     if kind == "attn_moe":
         info.critical_masks = ys["critical"]
         info.active_masks = ys["active"]
-        info.gate_mean = ys["gate_mean"]
         info.predicted_next = ys["pred"].at[-1].set(0.0)
     return logits, new_caches, info
 
@@ -927,15 +946,17 @@ def decode_many(params, cfg: ModelConfig, tokens: jnp.ndarray, caches: Any,
         tok, caches, key = carry
         logits, caches, info = decode_step(params, cfg, tok, caches,
                                            qparams=qparams)
-        if row_mode:
-            keys_i = jax.vmap(lambda k: jax.random.fold_in(k, i))(row_keys)
-            nxt = sample_token_rows(logits, keys_i, row_temperatures,
-                                    row_top_ks)
-        elif greedy:
-            nxt = sample_token(logits)
-        else:
-            nxt = sample_token(logits, jax.random.fold_in(key, i),
-                               temperature=temperature, top_k=top_k)
+        with jax.named_scope("sample"):
+            if row_mode:
+                keys_i = jax.vmap(lambda k: jax.random.fold_in(k, i))(
+                    row_keys)
+                nxt = sample_token_rows(logits, keys_i, row_temperatures,
+                                        row_top_ks)
+            elif greedy:
+                nxt = sample_token(logits)
+            else:
+                nxt = sample_token(logits, jax.random.fold_in(key, i),
+                                   temperature=temperature, top_k=top_k)
         return (nxt, caches, key), (nxt, info)
 
     (_, caches, _), (toks, infos) = jax.lax.scan(
@@ -955,13 +976,10 @@ def _mask_info_rows(info: DyMoEInfo, live: jnp.ndarray) -> DyMoEInfo:
     def mb(x):
         return None if x is None else x & m
 
-    def mf(x):
-        return None if x is None else x * m
-
     return DyMoEInfo(critical_masks=mb(info.critical_masks),
                      active_masks=mb(info.active_masks),
-                     gate_mean=mf(info.gate_mean),
-                     predicted_next=mf(info.predicted_next))
+                     predicted_next=(None if info.predicted_next is None
+                                     else info.predicted_next * m))
 
 
 def decode_many_batched(params, cfg: ModelConfig, tokens: jnp.ndarray,
@@ -1024,18 +1042,20 @@ def decode_many_batched(params, cfg: ModelConfig, tokens: jnp.ndarray,
         logits, new_caches, info = decode_step(
             params, cfg, tok, caches, qparams=qparams, per_row_moe=True,
             live_rows=live, moe_capacity=live_cap)
-        if rng_keys is None:
-            nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        else:
-            keys = jax.vmap(jax.random.fold_in)(rng_keys, emitted)
-            nxt = sample_token_rows(logits, keys, temperatures, top_ks)
-        nxt = jnp.where(dn, tok, nxt)
+        with jax.named_scope("sample"):
+            if rng_keys is None:
+                nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            else:
+                keys = jax.vmap(jax.random.fold_in)(rng_keys, emitted)
+                nxt = sample_token_rows(logits, keys, temperatures, top_ks)
+            nxt = jnp.where(dn, tok, nxt)
 
         def freeze(new, old):  # finished rows' caches must not advance
             mask = live.reshape((1, -1) + (1,) * (new.ndim - 2))
             return jnp.where(mask, new, old)
 
-        caches = _tmap(freeze, new_caches, caches)
+        with jax.named_scope("kv_freeze"):
+            caches = _tmap(freeze, new_caches, caches)
         emitted = emitted + live.astype(jnp.int32)
         dn = dn | ((eos_tokens >= 0) & (nxt == eos_tokens)) \
             | (emitted >= limits)

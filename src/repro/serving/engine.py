@@ -65,7 +65,7 @@ import dataclasses
 import queue as _queue
 import threading
 import time
-from functools import partial
+from functools import partial, update_wrapper
 from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -84,6 +84,7 @@ from repro.serving.cost_model import EdgeCostModel, EdgeProfile, expert_bytes
 from repro.serving.request import Request, RequestHandle
 from repro.serving.sampler import raw_key_data, resolve_sampling, \
     sample_token, sample_token_rows
+from repro.serving.spans import span
 
 __all__ = ["EngineConfig", "DyMoEEngine", "GenerationResult",
            "ReplayStream"]
@@ -156,7 +157,9 @@ class ReplayStream:
                 self._poisoned = True
                 raise
             return
-        self._q.put(job)
+        # a full queue blocks here: the span's length is the backpressure
+        with span("replay_submit", depth=self._q.qsize()):
+            self._q.put(job)
 
     def drain(self) -> None:
         """Block until every submitted job has run (or been skipped after
@@ -226,6 +229,12 @@ class GenerationResult:
     preempted: int = 0
 
 
+def _bind_cfg(f: Callable, cfg: ModelConfig) -> Callable:
+    """``partial(f, cfg=cfg)`` under ``f``'s own name, so that its jitted
+    program reads ``jit_<f>`` in a profile, not ``jit__unknown``."""
+    return update_wrapper(partial(f, cfg=cfg), f)
+
+
 class DyMoEEngine:
     def __init__(self, cfg: ModelConfig, params, engine_cfg: EngineConfig
                  = EngineConfig(), faults=None, *, mesh=None,
@@ -282,20 +291,20 @@ class DyMoEEngine:
                     f"divide the {mesh.shape[MODEL_AXIS]}-way "
                     f"{MODEL_AXIS!r} mesh axis")
             cfg = dataclasses.replace(cfg, expert_mesh=mesh)
-        self._prefill = jax.jit(partial(prefill, cfg=cfg),
+        self._prefill = jax.jit(_bind_cfg(prefill, cfg),
                                 static_argnames=("cache_slots",
                                                  "row_local"))
         # num_steps sets the scan length and top_k shapes lax.top_k, so
         # they are static; temperature stays traced — serving mixed
         # per-request temperatures must not recompile the decode scan
         self._decode_many = jax.jit(
-            partial(decode_many, cfg=cfg),
+            _bind_cfg(decode_many, cfg),
             static_argnames=("num_steps", "top_k"))
         # slot-batched decode with per-row done-masks (the continuous-
         # batching scheduler's device half); live_cap sizes the fused
         # MoE kernel's capacity regions to the chunk's live-slot count
         self._decode_batched = jax.jit(
-            partial(decode_many_batched, cfg=cfg),
+            _bind_cfg(decode_many_batched, cfg),
             static_argnames=("num_steps", "live_cap"))
         self._orch: Optional[DynamicExpertOrchestrator] = None
         self._session = None   # engine-owned step-driven serving session

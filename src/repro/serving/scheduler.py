@@ -133,6 +133,7 @@ from repro.serving.policy import SchedulingPolicy, SLOPressure, \
 from repro.serving.request import Request, RequestHandle, TokenChunk
 from repro.serving.sampler import raw_key_data, resolve_sampling, \
     sample_token_rows
+from repro.serving.spans import group_bytes, live_groups, span
 
 __all__ = ["SchedulerConfig", "ContinuousBatchingScheduler",
            "live_cap_for"]
@@ -327,6 +328,9 @@ class ContinuousBatchingScheduler:
         # while ONE thread drives step()
         self._lock = threading.Lock()
         self._n_chunks = 0
+        # trace indices: spans of one boundary / admission wave share them
+        self._n_boundaries = 0
+        self._n_waves = 0
         # fault-tolerance state — lives on the instance from birth so
         # health() is answerable before the session lazily starts
         self._health = SessionHealth()
@@ -429,6 +433,11 @@ class ContinuousBatchingScheduler:
         self._chunk = engine.ecfg.decode_chunk
         self._can_batch = self._can_batch_admissions()
         self._orch = engine._make_orchestrator()  # ONE shared cache+clock
+        # packed bytes of one live (expert, precision) group: the replay's
+        # kernel-weight counter, where decode runs the grouped kernel
+        self._group_bytes = (group_bytes(engine.qparams)
+                             if cfg.is_moe and engine.qparams is not None
+                             and cfg.dymoe.enabled else None)
         b = self._b
         self._states: List[Optional[_SlotState]] = [None] * b
         self._caches = engine.shard_decode_state(
@@ -604,16 +613,19 @@ class ContinuousBatchingScheduler:
             raise SessionClosed("serving session is closed")
         if not self._started:
             return False
-        progress = self._recover_replay()
-        progress |= self._shed_expired()
-        progress |= self._sweep_cancelled()
-        self._update_pressure()
-        progress |= self._preempt_boundary()
-        progress |= self._admit_boundary()
-        if self._done.all():
-            return progress
-        self._dispatch_chunk()
-        return True
+        boundary = self._n_boundaries
+        self._n_boundaries += 1
+        with span("step", boundary=boundary):
+            progress = self._recover_replay()
+            progress |= self._shed_expired()
+            progress |= self._sweep_cancelled()
+            self._update_pressure()
+            progress |= self._preempt_boundary()
+            progress |= self._admit_boundary()
+            if self._done.all():
+                return progress
+            self._dispatch_chunk()
+            return True
 
     def _shed_expired(self) -> bool:
         """Shed queued requests whose wall-clock deadline
@@ -835,7 +847,6 @@ class ContinuousBatchingScheduler:
         one-at-a-time admission loop would make. Survivors are
         scattered into their slots with one donated injection per
         wave."""
-        engine, cfg = self.engine, self.engine.cfg
         free = [r for r in range(self._b)
                 if self._done[r] and self._states[r] is None]
         if not free or not self._queue:
@@ -851,7 +862,7 @@ class ContinuousBatchingScheduler:
                         self._policy.order(list(self._queue), now0))
         n_survivors = 0
         cap: Optional[int] = None   # ladder: bound on a retried wave size
-        waves = []   # (rcaches, src rows, first tokens, states)
+        landed: List[tuple] = []    # (state, slot) of earlier waves' rows
         while n_survivors < len(free) and self._queue:
             room = len(free) - n_survivors
             if cap is not None:
@@ -867,142 +878,165 @@ class ContinuousBatchingScheduler:
             now = time.perf_counter()
             lens = [h.request.prompt_len for h in cands]
             n = len(cands)
-            batched = n > 1
-            try:
-                self._faults.fire("admit.alloc", n=n)
-                if batched:
-                    smax = max(lens)
-                    prompts = np.zeros((n, smax), np.int32)
-                    for i, h in enumerate(cands):
-                        prompts[i, smax - lens[i]:] = \
-                            h.request.prompt_tokens
-                    logits, rcaches, info = engine._prefill(
-                        engine.params, tokens=jnp.asarray(prompts),
-                        qparams=engine.qparams,
-                        cache_slots=self._slots_len,
-                        lengths=jnp.asarray(lens, jnp.int32),
-                        row_local=True,
-                        # exact host-side solo capacities: the in-graph
-                        # f32 formula can truncate one slot differently
-                        row_capacities=jnp.asarray(
-                            [_capacity(cfg, s) for s in lens], jnp.int32)
-                        if cfg.is_moe else None)
-                else:  # exact-shape solo program (also the SSM/hybrid path)
-                    prompt = jnp.asarray(
-                        cands[0].request.prompt_tokens, jnp.int32)[None, :]
-                    logits, rcaches, info = engine._prefill(
-                        engine.params, tokens=prompt,
-                        qparams=engine.qparams,
-                        cache_slots=self._slots_len)
-                # the wave's ONE host sync: every candidate's first token.
-                # Sampled candidates draw through the per-row sampler with
-                # fold count 0 — bit-identical to solo ``sample_token``
-                # over the (1, V) row (greedy rows take the same argmax)
-                if any(h.temperature > 0.0 for h in cands):
-                    keys = np.zeros((n, 2), np.uint32)
-                    for i, h in enumerate(cands):
-                        if h.key is not None:
-                            keys[i] = h.key
-                    keys0 = jax.vmap(lambda k: jax.random.fold_in(k, 0))(
-                        jnp.asarray(keys))
-                    first = np.asarray(jax.device_get(sample_token_rows(
-                        logits, keys0,
-                        jnp.asarray([h.temperature for h in cands],
-                                    jnp.float32),
-                        jnp.asarray([h.top_k for h in cands], jnp.int32))),
-                        np.int32)
-                else:
-                    first = np.asarray(
-                        jax.device_get(jnp.argmax(logits, axis=-1)),
-                        np.int32)
-            except InjectedFault as e:
-                # --- admission degradation ladder: requeue the wave and
-                # retry at half size; a single candidate that still fails
-                # resolves with a typed AdmissionError. Splitting a wave
-                # is bit-identical for its survivors (per-candidate
-                # replay order and row-local prefill rows are unchanged)
-                self._last_fault = e
-                self._health.last_fault = repr(e)
-                if n > 1:
-                    with self._lock:
-                        for h in reversed(cands):
-                            self._queue.appendleft(h)
-                    self._health.admission_retries += 1
-                    cap = max(1, n // 2)
+            wave = self._n_waves
+            self._n_waves += 1
+            with span("admit", wave=wave, rows=n, longest_prompt=max(lens),
+                      queue_wait_ms_max=1e3 * max(now - h.submit_t
+                                                  for h in cands)):
+                try:
+                    rcaches, first, tele = self._prefill_wave(cands, lens,
+                                                              wave)
+                except InjectedFault as e:
+                    # --- admission degradation ladder: requeue the wave
+                    # and retry at half size; a single candidate that
+                    # still fails resolves with a typed AdmissionError.
+                    # Splitting a wave is bit-identical for its survivors
+                    # (per-candidate replay order and row-local prefill
+                    # rows are unchanged)
+                    self._last_fault = e
+                    self._health.last_fault = repr(e)
+                    if n > 1:
+                        with self._lock:
+                            for h in reversed(cands):
+                                self._queue.appendleft(h)
+                        self._health.admission_retries += 1
+                        cap = max(1, n // 2)
+                        continue
+                    self._health.admission_failures += 1
+                    err = AdmissionError(
+                        f"{cands[0].request_id}: admission prefill failed "
+                        f"even as a solo wave ({e!r})")
+                    err.__cause__ = e
+                    cands[0]._finish_error(err)
                     continue
-                self._health.admission_failures += 1
-                err = AdmissionError(
-                    f"{cands[0].request_id}: admission prefill failed "
-                    f"even as a solo wave ({e!r})")
-                err.__cause__ = e
-                cands[0]._finish_error(err)
-                continue
-            except Exception as e:
-                # a real compile/device error: fail this wave and the
-                # earlier waves' survivors (popped, not yet in a slot)
-                popped = cands + [st.handle for w in waves for st in w[3]]
-                self._health.admission_failures += len(popped)
-                self._fail_unretried(e, popped, AdmissionError,
-                                     "admission prefill")
-                raise
-            cap = None   # a clean wave resets the ladder
-            wave_states: List[_SlotState] = []
-            wave_src: List[int] = []
-            wave_tok: List[int] = []
-            wave_surv: List[_SlotState] = []
-            for i, h in enumerate(cands):
-                req = h.request
-                ft = int(first[i])
-                st = _SlotState(
-                    handle=h, request=req, tokens=[ft],
-                    prompt_len=lens[i], admit_t=now,
-                    queue_wait_s=now - h.submit_t,
-                    finish_now=(req.max_new_tokens <= 1
-                                or (req.eos_token is not None
-                                    and ft == req.eos_token)))
-                st.decode_t0 = time.perf_counter()
-                wave_states.append(st)
-                if not st.finish_now:
-                    wave_src.append(i)
-                    wave_tok.append(ft)
-                    wave_surv.append(st)
-            self._submit_replay(partial(
-                self._replay_prefill, wave_states,
-                (info.critical_masks, info.active_masks,
-                 info.predicted_next), batched),
-                [st.handle for st in wave_states])
-            # decode-wall clock: starts AFTER the prefill replay
-            # (inline in serial mode), mirroring solo generate's t_dec —
-            # so measured decode throughput excludes prefill + its replay
-            t_dec = time.perf_counter()
-            for st in wave_surv:
-                st.decode_t0 = t_dec
-            if wave_src:
-                waves.append((rcaches, wave_src, wave_tok, wave_surv))
-                n_survivors += len(wave_src)
-        # survivors claim free slots in pop order (== the order the
-        # one-at-a-time admission loop would have filled them)
-        fi = 0
-        for rc, src, toks, sts in waves:
-            dst = free[fi:fi + len(src)]
-            fi += len(src)
-            for st, r in zip(sts, dst):
-                h = st.handle
-                self._states[r] = st
-                self._done[r] = False
-                self._emitted[r] = 1
-                self._limits[r] = st.request.max_new_tokens
-                self._eos[r] = (-1 if st.request.eos_token is None
-                                else st.request.eos_token)
-                self._temps[r] = h.temperature
-                self._topks[r] = h.top_k
-                self._keys[r] = h.key if h.key is not None else 0
-            self._caches = self._inject_rows(
-                self._caches, rc, jnp.asarray(src, jnp.int32),
-                jnp.asarray(dst, jnp.int32))
-            self._tok_d = self._tok_d.at[jnp.asarray(dst, jnp.int32)].set(
-                jnp.asarray(toks, jnp.int32))
+                except Exception as e:
+                    # a real compile/device error: fail this wave and the
+                    # earlier waves' survivors, whose slots are freed again
+                    for st, r in landed:
+                        self._states[r] = None
+                        self._done[r] = True
+                    popped = cands + [st.handle for st, _ in landed]
+                    self._health.admission_failures += len(popped)
+                    self._fail_unretried(e, popped, AdmissionError,
+                                         "admission prefill")
+                    raise
+                cap = None   # a clean wave resets the ladder
+                wave_states: List[_SlotState] = []
+                wave_src: List[int] = []
+                wave_tok: List[int] = []
+                wave_surv: List[_SlotState] = []
+                for i, h in enumerate(cands):
+                    req = h.request
+                    ft = int(first[i])
+                    st = _SlotState(
+                        handle=h, request=req, tokens=[ft],
+                        prompt_len=lens[i], admit_t=now,
+                        queue_wait_s=now - h.submit_t,
+                        finish_now=(req.max_new_tokens <= 1
+                                    or (req.eos_token is not None
+                                        and ft == req.eos_token)))
+                    st.decode_t0 = time.perf_counter()
+                    wave_states.append(st)
+                    if not st.finish_now:
+                        wave_src.append(i)
+                        wave_tok.append(ft)
+                        wave_surv.append(st)
+                self._submit_replay(partial(
+                    self._replay_prefill, wave, wave_states, tele, n > 1),
+                    [st.handle for st in wave_states])
+                # decode-wall clock: starts AFTER the prefill replay
+                # (inline in serial mode), mirroring solo generate's t_dec
+                # — so measured decode throughput excludes prefill + its
+                # replay
+                t_dec = time.perf_counter()
+                for st in wave_surv:
+                    st.decode_t0 = t_dec
+                if wave_src:
+                    # survivors claim free slots in pop order (== the
+                    # order the one-at-a-time admission loop would have
+                    # filled them)
+                    dst = free[n_survivors:n_survivors + len(wave_src)]
+                    self._land_wave(rcaches, wave_src, wave_tok, wave_surv,
+                                    dst)
+                    landed.extend(zip(wave_surv, dst))
+                    n_survivors += len(wave_src)
         return True
+
+    def _prefill_wave(self, cands: List[RequestHandle], lens: List[int],
+                      wave: int):
+        """Dispatch one admission wave's prefill — one ragged row-local
+        program for several candidates, the exact-shape solo program for
+        one — and fetch every candidate's first token: the wave's ONE
+        host sync. Returns (row caches, first tokens, the replay's
+        (critical, active, predicted) telemetry leaves)."""
+        engine, cfg = self.engine, self.engine.cfg
+        n = len(cands)
+        self._faults.fire("admit.alloc", n=n)
+        if n > 1:
+            smax = max(lens)
+            prompts = np.zeros((n, smax), np.int32)
+            for i, h in enumerate(cands):
+                prompts[i, smax - lens[i]:] = h.request.prompt_tokens
+            logits, rcaches, info = engine._prefill(
+                engine.params, tokens=jnp.asarray(prompts),
+                qparams=engine.qparams,
+                cache_slots=self._slots_len,
+                lengths=jnp.asarray(lens, jnp.int32),
+                row_local=True,
+                # exact host-side solo capacities: the in-graph f32
+                # formula can truncate one slot differently
+                row_capacities=jnp.asarray(
+                    [_capacity(cfg, s) for s in lens], jnp.int32)
+                if cfg.is_moe else None)
+        else:  # exact-shape solo program (also the SSM/hybrid path)
+            prompt = jnp.asarray(
+                cands[0].request.prompt_tokens, jnp.int32)[None, :]
+            logits, rcaches, info = engine._prefill(
+                engine.params, tokens=prompt,
+                qparams=engine.qparams,
+                cache_slots=self._slots_len)
+        # Sampled candidates draw through the per-row sampler with fold
+        # count 0 — bit-identical to solo ``sample_token`` over the (1, V)
+        # row (greedy rows take the same argmax)
+        if any(h.temperature > 0.0 for h in cands):
+            keys = np.zeros((n, 2), np.uint32)
+            for i, h in enumerate(cands):
+                if h.key is not None:
+                    keys[i] = h.key
+            keys0 = jax.vmap(lambda k: jax.random.fold_in(k, 0))(
+                jnp.asarray(keys))
+            first_d = sample_token_rows(
+                logits, keys0,
+                jnp.asarray([h.temperature for h in cands], jnp.float32),
+                jnp.asarray([h.top_k for h in cands], jnp.int32))
+        else:
+            first_d = jnp.argmax(logits, axis=-1)
+        with span("sync", wave=wave):
+            first = np.asarray(jax.device_get(first_d), np.int32)
+        return rcaches, first, (info.critical_masks, info.active_masks,
+                                info.predicted_next)
+
+    def _land_wave(self, rcaches, src: List[int], toks: List[int],
+                   states: List[_SlotState], dst: List[int]) -> None:
+        """Put an admission wave's surviving rows ``src`` into the free
+        slots ``dst``: their host bookkeeping, and one donated injection
+        of their caches plus their first tokens into the slot batch."""
+        for st, r in zip(states, dst):
+            h = st.handle
+            self._states[r] = st
+            self._done[r] = False
+            self._emitted[r] = 1
+            self._limits[r] = st.request.max_new_tokens
+            self._eos[r] = (-1 if st.request.eos_token is None
+                            else st.request.eos_token)
+            self._temps[r] = h.temperature
+            self._topks[r] = h.top_k
+            self._keys[r] = h.key if h.key is not None else 0
+        self._caches = self._inject_rows(
+            self._caches, rcaches, jnp.asarray(src, jnp.int32),
+            jnp.asarray(dst, jnp.int32))
+        self._tok_d = self._tok_d.at[jnp.asarray(dst, jnp.int32)].set(
+            jnp.asarray(toks, jnp.int32))
 
     # ---------------------------------------------------------- dispatch
     def _dispatch_chunk(self) -> None:
@@ -1043,19 +1077,22 @@ class ContinuousBatchingScheduler:
             try:
                 self._faults.fire("device.dispatch", chunk=self._n_chunks,
                                   num_steps=chunk, rows=len(live))
-                toks_d, caches, infos, done_d, emitted_d = \
-                    engine._decode_batched(
-                        engine.params, tokens=self._tok_d,
-                        caches=self._caches, num_steps=chunk,
-                        done=jnp.asarray(self._done | deferred),
-                        n_emitted=jnp.asarray(self._emitted),
-                        limits=jnp.asarray(self._limits),
-                        eos_tokens=jnp.asarray(self._eos),
-                        qparams=engine.qparams, live_cap=live_cap,
-                        **sample_kw)
+                with span("dispatch", chunk=self._n_chunks, rows=len(live),
+                          live_cap=live_cap, steps=chunk):
+                    toks_d, caches, infos, done_d, emitted_d = \
+                        engine._decode_batched(
+                            engine.params, tokens=self._tok_d,
+                            caches=self._caches, num_steps=chunk,
+                            done=jnp.asarray(self._done | deferred),
+                            n_emitted=jnp.asarray(self._emitted),
+                            limits=jnp.asarray(self._limits),
+                            eos_tokens=jnp.asarray(self._eos),
+                            qparams=engine.qparams, live_cap=live_cap,
+                            **sample_kw)
                 # the boundary sync: ONLY the small (B,) masks cross —
                 # the (T, L, B, E) telemetry stays behind for the worker
-                done_h, emitted_h = jax.device_get((done_d, emitted_d))
+                with span("sync", chunk=self._n_chunks):
+                    done_h, emitted_h = jax.device_get((done_d, emitted_d))
                 break
             except InjectedFault as e:
                 self._health.dispatch_retries += 1
@@ -1118,7 +1155,7 @@ class ContinuousBatchingScheduler:
                 self._states[r] = None  # evict: free to admit; the
                 #                         worker finalizes st later
         self._submit_replay(partial(
-            self._replay_chunk, toks_d,
+            self._replay_chunk, self._n_chunks, toks_d,
             (infos.critical_masks, infos.active_masks,
              infos.predicted_next), rows),
             [st.handle for _, st, _, _, _ in rows])
@@ -1285,58 +1322,72 @@ class ContinuousBatchingScheduler:
             wall_s=0.0, queue_wait_s=time.perf_counter() - h.submit_t,
             cancelled=True))
 
-    def _replay_prefill(self, wave: List[_SlotState], tele, per_row: bool
-                        ) -> None:
-        """Replay one admission wave's prefill telemetry, candidate by
-        candidate in pop order (the serial admission order), emit each
+    def _replay_prefill(self, index: int, wave: List[_SlotState], tele,
+                        per_row: bool) -> None:
+        """Replay admission wave ``index``'s prefill telemetry, candidate
+        by candidate in pop order (the serial admission order), emit each
         candidate's prefill TokenChunk, and finalize the one-token
         requests."""
-        engine = self.engine
-        self._faults.fire("replay.prefill", n=len(wave))
-        crit, act, pred = jax.device_get(tele)
-        for i, st in enumerate(wave):
-            if crit is None:
-                c = a = p = None
-            elif per_row:   # (L, B, E) row-local leaves -> this row
-                c, a, p = crit[:, i], act[:, i], pred[:, i]
-            else:           # solo admission: (L, E) leaves, B == 1
-                c, a, p = crit, act, pred
-            timings, totals, wbytes = engine._replay(
-                c, a, p, phase="prefill",
-                s_ctx=np.asarray([st.prompt_len]), s_q=st.prompt_len,
-                orch=self._orch)
-            st.ttft_s = (timings[0].total_s if timings else totals[0])
-            st.prefill_timing = timings[0] if timings else None
-            st.prefill_weight_bytes = wbytes
-            self._emit(st, "prefill", [st.tokens[0]], float(st.ttft_s), 0)
-            if st.finish_now:
-                self._finalize(st)
-
-    def _replay_chunk(self, toks_ref, tele, rows) -> None:
-        """Fetch + replay one decode chunk's telemetry: the job the
-        pipeline overlaps with the NEXT chunk's device dispatch."""
-        engine = self.engine
-        self._faults.fire("replay.chunk", rows=len(rows))
-        toks_np, crit, act, pred = jax.device_get((toks_ref,) + tele)
-        toks_np = np.asarray(toks_np)
-        for r, st, keep, ctx0, is_done in rows:
-            if keep:   # this row's live steps are the chunk's first
-                new = [int(t) for t in toks_np[:keep, r]]
-                st.tokens.extend(new)
-                # telemetry leaves are (T, L, B, E): this row's block
+        with span("replay", kind="prefill", wave=index, rows=len(wave)):
+            engine = self.engine
+            self._faults.fire("replay.prefill", n=len(wave))
+            crit, act, pred = jax.device_get(tele)
+            for i, st in enumerate(wave):
+                if crit is None:
+                    c = a = p = None
+                elif per_row:   # (L, B, E) row-local leaves -> this row
+                    c, a, p = crit[:, i], act[:, i], pred[:, i]
+                else:           # solo admission: (L, E) leaves, B == 1
+                    c, a, p = crit, act, pred
                 timings, totals, wbytes = engine._replay(
-                    None if crit is None else crit[:keep, :, r],
-                    None if act is None else act[:keep, :, r],
-                    None if pred is None else pred[:keep, :, r],
-                    phase="decode",
-                    s_ctx=ctx0 + np.arange(keep), s_q=1, orch=self._orch)
-                st.step_totals.extend(totals)
-                st.decode_timings.extend(timings)
-                st.decode_weight_bytes += wbytes
-                self._emit(st, "decode", new, float(sum(totals)),
-                           ctx0 - st.prompt_len)
-            if is_done:
-                self._finalize(st)
+                    c, a, p, phase="prefill",
+                    s_ctx=np.asarray([st.prompt_len]), s_q=st.prompt_len,
+                    orch=self._orch)
+                st.ttft_s = (timings[0].total_s if timings else totals[0])
+                st.prefill_timing = timings[0] if timings else None
+                st.prefill_weight_bytes = wbytes
+                self._emit(st, "prefill", [st.tokens[0]], float(st.ttft_s), 0)
+                if st.finish_now:
+                    self._finalize(st)
+
+    def _replay_chunk(self, index: int, toks_ref, tele, rows) -> None:
+        """Fetch + replay decode chunk ``index``'s telemetry: the job the
+        pipeline overlaps with the NEXT chunk's device dispatch. While the
+        profiler records, its span also counts the (expert, precision)
+        groups the chunk's grouped expert kernel calls found live, and the
+        packed bytes they hold."""
+        with span("replay", kind="chunk", chunk=index,
+                  rows=len(rows)) as sp:
+            engine = self.engine
+            self._faults.fire("replay.chunk", rows=len(rows))
+            toks_np, crit, act, pred = jax.device_get((toks_ref,) + tele)
+            toks_np = np.asarray(toks_np)
+            for r, st, keep, ctx0, is_done in rows:
+                if keep:   # this row's live steps are the chunk's first
+                    new = [int(t) for t in toks_np[:keep, r]]
+                    st.tokens.extend(new)
+                    # telemetry leaves are (T, L, B, E): this row's block
+                    timings, totals, wbytes = engine._replay(
+                        None if crit is None else crit[:keep, :, r],
+                        None if act is None else act[:keep, :, r],
+                        None if pred is None else pred[:keep, :, r],
+                        phase="decode",
+                        s_ctx=ctx0 + np.arange(keep), s_q=1, orch=self._orch)
+                    st.step_totals.extend(totals)
+                    st.decode_timings.extend(timings)
+                    st.decode_weight_bytes += wbytes
+                    self._emit(st, "decode", new, float(sum(totals)),
+                               ctx0 - st.prompt_len)
+                if is_done:
+                    self._finalize(st)
+            if (self._group_bytes is not None and crit is not None
+                    and jax.profiler.TraceAnnotation.is_enabled()):
+                hi, lo = live_groups(np.asarray(crit), np.asarray(act),
+                                     self.engine.cfg.dymoe.low_bits == 0)
+                sp.set_metadata(
+                    live_hi_groups=hi, live_lo_groups=lo,
+                    kernel_weight_bytes=(hi * self._group_bytes[0]
+                                         + lo * self._group_bytes[1]))
 
     # --------------------------------------------------------------- run
     def run(self, requests: Sequence[Request], *,

@@ -81,7 +81,7 @@ def test_moe_telemetry_matches_per_step_loop():
         got = np.asarray(getattr(infos, field))
         ref = np.stack([np.asarray(getattr(i, field)) for i in ref_infos])
         np.testing.assert_array_equal(got, ref, err_msg=field)
-    for field in ("gate_mean", "predicted_next"):
+    for field in ("predicted_next",):
         got = np.asarray(getattr(infos, field))
         ref = np.stack([np.asarray(getattr(i, field)) for i in ref_infos])
         np.testing.assert_allclose(got, ref, rtol=1e-6, atol=1e-7,
