@@ -31,6 +31,10 @@ one combined buffer and one ``(E * P, M/bm, N/bn, K/bk)`` grid whose
 scalar-prefetch operand is a per-(expert, precision-group) live-slot
 watermark table — the second dispatch, the second weight unpack, and every
 dead row block (finished/evicted/padded slots) disappear from the grid.
+Where its x is bf16 and its row blocks are short (decode; admission waves
+of a few rows; :func:`_scale_after_dot`), its body feeds the MXU the codes
+themselves as bf16 integers and applies the f32 group scales to the dot's
+per-group partial sums: no weight-side dequant and no f32 dot.
 """
 from __future__ import annotations
 
@@ -44,7 +48,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels.quant_matmul.quant_matmul import _unpack_dequant
 
-__all__ = ["expert_quant_matmul_pallas", "expert_quant_matmul_grouped_pallas"]
+__all__ = ["expert_quant_matmul_pallas", "expert_quant_matmul_grouped_pallas",
+           "grouped_scales_after_dot"]
 
 
 def _dual_kernel(crit_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref, o_ref,
@@ -90,9 +95,87 @@ def _skip_kernel(crit_ref, x_ref, hp_ref, hs_ref, o_ref, acc_ref, *,
         o_ref[0] = acc_ref[...].astype(o_ref.dtype)
 
 
+def _scale_after_dot(bm: int, bk: int, group_size: int, dtype) -> bool:
+    """Whether the grouped kernel applies its group scales after the dot.
+
+    That body (:func:`_dot_scale_after`) feeds the MXU a block-diagonal x
+    of ``G * bm`` rows, ``G = bk / group_size``: one bf16 pass whose cost
+    grows with ``G * bm`` while the weight-side dequant it replaces does
+    not. On a v5e chip it is faster at 128 to 512 rows and slower at 1024,
+    so it is taken up to 512 rows: every decode block and the admission
+    waves of a few rows. x must already be bf16, so that feeding it to a
+    bf16 pass rounds nothing."""
+    return (jnp.dtype(dtype) == jnp.bfloat16
+            and bm * (bk // group_size) <= 512)
+
+
+def _plane_major(x: jnp.ndarray, bk: int, bits: int) -> jnp.ndarray:
+    """(E, R, K) -> (E, R, K): within each bk block, the columns in the
+    order :func:`_codes_plane_major` unpacks the codes: the value of bit
+    plane ``j`` and byte ``b`` is ``k = b * vpb + j`` (quant/packing.py)."""
+    vpb = 8 // bits
+    if vpb == 1:
+        return x
+    e, r, k = x.shape
+    return x.reshape(e, r, k // bk, bk // vpb, vpb).swapaxes(3, 4).reshape(
+        e, r, k)
+
+
+def _codes_plane_major(packed_tile: jnp.ndarray, bits: int) -> jnp.ndarray:
+    """(bn, bk/vpb) uint8 codes -> (bn, bk) bf16 codes minus offset, bit
+    planes side by side along the lanes; every value is an integer of at
+    most 8 bits, so bf16 holds it exactly."""
+    vpb = 8 // bits
+    offset = 1 << (bits - 1)
+    mask = (1 << bits) - 1
+    codes = packed_tile.astype(jnp.int32)
+    planes = [((codes >> (bits * j)) & mask) - offset for j in range(vpb)]
+    q = planes[0] if vpb == 1 else jnp.concatenate(planes, axis=1)
+    return q.astype(jnp.float32).astype(jnp.bfloat16)
+
+
+def _dot_scale_after(x: jnp.ndarray, packed_tile: jnp.ndarray,
+                     scales_tile: jnp.ndarray, bits: int,
+                     group_size: int) -> jnp.ndarray:
+    """(bm, bk) bf16 x in plane-major column order, (bn, bk/vpb) codes and
+    (G, bn) f32 scales -> (bm, bn) f32, the scales applied after the dot.
+
+    Row block ``g`` of the (G * bm, bk) block-diagonal x keeps only the
+    columns of scale group ``g``, so one bf16 x bf16 -> f32 pass against
+    the codes (contracted on both operands' lanes, no transpose) gives
+    every group's partial sum; each is scaled in f32 and summed. Only the
+    f32 summation order differs from scaling the weights first."""
+    bm, bk = x.shape
+    g = scales_tile.shape[0]
+    vpb = 8 // bits
+    span = group_size // vpb            # a group's columns in one plane
+    col = jax.lax.broadcasted_iota(jnp.int32, (g, 1, bk), 2)
+    lo = jax.lax.broadcasted_iota(jnp.int32, (g, 1, bk), 0) * span
+    keep = functools.reduce(jnp.logical_or, [
+        (col >= lo + j * (bk // vpb)) & (col < lo + j * (bk // vpb) + span)
+        for j in range(vpb)])
+    x_bd = jnp.where(keep, x.astype(jnp.float32)[None], 0.0)
+    x_bd = x_bd.reshape(g * bm, bk).astype(jnp.bfloat16)
+    part = jax.lax.dot_general(
+        x_bd, _codes_plane_major(packed_tile, bits),
+        (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32)
+    return (part.reshape(g, bm, -1) * scales_tile[:, None, :]).sum(axis=0)
+
+
+def _grouped_tile(x, packed_tile, scales_tile, bits, group_size,
+                  scale_after_dot):
+    """One live grid step's (bm, bn) f32 partial product."""
+    if scale_after_dot:
+        return _dot_scale_after(x, packed_tile, scales_tile, bits,
+                                group_size)
+    w = _unpack_dequant(packed_tile, scales_tile, bits, group_size)
+    return jnp.dot(x.astype(jnp.float32), w,
+                   preferred_element_type=jnp.float32)
+
+
 def _grouped_dual_kernel(nb_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref,
                          o_ref, acc_ref, *, hi_bits, lo_bits, group_size,
-                         nk):
+                         nk, scale_after_dot):
     g = pl.program_id(0)
     i = pl.program_id(1)
     kk = pl.program_id(3)
@@ -106,14 +189,12 @@ def _grouped_dual_kernel(nb_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref,
     # dispatch, so skipping reproduces their dot exactly)
     @pl.when(i < nb_ref[g])
     def _compute():
-        w = jax.lax.cond(
+        acc_ref[...] += jax.lax.cond(
             g % 2 == 0,
-            lambda: _unpack_dequant(hp_ref[0], hs_ref[0], hi_bits,
-                                    group_size),
-            lambda: _unpack_dequant(lp_ref[0], ls_ref[0], lo_bits,
-                                    group_size))
-        x = x_ref[0].astype(jnp.float32)
-        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+            lambda: _grouped_tile(x_ref[0], hp_ref[0], hs_ref[0], hi_bits,
+                                  group_size, scale_after_dot),
+            lambda: _grouped_tile(x_ref[0], lp_ref[0], ls_ref[0], lo_bits,
+                                  group_size, scale_after_dot))
 
     @pl.when(kk == nk - 1)
     def _done():
@@ -121,7 +202,7 @@ def _grouped_dual_kernel(nb_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref,
 
 
 def _grouped_skip_kernel(nb_ref, x_ref, hp_ref, hs_ref, o_ref, acc_ref, *,
-                         hi_bits, group_size, nk):
+                         hi_bits, group_size, nk, scale_after_dot):
     g = pl.program_id(0)
     i = pl.program_id(1)
     kk = pl.program_id(3)
@@ -132,9 +213,8 @@ def _grouped_skip_kernel(nb_ref, x_ref, hp_ref, hs_ref, o_ref, acc_ref, *,
 
     @pl.when(i < nb_ref[g])
     def _compute():
-        w = _unpack_dequant(hp_ref[0], hs_ref[0], hi_bits, group_size)
-        x = x_ref[0].astype(jnp.float32)
-        acc_ref[...] += jnp.dot(x, w, preferred_element_type=jnp.float32)
+        acc_ref[...] += _grouped_tile(x_ref[0], hp_ref[0], hs_ref[0],
+                                      hi_bits, group_size, scale_after_dot)
 
     @pl.when(kk == nk - 1)
     def _done():
@@ -148,6 +228,21 @@ def _row_block(block_m: int, rows: int, *dtypes) -> int:
     that of a block's second-minor dim; the wrappers zero-pad M up to it."""
     sub = max(32 // jnp.dtype(d).itemsize for d in dtypes)
     return -(-min(block_m, rows) // sub) * sub
+
+
+def _k_block(block_k: int, k: int, group_size: int) -> int:
+    """Contraction tile: at most ``block_k``, a whole number of groups."""
+    return max(group_size, (min(block_k, k) // group_size) * group_size)
+
+
+def grouped_scales_after_dot(cap: int, k: int, *, group_size: int,
+                             block_m: int, block_k: int, dtype) -> bool:
+    """The body :func:`expert_quant_matmul_grouped_pallas` runs for regions
+    of ``cap`` rows, contraction ``k`` and activations and output of
+    ``dtype``: True where it applies the group scales after the dot."""
+    return _scale_after_dot(_row_block(block_m, cap, dtype, dtype),
+                            _k_block(block_k, k, group_size), group_size,
+                            dtype)
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
@@ -325,24 +420,26 @@ def expert_quant_matmul_grouped_pallas(
 
     cap = max(cap_hi, cap_lo)
     bm = _row_block(block_m, cap, x.dtype, out_dtype)
-    bn, bk = min(block_n, n), min(block_k, k)
-    bk = max(group_size, (bk // group_size) * group_size)
+    bn, bk = min(block_n, n), _k_block(block_k, k, group_size)
     assert k % group_size == 0, (k, group_size)
+
+    after = _scale_after_dot(bm, bk, group_size, x.dtype)
 
     # both regions are padded to the SAME m-block count so every group's
     # output tile index stays in range regardless of the cap split
     nb_cap = -(-cap // bm)
     rows = nb_cap * bm
 
-    def region(lo_, hi_):
+    def region(lo_, hi_, bits):
         r = x[:, lo_:hi_]
         pad = rows - r.shape[1]
-        return jnp.pad(r, ((0, 0), (0, pad), (0, 0))) if pad else r
+        r = _pad_to(jnp.pad(r, ((0, 0), (0, pad), (0, 0))) if pad else r,
+                    2, bk)
+        return _plane_major(r, bk, bits) if after else r
 
-    xr = region(0, cap_hi)
+    xp = region(0, cap_hi, hi_bits)
     if has_lo:
-        xr = jnp.concatenate([xr, region(cap_hi, m)], axis=1)
-    xp = _pad_to(xr, 2, bk)
+        xp = jnp.concatenate([xp, region(cap_hi, m, lo_bits)], axis=1)
     hp = _pad_to(_pad_to(hi_packed, 1, bn), 2, bk // vpb_hi)
     hs = _pad_to(_pad_to(hi_scales, 1, bk // group_size), 2, bn)
     if has_lo:
@@ -399,10 +496,11 @@ def expert_quant_matmul_grouped_pallas(
         operands += [lp, ls]
         kernel = functools.partial(_grouped_dual_kernel, hi_bits=hi_bits,
                                    lo_bits=lo_bits, group_size=group_size,
-                                   nk=nk)
+                                   nk=nk, scale_after_dot=after)
     else:
         kernel = functools.partial(_grouped_skip_kernel, hi_bits=hi_bits,
-                                   group_size=group_size, nk=nk)
+                                   group_size=group_size, nk=nk,
+                                   scale_after_dot=after)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,

@@ -37,7 +37,7 @@ from repro.sharding.partition import MODEL_AXIS
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_rows",
            "moe_apply_prefill_rows", "moe_apply_sharded", "quantize_moe",
-           "MoEStats"]
+           "scales_after_dot", "MoEStats"]
 
 
 @jax.tree_util.register_dataclass
@@ -121,6 +121,22 @@ def _moe_blocks(cfg: ModelConfig) -> dict:
     pol = cfg.dymoe
     return dict(block_m=pol.block_m, block_n=pol.block_n,
                 block_k=pol.block_k)
+
+
+def scales_after_dot(cfg: ModelConfig, capacity: int) -> bool:
+    """Whether the fused grouped dispatch's three expert matmuls, at
+    ``capacity`` rows per precision region, apply their group scales after
+    the dot: the static choice of
+    :func:`repro.kernels.quant_matmul.expert_quant_matmul.grouped_scales_after_dot`
+    for the activations' dtype and both contraction widths."""
+    from repro.kernels.quant_matmul.expert_quant_matmul import \
+        grouped_scales_after_dot
+
+    pol = cfg.dymoe
+    return all(grouped_scales_after_dot(
+        capacity, k, group_size=pol.group_size, block_m=pol.block_m,
+        block_k=pol.block_k, dtype=cfg.dtype)
+        for k in (cfg.d_model, cfg.expert_d_ff))
 
 
 def _over_local_experts(mesh, f, *args):

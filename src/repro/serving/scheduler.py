@@ -123,7 +123,7 @@ import numpy as np
 
 from repro.core.orchestrator import StepTiming
 from repro.models.kv_cache import KVCache
-from repro.models.layers.moe import _capacity
+from repro.models.layers.moe import _capacity, scales_after_dot
 from repro.models.model import init_decode_state
 from repro.serving.faults import NO_FAULTS, AdmissionError, \
     DeadlineExceeded, DispatchError, InjectedFault, QueueFull, \
@@ -882,7 +882,10 @@ class ContinuousBatchingScheduler:
             self._n_waves += 1
             with span("admit", wave=wave, rows=n, longest_prompt=max(lens),
                       queue_wait_ms_max=1e3 * max(now - h.submit_t
-                                                  for h in cands)):
+                                                  for h in cands),
+                      scaled_after_dot=self._scaled_after_dot(
+                          n * _capacity(self.engine.cfg, max(lens))
+                          if n > 1 else 0)):
                 try:
                     rcaches, first, tele = self._prefill_wave(cands, lens,
                                                               wave)
@@ -961,6 +964,15 @@ class ContinuousBatchingScheduler:
                     landed.extend(zip(wave_surv, dst))
                     n_survivors += len(wave_src)
         return True
+
+    def _scaled_after_dot(self, capacity: int) -> int:
+        """1 where the grouped expert kernel, at ``capacity`` rows per
+        precision region, applies its group scales after the dot; 0 where
+        it dequantizes the weights, or no grouped kernel runs
+        (``capacity`` 0: a one-row wave runs the solo program)."""
+        cfg = self.engine.cfg
+        return int(capacity > 0 and self._group_bytes is not None
+                   and scales_after_dot(cfg, capacity))
 
     def _prefill_wave(self, cands: List[RequestHandle], lens: List[int],
                       wave: int):
@@ -1077,8 +1089,10 @@ class ContinuousBatchingScheduler:
             try:
                 self._faults.fire("device.dispatch", chunk=self._n_chunks,
                                   num_steps=chunk, rows=len(live))
+                after = self._scaled_after_dot(live_cap)
                 with span("dispatch", chunk=self._n_chunks, rows=len(live),
-                          live_cap=live_cap, steps=chunk):
+                          live_cap=live_cap, steps=chunk,
+                          scaled_after_dot=after):
                     toks_d, caches, infos, done_d, emitted_d = \
                         engine._decode_batched(
                             engine.params, tokens=self._tok_d,

@@ -12,10 +12,10 @@ The spans (thread; stats):
 * ``dymoe.step`` (stepper; ``boundary``): one ``step()`` call, a chunk
   boundary;
 * ``dymoe.admit`` (stepper; ``wave``, ``rows``, ``longest_prompt``,
-  ``queue_wait_ms_max``): one admission wave — prefill dispatch,
-  first-token fetch, injection into the slot batch;
+  ``queue_wait_ms_max``, ``scaled_after_dot``): one admission wave —
+  prefill dispatch, first-token fetch, injection into the slot batch;
 * ``dymoe.dispatch`` (stepper; ``chunk``, ``rows``, ``live_cap``,
-  ``steps``): the enqueue of one decode chunk;
+  ``steps``, ``scaled_after_dot``): the enqueue of one decode chunk;
 * ``dymoe.sync`` (stepper; ``chunk`` or ``wave``): a blocking device
   fetch — the boundary's done/emitted masks, a wave's first tokens;
 * ``dymoe.replay_submit`` (stepper; ``depth``): handing a job to the
@@ -25,6 +25,11 @@ The spans (thread; stats):
   ``rows``): one replay job — telemetry fetch, orchestrator replay, token
   delivery. A chunk job also carries the counter of :func:`live_groups`
   as ``live_hi_groups``, ``live_lo_groups`` and ``kernel_weight_bytes``.
+
+``scaled_after_dot`` is 1 where the program's grouped expert kernel
+applies its group scales after the dot, 0 where it dequantizes the
+weights first or runs no grouped kernel (a one-row wave's solo prefill):
+the kernel's static choice for the program's shapes.
 """
 from __future__ import annotations
 

@@ -9,7 +9,11 @@ import pytest
 
 from repro.analysis import count_pallas_calls, intermediate_avals
 from repro.analysis.rules import FLOAT_DTYPES
+from repro.kernels.quant_matmul.expert_quant_matmul import \
+    _scale_after_dot, expert_quant_matmul_grouped_pallas, \
+    grouped_scales_after_dot
 from repro.kernels.quant_matmul.ops import expert_quant_matmul, force_impl
+from repro.kernels.quant_matmul.ref import expert_quant_matmul_grouped_ref
 from repro.models.config import DyMoEPolicy, ModelConfig
 from repro.models.layers.moe import init_moe, moe_apply, quantize_moe
 from repro.quant import MixedPrecisionWeights, mixed_precision_matmul
@@ -105,6 +109,77 @@ def test_vmaps_for_sharded_dispatch():
                                atol=1e-5, rtol=1e-5)
     np.testing.assert_allclose(np.asarray(ys[1]), 2 * np.asarray(ref),
                                atol=1e-4, rtol=1e-4)
+
+
+# ------------------------------------- grouped kernel, scales after dot
+# Decode shapes of OLMoE-1B-7B (w_gate/w_up K=2048 N=1024, w_down K=1024
+# N=2048) and Qwen3-30B-A3B (N=768; w_down K=768, padded to two K tiles)
+# at 16 rows a region, group 64, the serving tiles (bn 128, bk 512).
+
+
+@pytest.mark.parametrize("lo", [2, None], ids=["4/2", "4/0"])
+@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048), (2048, 768),
+                                 (768, 2048)])
+def test_grouped_scale_after_dot_matches_oracle(k, n, lo):
+    """bf16 codes on the MXU with the f32 group scales applied to the
+    per-group partial sums match the dequantize-first f32 oracle up to f32
+    summation order. Three experts hold watermarks 0, partial and full in
+    each region; the 2-bit region is live, as at admission prefill."""
+    cap, e, gs = 16, 3, 64
+    rng = np.random.default_rng(k + n)
+    w = jnp.asarray(rng.standard_normal((e, k, n)) / np.sqrt(k),
+                    jnp.float32)
+    mp = MixedPrecisionWeights.build(w, 4, lo, gs)
+    m = cap if lo is None else 2 * cap
+    counts = np.array([[0, cap], [5, 0], [cap, 9]], np.int32)
+    if lo is None:
+        counts[:, 1] = 0
+    x = rng.standard_normal((e, m, k)).astype(np.float32)
+    for ei, (c_hi, c_lo) in enumerate(counts):   # zero beyond watermarks
+        x[ei, c_hi:cap] = 0.0
+        x[ei, cap + c_lo:] = 0.0
+    x = jnp.asarray(x, jnp.bfloat16)
+    lp, ls = (None, None) if lo is None else (mp.low.packed, mp.low.scales)
+    assert _scale_after_dot(16, 512, gs, x.dtype)
+    ref = np.asarray(expert_quant_matmul_grouped_ref(
+        x, mp.high.packed, mp.high.scales, lp, ls, cap_hi=cap, hi_bits=4,
+        lo_bits=lo or 0, group_size=gs, out_dtype=jnp.float32))
+    pal = np.asarray(expert_quant_matmul_grouped_pallas(
+        x, mp.high.packed, mp.high.scales, lp, ls, jnp.asarray(counts),
+        cap_hi=cap, hi_bits=4, lo_bits=lo or 0, group_size=gs, block_m=32,
+        block_n=128, block_k=512, interpret=True, out_dtype=jnp.float32))
+    scale = np.abs(ref).max()
+    np.testing.assert_allclose(pal, ref, rtol=1e-5, atol=1e-5 * scale)
+    assert not pal[0, :cap].any() and not pal[1, cap:].any()
+
+
+def test_scale_after_dot_is_chosen_by_shape():
+    """Decode blocks of both served configurations take the new body at
+    every live_cap of the ladder, and so do admission waves of a few rows;
+    128-row blocks (an 8-row Qwen3 wave) and f32 activations keep the
+    dequantize-first body. The choice reads shapes and dtype alone."""
+    from repro.configs import get_config
+
+    for name in ("olmoe_1b_7b", "qwen3_30b_a3b"):
+        pol = get_config(name).dymoe
+        kw = dict(group_size=64, block_m=pol.block_m, block_k=pol.block_k)
+        for cap in (1, 2, 4, 8, 16):
+            for k in (2048, 1024, 768):
+                assert grouped_scales_after_dot(cap, k, dtype=jnp.bfloat16,
+                                                **kw)
+                assert not grouped_scales_after_dot(cap, k,
+                                                    dtype=jnp.float32, **kw)
+    assert grouped_scales_after_dot(80, 2048, group_size=64, block_m=32,
+                                    block_k=512, dtype=jnp.bfloat16)
+    assert not grouped_scales_after_dot(160, 2048, group_size=64,
+                                        block_m=128, block_k=512,
+                                        dtype=jnp.bfloat16)
+    # a function of (bm, bk, group_size, dtype): K beyond one tile and the
+    # rows beyond one block do not enter
+    assert _scale_after_dot(16, 512, 64, jnp.bfloat16) == \
+        grouped_scales_after_dot(3, 4096, group_size=64, block_m=16,
+                                 block_k=512, dtype=jnp.bfloat16)
+    assert not _scale_after_dot(128, 512, 64, jnp.bfloat16)
 
 
 # ------------------------------------------------ structural guarantee

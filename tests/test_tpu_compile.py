@@ -1,7 +1,8 @@
 """Ahead-of-time compiles for TPU v5e, without a chip.
 
 The expert kernels at OLMoE-1B-7B's widths (E=64, K=2048, N=1024, group
-64) go through the real v5e compiler here: interpret mode cannot see what
+64), and the grouped one at Qwen3-30B-A3B's (E=128, width 768), go through
+the real v5e compiler here: interpret mode cannot see what
 Mosaic refuses (a lane-splitting reshape, a row block that is not a whole
 sublane tile), and such a refusal costs chip time to find. The compiler
 also checks the expert-parallel MoE layer on a 2x2 topology: its kernels
@@ -25,7 +26,8 @@ from jax.sharding import AxisType, Mesh, PartitionSpec as P, \
 
 from repro.configs import get_config
 from repro.kernels.quant_matmul.expert_quant_matmul import \
-    expert_quant_matmul_grouped_pallas, expert_quant_matmul_pallas
+    expert_quant_matmul_grouped_pallas, expert_quant_matmul_pallas, \
+    grouped_scales_after_dot
 
 E, K, N, GS = 64, 2048, 1024, 64      # OLMoE-1B-7B expert w_gate / w_up
 
@@ -87,6 +89,35 @@ def test_grouped_kernel_compiles_for_v5e(one_chip, cap, lo_bits):
             lambda x, hp, hs, c: expert_quant_matmul_grouped_pallas(
                 x, hp, hs, None, None, c, **kw), x, hp, hs, counts)
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("cap", [1, 8, 16])
+@pytest.mark.parametrize("k,n", [(2048, 768), (768, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_kernel_compiles_for_v5e_at_qwen3_widths(one_chip, k, n,
+                                                         cap):
+    """Qwen3-30B-A3B's experts (E=128, width 768; w_down's K=768 pads to
+    two K tiles) at the decode capacities: the body that applies the
+    group scales after the dot is the one compiled, and the compiled
+    program still names the kernel the benchmark's trace reader finds."""
+    blocks = get_config("qwen3_30b_a3b").dymoe
+    e = 128
+    kw = dict(cap_hi=cap, hi_bits=4, lo_bits=2, group_size=GS,
+              block_m=blocks.block_m, block_n=blocks.block_n,
+              block_k=blocks.block_k)
+    assert grouped_scales_after_dot(cap, k, group_size=GS,
+                                    block_m=blocks.block_m,
+                                    block_k=blocks.block_k,
+                                    dtype=jnp.bfloat16)
+    hp, hs = _store(4, one_chip, e=e, n=n, k=k)
+    lp, ls = _store(2, one_chip, e=e, n=n, k=k)
+    x = _sds((e, 2 * cap, k), jnp.bfloat16, one_chip)
+    counts = _sds((e, 2), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda x, hp, hs, lp, ls, c: expert_quant_matmul_grouped_pallas(
+            x, hp, hs, lp, ls, c, **kw), x, hp, hs, lp, ls, counts)
+    assert "tpu_custom_call" in text
+    assert "expert_quant_matmul_grouped_pallas" in text
 
 
 @pytest.mark.parametrize("rows", [1, 80])

@@ -2,6 +2,7 @@
 scopes inside them, ``dymoe.*`` host spans in the profiler's trace, and the
 live-group counter of the grouped expert kernel — computed only while the
 profiler records."""
+import dataclasses
 import glob
 import os
 import re
@@ -138,29 +139,40 @@ def _serve(eng):
     session.close()
 
 
-def test_session_spans_under_the_profiler(engine, tmp_path):
+def _traced_spans(eng, tmp_path):
+    """(thread, name, stats) of every ``dymoe.*`` span of one session
+    served under the profiler; threads may share a name."""
     from jax.profiler import ProfileData
 
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
     jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
     try:
-        _serve(engine)
+        _serve(eng)
     finally:
         jax.profiler.stop_trace()
     (path,) = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
                         recursive=True)
-    spans = []   # (thread, name, stats); threads may share a name
+    spans = []
     for plane in ProfileData.from_file(path).planes:
         for i, line in enumerate(plane.lines):
             for e in line.events:
                 if e.name.startswith("dymoe."):
                     spans.append((f"{plane.name}#{i}", e.name,
                                   dict(e.stats)))
+    return spans
+
+
+def _of(spans, name, **match):
+    return [st for _, nm, st in spans if nm == f"dymoe.{name}"
+            and all(st.get(k) == v for k, v in match.items())]
+
+
+def test_session_spans_under_the_profiler(engine, tmp_path):
+    spans = _traced_spans(engine, tmp_path)
 
     def of(name, **match):
-        return [st for _, nm, st in spans if nm == f"dymoe.{name}"
-                and all(st.get(k) == v for k, v in match.items())]
+        return _of(spans, name, **match)
 
     assert {nm for _, nm, _ in spans} == {
         "dymoe.step", "dymoe.admit", "dymoe.dispatch", "dymoe.sync",
@@ -170,8 +182,10 @@ def test_session_spans_under_the_profiler(engine, tmp_path):
     for st in of("admit"):
         assert st["rows"] >= 1 and st["longest_prompt"] >= 6
         assert st["queue_wait_ms_max"] >= 0
+        assert st["scaled_after_dot"] == 0    # f32 activations
     for st in of("dispatch"):
         assert st["steps"] == 4 and 1 <= st["rows"] <= st["live_cap"] <= 2
+        assert st["scaled_after_dot"] == 0
     # one chunk index pairs a chunk's dispatch, boundary sync and replay
     chunks = {st["chunk"] for st in of("dispatch")}
     assert chunks and chunks == {st["chunk"] for st in of("sync")
@@ -192,6 +206,23 @@ def test_session_spans_under_the_profiler(engine, tmp_path):
     threads = {th for th, nm, _ in spans if nm == "dymoe.replay"}
     assert threads.isdisjoint(
         {th for th, nm, _ in spans if nm == "dymoe.dispatch"})
+
+
+def test_scaled_after_dot_on_dispatch_and_admit(tmp_path):
+    """bf16 activations in short row blocks: every decode chunk's grouped
+    kernel applies its group scales after the dot, and so does every
+    admission wave of several rows; a one-row wave runs the solo program,
+    which has no grouped kernel."""
+    cfg = dataclasses.replace(_cfg(), dtype="bfloat16")
+    eng = DyMoEEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                      EngineConfig(decode_chunk=4))
+    spans = _traced_spans(eng, tmp_path)
+    dispatch, admit = _of(spans, "dispatch"), _of(spans, "admit")
+    assert dispatch and admit
+    assert all(st["scaled_after_dot"] == 1 for st in dispatch)
+    assert all(st["scaled_after_dot"] == int(st["rows"] > 1)
+               for st in admit)
+    assert any(st["rows"] > 1 for st in admit)
 
 
 def test_counter_is_not_computed_without_the_profiler(engine, monkeypatch):
