@@ -10,7 +10,7 @@ config (abstractly, via ``jax.eval_shape``/``jax.make_jaxpr`` over
 no TPU needed) and checks the jaxprs against a rule registry. Run it as::
 
     PYTHONPATH=src python -m repro.analysis            # all configs
-    PYTHONPATH=src python -m repro.analysis --smoke    # 3 edge configs
+    PYTHONPATH=src python -m repro.analysis --smoke    # 4 edge configs
     PYTHONPATH=src python -m repro.analysis --json report.json
 
 Exit status is non-zero on any error-severity finding.

@@ -127,7 +127,7 @@ def _trace_prefill(cfg, params, qparams):
 def _admission_supported(cfg: ModelConfig) -> bool:
     # mirrors scheduler._can_batch_admissions: attention archs only, no
     # weight-shared hybrid blocks, no sliding-window ring caches
-    return (cfg.block_kinds()[0] in ("attn_dense", "attn_moe")
+    return (cfg.stack_kind in ("attn_dense", "attn_moe")
             and not cfg.shared_attn_every and cfg.sliding_window is None)
 
 

@@ -139,7 +139,7 @@ def forbidden_weight_shapes(cfg) -> frozenset:
     (both matmul orientations) — the tensors PR 1 abolished."""
     dm = cfg.d_model
     shapes = set()
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     if kind == "attn_moe":
         e, dff = cfg.num_experts, cfg.expert_d_ff
         shapes |= {(e, dm, dff), (e, dff, dm)}
@@ -169,12 +169,17 @@ def expected_dispatch_count(cfg, *, phase: str, fused: bool = True) -> int:
     matmul — 3 regardless of the bit mix.
     attn_dense: one kernel per FFN matmul (swiglu 3, gelu 2).
     ssm/hybrid: the two quantized projections (in_proj / out_proj).
+    Latent attention adds its decode kernel once per traced layer body
+    at decode: the scanned stack's and, with ``first_k_dense``, the
+    leading dense stack's (prefill runs it in XLA).
     """
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     if kind == "attn_moe":
+        mla = (1 + bool(cfg.first_k_dense)
+               if cfg.is_mla and phase.startswith("decode") else 0)
         if fused or cfg.dymoe.low_bits == 0:
-            return 3
-        return 3 if phase == "prefill" else 6
+            return 3 + mla
+        return (3 if phase == "prefill" else 6) + mla
     if kind == "attn_dense":
         return 3 if cfg.mlp_type == "swiglu" else 2
     return 2
@@ -185,7 +190,7 @@ def _default_packed_threshold(cfg) -> int:
     upcast at/above this size outside a kernel is packed codes being
     unpacked in the XLA graph."""
     dm = cfg.d_model
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     bits = min(b for b in (cfg.dymoe.high_bits,
                            cfg.dymoe.low_bits or cfg.dymoe.high_bits))
     vpb = 8 // bits

@@ -25,15 +25,21 @@ ARCH_IDS: List[str] = [
     # paper's own evaluation models
     "mixtral_8x7b",
     "qwen3_30b_a3b",
+    # the DeepSeek-V3 block: latent attention, shared experts, sigmoid
+    # router, dense lead layer
+    "moonlight_16b_a3b",
 ]
 
 # Edge-deployment subset the CI `analysis` leg lints (`--smoke`): the two
 # small MoE configs with custom Pallas tile overrides plus the tiniest
-# dense config — the fastest set that still exercises every rule family.
+# dense config — the fastest set that still exercises every rule family —
+# and the DeepSeek-V3 block (a dense lead stack, the latent-attention
+# decode kernel in the dispatch budget).
 ANALYSIS_SMOKE_CONFIGS: List[str] = [
     "qwen3_0p6b",
     "olmoe_1b_7b",
     "qwen2_moe_a2p7b",
+    "moonlight_16b_a3b",
 ]
 
 _ALIASES: Dict[str, str] = {
@@ -49,6 +55,7 @@ _ALIASES: Dict[str, str] = {
     "qwen1.5-32b": "qwen1p5_32b",
     "mixtral-8x7b": "mixtral_8x7b",
     "qwen3-30b-a3b": "qwen3_30b_a3b",
+    "moonlight-16b-a3b": "moonlight_16b_a3b",
 }
 
 

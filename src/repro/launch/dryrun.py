@@ -82,7 +82,7 @@ def shape_adapted_config(cfg: ModelConfig, shape: str) -> ModelConfig:
 def strip_expert_weights(params_tree, cfg: ModelConfig):
     """Serving keeps experts ONLY in the quantized store (the paper's whole
     point); drop the bf16 masters from the serve-step inputs."""
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     if kind == "attn_moe":
         return drop_dense_experts(params_tree)
     params_tree = dict(params_tree)

@@ -58,6 +58,14 @@ class ModelConfig:
     num_heads: int = 0
     num_kv_heads: int = 0
     head_dim: int = 0
+    # multi-head latent attention (DeepSeek-V2/V3; on when kv_lora_rank > 0):
+    # per head, q·k spans qk_nope_head_dim + qk_rope_head_dim and v
+    # v_head_dim; keys and values are decompressed from a kv_lora_rank-wide
+    # latent (cached, with the shared rope key) per token
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
     qk_norm: bool = False
     qkv_bias: bool = False
     rope_theta: float = 10000.0
@@ -90,6 +98,12 @@ class ModelConfig:
     num_experts_per_tok: int = 0
     num_shared_experts: int = 0
     moe_d_ff: int = 0  # per-expert hidden size (defaults to d_ff)
+    first_k_dense: int = 0  # leading layers with a dense FFN of width d_ff
+                            # (DeepSeek's first_k_dense_replace)
+    # softmax | sigmoid (DeepSeek-V3's noaux_tc: sigmoid affinities ranked
+    # with a per-expert selection bias that never weights them)
+    router_scoring: str = "softmax"
+    routed_scaling_factor: float = 1.0  # scale of the renormalized gates
     capacity_factor: float = 1.25
     router_aux_coef: float = 0.01
     router_z_coef: float = 1e-3
@@ -127,6 +141,29 @@ class ModelConfig:
         return self.moe_d_ff or self.d_ff
 
     @property
+    def is_mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def qk_head_dim(self) -> int:
+        """Width of one head's q·k product."""
+        if self.is_mla:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
+        return self.head_dim
+
+    @property
+    def num_moe_layers(self) -> int:
+        """Layers of the scanned MoE stack (the DyMoE depth schedule's
+        span): every layer but the leading dense ones."""
+        return self.num_layers - self.first_k_dense if self.is_moe else 0
+
+    @property
+    def stack_kind(self) -> str:
+        """Block kind of the scanned layer stack (the leading dense layers
+        of a ``first_k_dense`` model run before it)."""
+        return self.block_kinds()[-1]
+
+    @property
     def kv_groups(self) -> int:
         return self.num_heads // max(self.num_kv_heads, 1)
 
@@ -147,7 +184,8 @@ class ModelConfig:
         if self.arch_type in ("dense", "vlm", "audio"):
             return ("attn_dense",) * self.num_layers
         if self.arch_type == "moe":
-            return ("attn_moe",) * self.num_layers
+            return (("attn_dense",) * self.first_k_dense
+                    + ("attn_moe",) * (self.num_layers - self.first_k_dense))
         if self.arch_type in ("ssm", "hybrid"):
             return ("ssm",) * self.num_layers
         raise ValueError(self.arch_type)
@@ -156,8 +194,14 @@ class ModelConfig:
         if self.has_attention:
             assert self.head_dim > 0 and self.num_kv_heads > 0
             assert self.num_heads % self.num_kv_heads == 0
+        if self.is_mla:
+            assert self.qk_nope_head_dim > 0 and self.v_head_dim > 0
+            assert self.qk_rope_head_dim % 2 == 0
+            assert self.num_kv_heads == 1 and self.sliding_window is None
         if self.is_moe:
             assert 0 < self.num_experts_per_tok <= self.num_experts
+            assert 0 <= self.first_k_dense < self.num_layers
+            assert self.router_scoring in ("softmax", "sigmoid")
         if self.ssm_version:
             assert self.d_inner > 0 and self.ssm_state > 0
         if self.ssm_version == 2:
@@ -176,6 +220,10 @@ class ModelConfig:
                          head_dim=32)
             if self.num_kv_heads == self.num_heads:
                 small["num_kv_heads"] = 4
+        if self.is_mla:
+            small.update(num_kv_heads=1, kv_lora_rank=64,
+                         qk_nope_head_dim=32, qk_rope_head_dim=16,
+                         v_head_dim=32)
         if self.d_ff:
             small["d_ff"] = 512
         if self.is_moe:
@@ -183,6 +231,7 @@ class ModelConfig:
                          num_experts_per_tok=min(2, self.num_experts_per_tok),
                          num_shared_experts=min(1, self.num_shared_experts),
                          moe_d_ff=128,
+                         first_k_dense=min(1, self.first_k_dense),
                          # effectively dropless at smoke-test scale so
                          # prefill/decode consistency is exact
                          capacity_factor=4.0)

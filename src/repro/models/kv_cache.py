@@ -13,7 +13,8 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-__all__ = ["KVCache", "SSMCache", "init_kv_cache", "update_kv_cache"]
+__all__ = ["KVCache", "SSMCache", "init_kv_cache", "init_latent_cache",
+           "update_kv_cache"]
 
 
 @jax.tree_util.register_dataclass
@@ -27,6 +28,11 @@ class KVCache:
     token at logical position ``length`` lands in slot ``length + offset``
     while attention masks keep reasoning in logical positions.
     ring: static flag — True means S_slots is a sliding window.
+
+    A latent cache (multi-head latent attention) is the same structure
+    with one "head": k holds each token's normed latent c_kv (B, 1, S, R)
+    and v its rope key (B, 1, S, Dr), so every slot operation (writes,
+    freezes, the scheduler's row injection) applies unchanged.
     """
 
     k: jnp.ndarray
@@ -57,6 +63,19 @@ def init_kv_cache(batch: int, num_kv_heads: int, slots: int, head_dim: int,
         length=jnp.zeros((batch,), jnp.int32),
         offset=jnp.zeros((batch,), jnp.int32),
         ring=ring,
+    )
+
+
+def init_latent_cache(batch: int, slots: int, rank: int, rope_dim: int,
+                      dtype=jnp.bfloat16) -> KVCache:
+    """A latent cache (see :class:`KVCache`): rank + rope_dim values per
+    token and layer."""
+    return KVCache(
+        k=jnp.zeros((batch, 1, slots, rank), dtype),
+        v=jnp.zeros((batch, 1, slots, rope_dim), dtype),
+        positions=jnp.full((batch, slots), -1, jnp.int32),
+        length=jnp.zeros((batch,), jnp.int32),
+        offset=jnp.zeros((batch,), jnp.int32),
     )
 
 

@@ -23,7 +23,8 @@ from repro.models.kv_cache import KVCache, update_kv_cache
 from repro.models.layers.norms import init_rmsnorm, rmsnorm
 from repro.models.layers.rotary import apply_rope
 
-__all__ = ["init_attention", "attention_train", "attention_decode"]
+__all__ = ["init_attention", "attention_train", "attention_decode",
+           "causal_attention"]
 
 _NEG_INF = -1e30
 
@@ -80,6 +81,53 @@ def _pick_chunk(s: int, target: int = 1024) -> int:
     return c
 
 
+def causal_attention(cfg: ModelConfig, q: jnp.ndarray, k: jnp.ndarray,
+                     v: jnp.ndarray, scale: float, *,
+                     kv_valid: Optional[jnp.ndarray] = None,
+                     want_mass: bool = False, chunk: int = 1024
+                     ) -> Tuple[jnp.ndarray, Optional[jnp.ndarray]]:
+    """The q-chunked causal core of :func:`attention_train`, for any q·k
+    width and v width: q (B, Hkv, G, S, Dqk), k (B, Hkv, S, Dqk), v
+    (B, Hkv, S, Dv). Returns (out (B, Hkv, G, S, Dv) float32, received
+    attention mass (B, Hkv, S) — over queries, averaged over heads — or
+    None)."""
+    b, hk, g, s, _ = q.shape
+    cq = _pick_chunk(s, chunk)
+    nc = s // cq
+    cdt = jnp.dtype(cfg.attn_compute_dtype)
+    kf = k.astype(cdt)
+    vf = v.astype(cdt)
+
+    mass = (jnp.zeros((b, hk, s), jnp.float32) if want_mass else None)
+    outs = []
+    for ci in range(nc):
+        qc = q[:, :, :, ci * cq:(ci + 1) * cq].astype(cdt)
+        lo, hi = 0, s
+        if cfg.attn_causal_skip:
+            hi = (ci + 1) * cq  # keys beyond the causal frontier: skipped
+            if cfg.sliding_window:
+                lo = max(0, ci * cq - cfg.sliding_window + 1)
+        logits = jnp.einsum("bkgqd,bkpd->bkgqp", qc, kf[:, :, lo:hi]
+                            ).astype(jnp.float32) * scale
+        qi = ci * cq + jnp.arange(cq, dtype=jnp.int32)
+        kj = jnp.arange(lo, hi, dtype=jnp.int32)
+        m = qi[:, None] >= kj[None, :]
+        if cfg.sliding_window:
+            m = m & (qi[:, None] - kj[None, :] < cfg.sliding_window)
+        m = m[None, None, None]                       # (1, 1, 1, cq, hi-lo)
+        if kv_valid is not None:
+            m = m & kv_valid[:, None, None, None, lo:hi]
+        logits = jnp.where(m, logits, _NEG_INF)
+        probs = jax.nn.softmax(logits, axis=-1)
+        oc = jnp.einsum("bkgqp,bkpd->bkgqd", probs.astype(cdt),
+                        vf[:, :, lo:hi])
+        outs.append(oc.astype(jnp.float32))
+        if mass is not None:
+            pm = probs.sum(axis=(2, 3)) / (hk * g)      # (B, Hkv, hi-lo)
+            mass = mass.at[:, :, lo:hi].add(pm)
+    return jnp.concatenate(outs, axis=3), mass
+
+
 def attention_train(p, cfg: ModelConfig, x: jnp.ndarray, *,
                     positions: Optional[jnp.ndarray] = None,
                     kv_valid: Optional[jnp.ndarray] = None,
@@ -108,44 +156,11 @@ def attention_train(p, cfg: ModelConfig, x: jnp.ndarray, *,
         positions = jnp.broadcast_to(jnp.arange(s, dtype=jnp.int32)[None],
                                      (b, s))
     q, k, v = _project_qkv(p, cfg, x, positions)
-    hk, g, d = q.shape[1], q.shape[2], q.shape[4]
-    scale = d ** -0.5
-    cq = _pick_chunk(s, chunk)
-    nc = s // cq
-    cdt = jnp.dtype(cfg.attn_compute_dtype)
-    kf = k.astype(cdt)
-    vf = v.astype(cdt)
-
-    mass = (jnp.zeros((b, hk, s), jnp.float32)
-            if want_token_importance else None)
-    outs = []
-    for ci in range(nc):
-        qc = q[:, :, :, ci * cq:(ci + 1) * cq].astype(cdt)
-        lo, hi = 0, s
-        if cfg.attn_causal_skip:
-            hi = (ci + 1) * cq  # keys beyond the causal frontier: skipped
-            if cfg.sliding_window:
-                lo = max(0, ci * cq - cfg.sliding_window + 1)
-        logits = jnp.einsum("bkgqd,bkpd->bkgqp", qc, kf[:, :, lo:hi]
-                            ).astype(jnp.float32) * scale
-        qi = ci * cq + jnp.arange(cq, dtype=jnp.int32)
-        kj = jnp.arange(lo, hi, dtype=jnp.int32)
-        m = qi[:, None] >= kj[None, :]
-        if cfg.sliding_window:
-            m = m & (qi[:, None] - kj[None, :] < cfg.sliding_window)
-        m = m[None, None, None]                       # (1, 1, 1, cq, hi-lo)
-        if kv_valid is not None:
-            m = m & kv_valid[:, None, None, None, lo:hi]
-        logits = jnp.where(m, logits, _NEG_INF)
-        probs = jax.nn.softmax(logits, axis=-1)
-        oc = jnp.einsum("bkgqp,bkpd->bkgqd", probs.astype(cdt),
-                        vf[:, :, lo:hi])
-        outs.append(oc.astype(jnp.float32))
-        if mass is not None:
-            pm = probs.sum(axis=(2, 3)) / (hk * g)      # (B, Hkv, hi-lo)
-            mass = mass.at[:, :, lo:hi].add(pm)
-    out = jnp.concatenate(outs, axis=3)                  # (B,Hkv,G,S,D)
-    out = out.reshape(b, hk * g, s, d)
+    d = q.shape[4]
+    out, mass = causal_attention(cfg, q, k, v, d ** -0.5, kv_valid=kv_valid,
+                                 want_mass=want_token_importance,
+                                 chunk=chunk)
+    out = out.reshape(b, -1, s, d)
     out = out.transpose(0, 2, 1, 3).reshape(b, s, -1).astype(x.dtype)
     out = out @ p["wo"]
 

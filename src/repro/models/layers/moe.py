@@ -37,7 +37,7 @@ from repro.sharding.partition import MODEL_AXIS
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_rows",
            "moe_apply_prefill_rows", "moe_apply_sharded", "quantize_moe",
-           "scales_after_dot", "MoEStats"]
+           "scales_after_dot", "route", "selection_scores", "MoEStats"]
 
 
 @jax.tree_util.register_dataclass
@@ -74,7 +74,47 @@ def init_moe(cfg: ModelConfig, key, dtype) -> dict:
                             * dm ** -0.5).astype(dtype)
         p["shared_w_down"] = (jax.random.normal(ks[6], (se, sdff, dm))
                               * sdff ** -0.5).astype(dtype)
+    if cfg.router_scoring == "sigmoid":
+        # the selection bias (e_score_correction_bias) is a trained buffer
+        # in a released model; here N(0, 0.1^2), so that ranking by
+        # affinity plus bias and weighting by affinity part on some tokens
+        p["e_score_bias"] = jax.random.normal(
+            jax.random.fold_in(key, 7), (e,), jnp.float32) * 0.1
     return p
+
+
+def selection_scores(p, cfg: ModelConfig, logits: jnp.ndarray
+                     ) -> jnp.ndarray:
+    """What the router ranks experts by, from its logits (..., E): the
+    softmax probabilities, or (``router_scoring`` sigmoid) the sigmoid
+    affinities plus the per-expert selection bias. Also DyMoE's decode
+    importance (Eq. 3): a row's Critical experts are its ``t_l`` highest."""
+    if cfg.router_scoring == "sigmoid":
+        return jax.nn.sigmoid(logits) + p["e_score_bias"]
+    return jax.nn.softmax(logits, axis=-1)
+
+
+def route(p, cfg: ModelConfig, logits: jnp.ndarray
+          ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Top-k routing from router logits (..., E). Returns (affinities
+    (..., E): softmax probabilities or sigmoid scores; gates (..., k);
+    expert indices (..., k)). Softmax: the k most probable, renormalized.
+    Sigmoid (DeepSeek-V3 ``noaux_tc``, one group): the k highest of
+    affinity plus bias, weighted by their affinity alone. Either way the
+    gates are renormalized to sum to one, then scaled by
+    ``routed_scaling_factor``."""
+    k = cfg.num_experts_per_tok
+    if cfg.router_scoring == "sigmoid":
+        aff = jax.nn.sigmoid(logits)
+        _, idx = jax.lax.top_k(aff + p["e_score_bias"], k)
+        gates = jnp.take_along_axis(aff, idx, axis=-1)
+    else:
+        aff = jax.nn.softmax(logits, axis=-1)
+        gates, idx = jax.lax.top_k(aff, k)
+    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+    if cfg.routed_scaling_factor != 1.0:
+        gates = gates * cfg.routed_scaling_factor
+    return aff, gates, idx
 
 
 def quantize_moe(p, cfg: ModelConfig) -> dict:
@@ -202,7 +242,9 @@ def _expert_ffn_grouped(qweights: dict, xb: jnp.ndarray,
 
 
 def _shared_experts(p, x: jnp.ndarray) -> jnp.ndarray:
-    """Always-active shared experts (Qwen2-MoE): (T, dm) -> (T, dm)."""
+    """Always-active shared experts (Qwen2-MoE, DeepSeek-V3): (T, dm) ->
+    (T, dm). The sum over the stacked experts is one SwiGLU of their
+    summed width."""
     hs = jax.nn.silu(jnp.einsum("td,edf->etf", x, p["shared_w_gate"]))
     hs = hs * jnp.einsum("td,edf->etf", x, p["shared_w_up"])
     return jnp.einsum("etf,efd->td", hs, p["shared_w_down"])
@@ -256,9 +298,7 @@ def moe_apply(p, cfg: ModelConfig, x: jnp.ndarray, *,
 
     with jax.named_scope("router"):
         logits = x.astype(jnp.float32) @ p["wg_router"]      # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, idx = jax.lax.top_k(probs, k)                 # (T, k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        probs, gates, idx = route(p, cfg, logits)            # (T, k)
 
     with jax.named_scope("moe_dispatch"):
         flat_e = idx.reshape(-1)                             # (T*k,)
@@ -294,7 +334,7 @@ def moe_apply(p, cfg: ModelConfig, x: jnp.ndarray, *,
         y = ye.reshape(t, k, dm).sum(axis=1)
 
     if cfg.num_shared_experts:
-        with jax.named_scope("experts"):
+        with jax.named_scope("experts"), jax.named_scope("shared_experts"):
             y = y + _shared_experts(p, x)
 
     # ----- statistics / losses (over valid tokens only) -----
@@ -389,9 +429,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
 
     with jax.named_scope("router"):
         logits = x.astype(jnp.float32) @ p["wg_router"]      # (B, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, idx = jax.lax.top_k(probs, k)                 # (B, k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        _, gates, idx = route(p, cfg, logits)                # (B, k)
 
     def place(select):
         """Slot index inside the (expert, precision-stream) capacity
@@ -462,7 +500,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         y = ye.reshape(b, k, dm).sum(axis=1)
 
     if cfg.num_shared_experts:
-        with jax.named_scope("experts"):
+        with jax.named_scope("experts"), jax.named_scope("shared_experts"):
             y = y + _shared_experts(p, x)
 
     with jax.named_scope("router"):
@@ -534,9 +572,7 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
 
     with jax.named_scope("router"):
         logits = x.astype(jnp.float32) @ p["wg_router"]      # (T, E)
-        probs = jax.nn.softmax(logits, axis=-1)
-        gates, idx = jax.lax.top_k(probs, k)                 # (T, k)
-        gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
+        probs, gates, idx = route(p, cfg, logits)            # (T, k)
 
     def stream_pos(select):
         """Within-ROW running slot index of each (token, k) pair inside the
@@ -649,7 +685,7 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         y = ye.reshape(t, k, dm).sum(axis=1)
 
     if cfg.num_shared_experts:
-        with jax.named_scope("experts"):
+        with jax.named_scope("experts"), jax.named_scope("shared_experts"):
             y = y + _shared_experts(p, x)
 
     # ----- per-row statistics (each row's block == its solo stats) -----

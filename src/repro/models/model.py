@@ -12,6 +12,12 @@ scanned inputs.
 Layer pattern per family (pre-norm residual blocks):
   dense/vlm/audio:  x += Attn(n1(x));  x += MLP(n2(x))
   moe:              x += Attn(n1(x));  x += MoE(n2(x))      [+ shared experts]
+                    (``first_k_dense`` leading layers take a dense MLP of
+                    width d_ff instead; they are stacked apart, as
+                    ``dense_layers``, and run before the scanned MoE stack,
+                    so the DyMoE depth schedule spans the MoE layers only.
+                    Attention is multi-head latent attention when
+                    ``kv_lora_rank`` is set, with a latent cache.)
   ssm:              x += Mamba(n(x))
   hybrid (zamba2):  Mamba backbone + a weight-SHARED attention block applied
                     every ``shared_attn_every`` layers (per-site KV caches).
@@ -41,12 +47,15 @@ from repro.core.importance import heavy_hitter_mask, \
 from repro.core.prefetch import predict_next_gates, prefetch_targets
 from repro.core.schedule import critical_counts, retention_ratio
 from repro.models.config import ModelConfig
-from repro.models.kv_cache import KVCache, fill_kv_cache, init_kv_cache
+from repro.models.kv_cache import KVCache, fill_kv_cache, init_kv_cache, \
+    init_latent_cache
 from repro.models.layers.attention import attention_decode, attention_train, \
     init_attention
+from repro.models.layers.mla import init_mla, mla_decode, mla_train
 from repro.models.layers.mlp import init_mlp, mlp, mlp_quantized, quantize_mlp
 from repro.models.layers.moe import init_moe, moe_apply_prefill_rows, \
-    moe_apply_rows, moe_apply_sharded, quantize_moe
+    moe_apply_rows, moe_apply_sharded, quantize_moe, route, \
+    selection_scores
 from repro.models.layers.norms import init_rmsnorm, rmsnorm
 from repro.models.layers.rotary import sinusoidal_embedding
 from repro.models.layers.ssm import init_mamba, init_ssm_cache, \
@@ -88,7 +97,7 @@ def _scan_blocks(cfg: ModelConfig, body, carry0, xs):
             return jax.lax.scan(body, carry0, xs)
         carry = carry0
         ys = []
-        for l in range(cfg.num_layers):
+        for l in range(jax.tree.leaves(xs)[0].shape[0]):
             carry, y = body(carry, _index_tree(xs, l))
             ys.append(y)
         if ys and ys[0] is not None:
@@ -106,7 +115,8 @@ def _init_block(cfg: ModelConfig, key, kind: str, dtype) -> Dict[str, Any]:
     k1, k2 = jax.random.split(key)
     if kind in ("attn_dense", "attn_moe"):
         lp["norm2"] = init_rmsnorm(cfg.d_model, dtype)
-        lp["attn"] = init_attention(cfg, k1, dtype)
+        lp["attn"] = (init_mla if cfg.is_mla else init_attention)(
+            cfg, k1, dtype)
         if kind == "attn_moe":
             lp["moe"] = init_moe(cfg, k2, dtype)
         else:
@@ -155,18 +165,28 @@ def init_params(cfg: ModelConfig, key) -> Dict[str, Any]:
     cfg.validate()
     dt = _dtype(cfg)
     kinds = cfg.block_kinds()
-    assert len(set(kinds)) == 1, "block kinds are uniform per arch"
+    nd = cfg.first_k_dense
+    assert len(set(kinds[nd:])) == 1, "the scanned stack is uniform"
     k_embed, k_head, layer_keys, k_shared = _layer_keys(cfg, key)
     params = _init_outer(cfg, k_embed, k_head, k_shared, dt)
+    if nd:
+        params["dense_layers"] = _init_dense_layers(cfg, layer_keys[:nd], dt)
+        layer_keys = layer_keys[nd:]
     params["layers"] = jax.lax.map(
-        lambda k: _init_block(cfg, k, kinds[0], dt), layer_keys)
+        lambda k: _init_block(cfg, k, kinds[-1], dt), layer_keys)
     return params
+
+
+def _init_dense_layers(cfg: ModelConfig, keys, dt) -> Dict[str, Any]:
+    """The leading dense layers (``first_k_dense``), stacked, never
+    quantized: DyMoE stores experts only."""
+    return jax.lax.map(lambda k: _init_block(cfg, k, "attn_dense", dt), keys)
 
 
 def _quantize_block(cfg: ModelConfig, lp: dict) -> dict:
     """One layer's DyMoE mixed-precision store (see :func:`quantize_model`)."""
     pol = cfg.dymoe
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     if kind == "attn_moe":
         return {"moe": quantize_moe(lp["moe"], cfg)}
     if kind == "attn_dense":
@@ -213,9 +233,14 @@ def init_quantized_params(cfg: ModelConfig, key
     stack. Same values as the two-step route."""
     cfg.validate()
     dt = _dtype(cfg)
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     k_embed, k_head, layer_keys, k_shared = _layer_keys(cfg, key)
     params = _init_outer(cfg, k_embed, k_head, k_shared, dt)
+    nd = cfg.first_k_dense
+    if nd:
+        params["dense_layers"] = jax.jit(
+            lambda ks: _init_dense_layers(cfg, ks, dt))(layer_keys[:nd])
+        layer_keys = layer_keys[nd:]
 
     def one(k):
         lp = _init_block(cfg, k, kind, dt)
@@ -251,19 +276,38 @@ def _lm_head(params, cfg: ModelConfig, x: jnp.ndarray) -> jnp.ndarray:
 
 def _layer_tier_flags(cfg: ModelConfig) -> jnp.ndarray:
     """Depth-aware layer criticality for non-MoE archs: a layer is Critical
-    (high precision) when its retention ratio is >= the schedule mean."""
+    (high precision) when its retention ratio is >= the schedule mean.
+    One flag per layer of the scanned stack."""
     lam = cfg.dymoe.lam
     mean_r = (1.0 + lam) / 2.0
+    n = cfg.num_layers - cfg.first_k_dense
     return jnp.asarray([
-        retention_ratio(l, cfg.num_layers, lam, cfg.dymoe.depth_schedule)
-        >= mean_r
-        for l in range(cfg.num_layers)], bool)
+        retention_ratio(l, n, lam, cfg.dymoe.depth_schedule) >= mean_r
+        for l in range(n)], bool)
 
 
 def _t_l_array(cfg: ModelConfig) -> jnp.ndarray:
+    """Critical counts t_l (Eq. 4-5) over the MoE layers."""
     return jnp.asarray(critical_counts(
-        cfg.num_layers, max(cfg.num_experts, 1), cfg.dymoe.lam,
-        cfg.dymoe.depth_schedule), jnp.int32)
+        cfg.num_layers - cfg.first_k_dense, max(cfg.num_experts, 1),
+        cfg.dymoe.lam, cfg.dymoe.depth_schedule), jnp.int32)
+
+
+def _attn_train(cfg: ModelConfig):
+    return mla_train if cfg.is_mla else attention_train
+
+
+def _attn_decode(cfg: ModelConfig):
+    return mla_decode if cfg.is_mla else attention_decode
+
+
+def _init_cache(cfg: ModelConfig, b: int, slots: int, dt, ring: bool
+                ) -> KVCache:
+    """One layer's empty attention cache: latent (MLA) or per KV head."""
+    if cfg.is_mla:
+        return init_latent_cache(b, slots, cfg.kv_lora_rank,
+                                 cfg.qk_rope_head_dim, dt)
+    return init_kv_cache(b, cfg.num_kv_heads, slots, cfg.head_dim, dt, ring)
 
 
 def _shared_flags(cfg: ModelConfig) -> jnp.ndarray:
@@ -330,10 +374,11 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
     """Training forward. Returns (logits (B,S,V) f32, aux_loss scalar)."""
     x = _embed(params, cfg, tokens, embeds)
     b, s, _ = x.shape
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     hybrid = bool(cfg.shared_attn_every)
+    attn_train = _attn_train(cfg)
 
-    def body(carry, xs):
+    def body(carry, xs, kind=kind):
         x, aux = carry
         lp = xs["block"]
         if cfg.act_seq_shard:
@@ -347,12 +392,13 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                 return _shared_block_train(params, cfg, x)[0]
             x = jax.lax.cond(xs["shared"], with_shared, lambda x: x, x)
         if kind in ("attn_dense", "attn_moe"):
-            a, _, _ = attention_train(lp["attn"], cfg,
-                                      rmsnorm(lp["norm1"], x, cfg.norm_eps))
+            a, _, _ = attn_train(lp["attn"], cfg,
+                                 rmsnorm(lp["norm1"], x, cfg.norm_eps))
             x = x + a
             h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
             if kind == "attn_dense":
-                x = x + mlp(lp["mlp"], cfg, h)
+                with jax.named_scope("dense_ffn"):
+                    x = x + mlp(lp["mlp"], cfg, h)
             else:
                 y, stats = moe_apply_sharded(lp["moe"], cfg, h.reshape(b * s, -1))
                 x = x + y.reshape(b, s, -1)
@@ -364,12 +410,19 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
             x = x + y
         return (x, aux), None
 
+    carry = (x, jnp.zeros((), jnp.float32))
+    if cfg.first_k_dense:
+        lead = partial(body, kind="attn_dense")
+        if cfg.remat == "block":
+            lead = jax.checkpoint(lead)
+        carry, _ = _scan_blocks(cfg, lead, carry,
+                                {"block": params["dense_layers"]})
     if cfg.remat == "block":
         body = jax.checkpoint(body)
     xs = {"block": params["layers"]}
     if hybrid:
         xs["shared"] = _shared_flags(cfg)
-    (x, aux), _ = _scan_blocks(cfg, body, (x, jnp.zeros((), jnp.float32)), xs)
+    (x, aux), _ = _scan_blocks(cfg, body, carry, xs)
     x = rmsnorm(params["final_norm"], x, cfg.norm_eps)
     return _lm_head(params, cfg, x), aux
 
@@ -454,7 +507,7 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
     b_, s_ = (tokens.shape if tokens is not None else embeds.shape[:2])
     offsets = valid = positions = None
     if lengths is not None:
-        assert cfg.block_kinds()[0] in ("attn_dense", "attn_moe"), \
+        assert cfg.stack_kind in ("attn_dense", "attn_moe"), \
             "ragged prefill requires attention archs"
         assert not cfg.shared_attn_every, \
             "ragged prefill unsupported for shared-attention hybrids"
@@ -468,8 +521,9 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
     dt = _dtype(cfg)
     dymoe_on = qparams is not None and cfg.dymoe.enabled
     pol = cfg.dymoe
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     hybrid = bool(cfg.shared_attn_every)
+    attn_train = _attn_train(cfg)
     slots = cache_slots or (cfg.sliding_window or max(s, cfg.max_seq_len))
     ring = cfg.sliding_window is not None and slots == cfg.sliding_window
     assert lengths is None or not ring, \
@@ -497,7 +551,7 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
 
     e = max(cfg.num_experts, 1)
 
-    def body(carry, xs_l):
+    def body(carry, xs_l, kind=kind):
         if hybrid:
             x, shared_caches = carry
         else:
@@ -519,22 +573,23 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
         if kind in ("attn_dense", "attn_moe"):
             want_imp = kind == "attn_moe"
             with jax.named_scope("attention"):
-                a, tok_imp, (k, v) = attention_train(
+                a, tok_imp, (k, v) = attn_train(
                     lp["attn"], cfg, rmsnorm(lp["norm1"], x, cfg.norm_eps),
                     positions=positions, kv_valid=valid,
                     want_token_importance=want_imp)
                 cache = fill_kv_cache(
-                    init_kv_cache(b, cfg.num_kv_heads, slots, cfg.head_dim,
-                                  dt, ring), k, v, lengths=lengths,
-                    offsets=offsets)
+                    _init_cache(cfg, b, slots, dt, ring), k, v,
+                    lengths=lengths, offsets=offsets)
                 x = x + a
             if kind == "attn_dense":
-                h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
-                if dymoe_on:
-                    y = mlp_quantized(xs_l["q"]["mlp"], cfg, h, xs_l["tier"])
-                else:
-                    y = mlp(lp["mlp"], cfg, h)
-                x = x + y
+                with jax.named_scope("dense_ffn"):
+                    h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+                    if dymoe_on and "q" in xs_l:
+                        y = mlp_quantized(xs_l["q"]["mlp"], cfg, h,
+                                          xs_l["tier"])
+                    else:
+                        y = mlp(lp["mlp"], cfg, h)
+                    x = x + y
             else:
                 k_tok = cfg.num_experts_per_tok
                 critical, hh = None, None
@@ -557,8 +612,12 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                         # expert compute (Eq. 1-2 -> Eq. 5)
                         logits_r = hflat.astype(jnp.float32) @ lp["moe"][
                             "wg_router"]
-                        probs_r = jax.nn.softmax(logits_r, axis=-1)
-                        gates_r, idx_r = jax.lax.top_k(probs_r, k_tok)
+                        if cfg.router_scoring == "softmax":
+                            probs_r = jax.nn.softmax(logits_r, axis=-1)
+                            gates_r, idx_r = jax.lax.top_k(probs_r, k_tok)
+                        else:
+                            _, gates_r, idx_r = route(lp["moe"], cfg,
+                                                      logits_r)
                         oh = jax.nn.one_hot(idx_r, e, dtype=jnp.float32)
                         if vflat is not None:  # pads route nowhere
                             oh = oh * vflat.astype(jnp.float32)[:, None,
@@ -663,6 +722,9 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
         return carry, {"cache": cache, **telem}
 
     carry0 = (x, shared_caches0) if hybrid else (x,)
+    if cfg.first_k_dense:
+        carry0, ys_d = _scan_blocks(cfg, partial(body, kind="attn_dense"),
+                                    carry0, {"block": params["dense_layers"]})
     carry, ys = _scan_blocks(cfg, body, carry0, xs)
     x = carry[0]
     with jax.named_scope("lm_head"):
@@ -670,6 +732,8 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
         logits = _lm_head(params, cfg, x if full_logits else x[:, -1])
 
     caches: Dict[str, Any] = {"layers": ys["cache"]}
+    if cfg.first_k_dense:
+        caches["dense_layers"] = ys_d["cache"]
     if hybrid:
         caches["shared"] = carry[1]
     info = DyMoEInfo()
@@ -697,15 +761,17 @@ def init_decode_state(cfg: ModelConfig, batch: int, seq_len: int) -> Any:
     dt = _dtype(cfg)
     slots = min(seq_len, cfg.sliding_window) if cfg.sliding_window else seq_len
     ring = cfg.sliding_window is not None and slots == cfg.sliding_window
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
 
     def one(_):
         if kind in ("attn_dense", "attn_moe"):
-            return init_kv_cache(batch, cfg.num_kv_heads, slots,
-                                 cfg.head_dim, dt, ring)
+            return _init_cache(cfg, batch, slots, dt, ring)
         return init_ssm_cache(cfg, batch, dt)
 
-    caches = {"layers": jax.vmap(one)(jnp.arange(cfg.num_layers))}
+    nd = cfg.first_k_dense
+    caches = {"layers": jax.vmap(one)(jnp.arange(cfg.num_layers - nd))}
+    if nd:
+        caches["dense_layers"] = jax.vmap(one)(jnp.arange(nd))
     if cfg.shared_attn_every:
         caches["shared"] = jax.vmap(
             lambda _: init_kv_cache(batch, cfg.num_kv_heads, slots,
@@ -740,10 +806,11 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
     ``moe_capacity`` (static, requires ``live_rows``) bounds each MoE
     precision region to the chunk's live-row count instead of B."""
     dt = _dtype(cfg)
-    kind = cfg.block_kinds()[0]
+    kind = cfg.stack_kind
     hybrid = bool(cfg.shared_attn_every)
     dymoe_on = qparams is not None and cfg.dymoe.enabled
     pol = cfg.dymoe
+    attn_decode = _attn_decode(cfg)
     b = tokens.shape[0]
     e = max(cfg.num_experts, 1)
 
@@ -763,7 +830,7 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
         xs["shared"] = _shared_flags(cfg)
         xs["site"] = _site_index(cfg)
 
-    def body(carry, xs_l):
+    def body(carry, xs_l, kind=kind):
         if hybrid:
             x, shared_caches = carry
         else:
@@ -790,17 +857,19 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
         telem: Dict[str, Any] = {}
         if kind in ("attn_dense", "attn_moe"):
             with jax.named_scope("attention"):
-                a, cache = attention_decode(
+                a, cache = attn_decode(
                     lp["attn"], cfg, rmsnorm(lp["norm1"], x, cfg.norm_eps),
                     cache, live=live_rows)
                 x = x + a
             if kind == "attn_dense":
-                h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
-                if dymoe_on:
-                    y = mlp_quantized(xs_l["q"]["mlp"], cfg, h, xs_l["tier"])
-                else:
-                    y = mlp(lp["mlp"], cfg, h)
-                x = x + y
+                with jax.named_scope("dense_ffn"):
+                    h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
+                    if dymoe_on and "q" in xs_l:
+                        y = mlp_quantized(xs_l["q"]["mlp"], cfg, h,
+                                          xs_l["tier"])
+                    else:
+                        y = mlp(lp["mlp"], cfg, h)
+                    x = x + y
             else:
                 critical = None
                 with jax.named_scope("router"):
@@ -813,7 +882,8 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
                         # gate
                         logits_r = hflat.astype(jnp.float32) @ lp["moe"][
                             "wg_router"]
-                        imp = jax.nn.softmax(logits_r, axis=-1)  # (B, E)
+                        imp = selection_scores(lp["moe"], cfg,
+                                               logits_r)         # (B, E)
                         critical = (
                             select_critical_rows(imp, xs_l["t_l"])
                             if per_row_moe
@@ -871,6 +941,11 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
         carry0 = (x, caches["shared"])
     else:
         carry0 = (x,)
+    if cfg.first_k_dense:
+        carry0, ys_d = _scan_blocks(
+            cfg, partial(body, kind="attn_dense"), carry0,
+            {"block": params["dense_layers"],
+             "cache": caches["dense_layers"]})
     carry, ys = _scan_blocks(cfg, body, carry0, xs)
     x = carry[0]
     with jax.named_scope("lm_head"):
@@ -878,6 +953,8 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
         logits = _lm_head(params, cfg, x[:, 0])
 
     new_caches: Dict[str, Any] = {"layers": ys["cache"]}
+    if cfg.first_k_dense:
+        new_caches["dense_layers"] = ys_d["cache"]
     if hybrid:
         new_caches["shared"] = carry[1]
     info = DyMoEInfo()

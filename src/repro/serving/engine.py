@@ -329,7 +329,7 @@ class DyMoEEngine:
         pol = cfg.dymoe
         budget = int(e.profile.vram_bytes * e.max_cache_fraction)
         ocfg = OrchestratorConfig(
-            num_layers=cfg.num_layers,
+            num_layers=cfg.num_moe_layers,
             num_experts=cfg.num_experts,
             experts_per_token=cfg.num_experts_per_tok,
             bytes_high=expert_bytes(cfg, pol.high_bits),
@@ -388,7 +388,7 @@ class DyMoEEngine:
             totals = np.broadcast_to(
                 per_layer, (T, cfg.num_layers)).sum(axis=1)
             return [], [float(x) for x in totals], 0
-        crit = np.asarray(crit, bool).reshape(T, cfg.num_layers, -1)
+        crit = np.asarray(crit, bool).reshape(T, cfg.num_moe_layers, -1)
         active = np.asarray(active, bool).reshape(crit.shape)
         pred = np.asarray(pred).reshape(crit.shape)
         # SLO pressure ladder: price compute/bytes with the SAME degraded

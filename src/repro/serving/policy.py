@@ -170,7 +170,7 @@ def estimate_service_s(cost, cfg, request) -> float:
     if cfg.is_moe:
         k = cfg.num_experts_per_tok
         t_l = np.asarray(critical_counts(
-            cfg.num_layers, cfg.num_experts, cfg.dymoe.lam,
+            cfg.num_moe_layers, cfg.num_experts, cfg.dymoe.lam,
             cfg.dymoe.depth_schedule))
         n_hi = np.minimum(t_l, k)
         n_lo = (np.zeros_like(n_hi) if cfg.dymoe.low_bits == 0

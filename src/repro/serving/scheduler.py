@@ -133,7 +133,8 @@ from repro.serving.policy import SchedulingPolicy, SLOPressure, \
 from repro.serving.request import Request, RequestHandle, TokenChunk
 from repro.serving.sampler import raw_key_data, resolve_sampling, \
     sample_token_rows
-from repro.serving.spans import group_bytes, live_groups, span
+from repro.serving.spans import group_bytes, latent_cache_bytes, \
+    live_groups, span
 
 __all__ = ["SchedulerConfig", "ContinuousBatchingScheduler",
            "live_cap_for"]
@@ -363,7 +364,7 @@ class ContinuousBatchingScheduler:
         cache. Everything else admits one request per prefill (the exact
         solo program)."""
         cfg = self.engine.cfg
-        return (cfg.block_kinds()[0] in ("attn_dense", "attn_moe")
+        return (cfg.stack_kind in ("attn_dense", "attn_moe")
                 and not cfg.shared_attn_every
                 and cfg.sliding_window is None)
 
@@ -442,6 +443,10 @@ class ContinuousBatchingScheduler:
         self._states: List[Optional[_SlotState]] = [None] * b
         self._caches = engine.shard_decode_state(
             init_decode_state(cfg, b, self._slots_len))
+        # the slot batch's latent cache (multi-head latent attention):
+        # reported on the session's first admission span
+        self._latent_bytes = (latent_cache_bytes(self._caches)
+                              if cfg.is_mla else None)
         self._tok_d = jnp.zeros(b, jnp.int32)  # ON DEVICE between chunks
         self._done = np.ones(b, bool)          # empty slots stay frozen
         self._emitted = np.zeros(b, np.int32)
@@ -880,12 +885,14 @@ class ContinuousBatchingScheduler:
             n = len(cands)
             wave = self._n_waves
             self._n_waves += 1
+            first_wave = ({} if wave or self._latent_bytes is None
+                          else {"latent_cache_bytes": self._latent_bytes})
             with span("admit", wave=wave, rows=n, longest_prompt=max(lens),
                       queue_wait_ms_max=1e3 * max(now - h.submit_t
                                                   for h in cands),
                       scaled_after_dot=self._scaled_after_dot(
                           n * _capacity(self.engine.cfg, max(lens))
-                          if n > 1 else 0)):
+                          if n > 1 else 0), **first_wave):
                 try:
                     rcaches, first, tele = self._prefill_wave(cands, lens,
                                                               wave)
@@ -1090,9 +1097,12 @@ class ContinuousBatchingScheduler:
                 self._faults.fire("device.dispatch", chunk=self._n_chunks,
                                   num_steps=chunk, rows=len(live))
                 after = self._scaled_after_dot(live_cap)
+                ctx = (sum(self._states[r].prompt_len + int(self._emitted[r])
+                           for r in live)
+                       if jax.profiler.TraceAnnotation.is_enabled() else 0)
                 with span("dispatch", chunk=self._n_chunks, rows=len(live),
                           live_cap=live_cap, steps=chunk,
-                          scaled_after_dot=after):
+                          scaled_after_dot=after, ctx_tokens=ctx):
                     toks_d, caches, infos, done_d, emitted_d = \
                         engine._decode_batched(
                             engine.params, tokens=self._tok_d,

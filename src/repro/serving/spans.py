@@ -13,9 +13,15 @@ The spans (thread; stats):
   boundary;
 * ``dymoe.admit`` (stepper; ``wave``, ``rows``, ``longest_prompt``,
   ``queue_wait_ms_max``, ``scaled_after_dot``): one admission wave —
-  prefill dispatch, first-token fetch, injection into the slot batch;
+  prefill dispatch, first-token fetch, injection into the slot batch. The
+  session's first wave of a latent-attention model also carries
+  ``latent_cache_bytes``, the slot batch's whole latent cache
+  (:func:`latent_cache_bytes`);
 * ``dymoe.dispatch`` (stepper; ``chunk``, ``rows``, ``live_cap``,
-  ``steps``, ``scaled_after_dot``): the enqueue of one decode chunk;
+  ``steps``, ``scaled_after_dot``, ``ctx_tokens``): the enqueue of one
+  decode chunk; ``ctx_tokens`` (only while the profiler records, else 0)
+  sums over the live rows the context the chunk's first step attends:
+  prompt plus tokens emitted so far;
 * ``dymoe.sync`` (stepper; ``chunk`` or ``wave``): a blocking device
   fetch — the boundary's done/emitted masks, a wave's first tokens;
 * ``dymoe.replay_submit`` (stepper; ``depth``): handing a job to the
@@ -38,7 +44,7 @@ from typing import Tuple
 import jax
 import numpy as np
 
-__all__ = ["span", "group_bytes", "live_groups"]
+__all__ = ["span", "group_bytes", "live_groups", "latent_cache_bytes"]
 
 _PROJECTIONS = ("w_gate", "w_up", "w_down")
 
@@ -59,6 +65,18 @@ def group_bytes(qparams) -> Tuple[int, int]:
     groups = int(np.prod(lead))
     return tuple(sum(moe[name].nbytes(prec) for name in _PROJECTIONS)
                  // groups for prec in ("high", "low"))
+
+
+def latent_cache_bytes(caches) -> int:
+    """Bytes of every attention cache in a decode-state pytree (for a
+    latent-attention model, the latents and rope keys of every slot and
+    layer), from the shapes alone."""
+    from repro.models.kv_cache import KVCache
+
+    leaves = jax.tree_util.tree_leaves(
+        caches, is_leaf=lambda c: isinstance(c, KVCache))
+    return sum(c.k.nbytes + c.v.nbytes for c in leaves
+               if isinstance(c, KVCache))
 
 
 def live_groups(critical: np.ndarray, active: np.ndarray,

@@ -143,9 +143,10 @@ def _align(rule: Tuple, shape: Tuple[int, ...], lead_pad: int) -> P:
 
 
 def _spec_for(path_s: str, shape, mesh: Mesh, expert_parallel: bool) -> P:
-    # "/layers/" anywhere (params, or mu/nu inside optimizer state) marks
-    # the stacked-layer layout with a leading L dim
-    lead_pad = 1 if "/layers/" in path_s else 0
+    # "layers/" anywhere (params, or mu/nu inside optimizer state; the
+    # leading dense stack's "dense_layers/" too) marks the stacked-layer
+    # layout with a leading L dim
+    lead_pad = 1 if "layers/" in path_s else 0
     if expert_parallel:
         # pure expert parallelism: only the routed experts are split; the
         # rest is replicated, so every device runs attention, router and

@@ -73,9 +73,11 @@ def test_smoke_serve_path(arch, rng):
     assert lg.shape == (B, cfg.vocab_size)
     assert np.isfinite(np.asarray(lg)).all()
     if cfg.is_moe:
-        assert info.critical_masks.shape == (cfg.num_layers,
+        # telemetry covers the MoE layers (all of them but a leading
+        # dense stack)
+        assert info.critical_masks.shape == (cfg.num_moe_layers,
                                              cfg.num_experts)
-        assert info2.predicted_next.shape == (cfg.num_layers,
+        assert info2.predicted_next.shape == (cfg.num_moe_layers,
                                               cfg.num_experts)
 
 

@@ -176,3 +176,26 @@ def test_expert_parallel_moe_layer_compiles_for_v5e_2x2(topo):
     gathers = [ln for ln in text.splitlines()
                if re.search(r"\ball-gather(-start)?\(", ln)]
     assert not [ln for ln in gathers if "u8[" in ln], gathers
+
+
+# --------------------------------------------- latent-attention decode
+# Moonlight-16B-A3B's published widths: 16 heads, latent 512, rope key 64
+
+
+@pytest.mark.parametrize("rows,slots", [(8, 2816), (1, 2816), (8, 768)])
+def test_mla_decode_kernel_compiles_for_v5e(one_chip, rows, slots):
+    """The absorbed latent-attention decode kernel at the published widths
+    (16 heads, latent 512 + rope 64) over a bf16 latent cache: the doc_qa
+    cell's slot batch and window, one row, and a shorter window. Its name
+    reaches the compiled program, where the device trace finds it."""
+    from repro.kernels.mla_decode.mla_decode import mla_decode_attention
+
+    q = _sds((rows, 16, 576), jnp.float32, one_chip)
+    ckv = _sds((rows, slots, 512), jnp.bfloat16, one_chip)
+    kpe = _sds((rows, slots, 64), jnp.bfloat16, one_chip)
+    lens = _sds((rows,), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda q, c, p, s, e: mla_decode_attention(
+            q, c, p, s, e, scale=192 ** -0.5), q, ckv, kpe, lens, lens)
+    assert "tpu_custom_call" in text or "custom-call" in text
+    assert "mla_decode_attention" in text

@@ -19,7 +19,8 @@ from repro.models.layers.moe import init_moe, moe_apply_rows, quantize_moe
 from repro.models.model import init_decode_state
 from repro.serving import DyMoEEngine, EngineConfig, Request
 from repro.serving import scheduler as sched_mod
-from repro.serving.spans import group_bytes, live_groups
+from repro.serving.spans import group_bytes, latent_cache_bytes, \
+    live_groups
 
 DECODE_SCOPES = ("layers", "attention", "router", "moe_dispatch", "experts",
                  "moe_combine", "lm_head", "sample", "kv_freeze")
@@ -237,3 +238,58 @@ def test_counter_is_not_computed_without_the_profiler(engine, monkeypatch):
     assert not jax.profiler.TraceAnnotation.is_enabled()
     _serve(engine)
     assert calls == []
+
+
+def _mla_cfg():
+    """A latent-attention model with a dense lead layer (the DeepSeek-V3
+    block at toy widths)."""
+    return dataclasses.replace(
+        _cfg(), num_layers=3, first_k_dense=1, d_ff=64, num_kv_heads=1,
+        head_dim=24, kv_lora_rank=32, qk_nope_head_dim=16,
+        qk_rope_head_dim=8, v_head_dim=16, num_shared_experts=1,
+        router_scoring="sigmoid",
+        routed_scaling_factor=2.446)
+
+
+def test_latent_cache_bytes_counts_every_layer():
+    cfg = _mla_cfg()
+    caches = init_decode_state(cfg, 2, 48)
+    # 3 layers (the dense one too) x 2 slots x 48 positions x (32 + 8) f32
+    assert latent_cache_bytes(caches) == 3 * 2 * 48 * (32 + 8) * 4
+
+
+def test_latent_attention_counters_on_admit_and_dispatch(tmp_path):
+    """The session's first admission wave carries the slot batch's latent
+    cache bytes, and no later wave does; every decode chunk's dispatch
+    carries its live rows' summed context, which grows by the chunk's
+    steps for each row that stays."""
+    cfg = _mla_cfg()
+    eng = DyMoEEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                      EngineConfig(decode_chunk=4))
+    spans = _traced_spans(eng, tmp_path)
+    admit = sorted(_of(spans, "admit"), key=lambda st: st["wave"])
+    assert admit[0]["wave"] == 0
+    assert admit[0]["latent_cache_bytes"] == latent_cache_bytes(
+        init_decode_state(cfg, 2, 48))
+    assert all("latent_cache_bytes" not in st for st in admit[1:])
+    dispatch = sorted(_of(spans, "dispatch"), key=lambda st: st["chunk"])
+    # the first wave admits the 6- and 9-token prompts, each with its
+    # first token from the prefill
+    assert dispatch[0]["rows"] == 2
+    assert dispatch[0]["ctx_tokens"] == (6 + 1) + (9 + 1)
+    for a, b in zip(dispatch, dispatch[1:]):
+        if a["rows"] == b["rows"] == 2 and b["chunk"] == a["chunk"] + 1:
+            assert b["ctx_tokens"] <= a["ctx_tokens"] + 2 * 4
+    assert all(st["ctx_tokens"] > 0 for st in dispatch)
+
+
+@pytest.mark.parametrize("program", ["prefill", "decode"])
+def test_latent_attention_programs_carry_their_scopes(program):
+    """The DeepSeek-V3 block's parts are named in both serving programs:
+    latent attention, the shared experts and the dense lead layer's FFN."""
+    cfg = _mla_cfg()
+    eng = DyMoEEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                      EngineConfig(decode_chunk=4))
+    text = _lowered(eng, program).as_text(debug_info=True)
+    for scope in ("mla", "shared_experts", "dense_ffn"):
+        assert re.search(rf'loc\("([^"]*/)?{scope}/', text), scope
