@@ -142,7 +142,7 @@ def run_fused() -> List[dict]:
                                   out_dtype=jnp.float32)
             t_int, pal = _time_us(expert_quant_matmul_grouped, x, mp, cj,
                                   cap_hi=cap, impl="pallas", interpret=True,
-                                  block_m=4, block_n=64, block_k=256,
+                                  block_m=4, block_k=256,
                                   out_dtype=jnp.float32)
             y_hi = expert_quant_matmul_fixed(x[:, :cap], mp.high,
                                              impl="ref",

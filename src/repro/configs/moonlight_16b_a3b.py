@@ -41,7 +41,9 @@ def config() -> ModelConfig:
         norm_eps=1e-5,
         max_seq_len=8192,
         # block_m=32 as OLMoE: 64 experts top-6 leave each expert's
-        # capacity region a few rows deep at decode
+        # capacity region a few rows deep at decode; block_n=128 tiles N
+        # in the critical-mask kernel only: the grouped kernel takes its
+        # N tile from the shapes (all of moe_d_ff=1408 at decode)
         dymoe=DyMoEPolicy(high_bits=4, low_bits=2, retention=0.75,
                           block_m=32, block_n=128, block_k=512),
         source="DeepSeek-V3 block, 64 routed top-6 + 2 shared, MLA "

@@ -26,7 +26,9 @@ def config() -> ModelConfig:
         max_seq_len=32768,
         # block_m=32: 64-expert top-8 dispatch leaves each expert's
         # capacity region a few rows deep — 128-row tiles would be mostly
-        # padding; block_n=128 walks moe_d_ff=1024 in 8 tiles
+        # padding; block_n=128 tiles N in the critical-mask kernel only:
+        # the grouped kernel takes its N tile from the shapes (all of
+        # moe_d_ff=1024 at decode)
         dymoe=DyMoEPolicy(high_bits=4, low_bits=2, retention=0.75,
                           block_m=32, block_n=128, block_k=512),
         source="64 experts top-8 [arXiv:2409.02060]",

@@ -8,6 +8,8 @@ their output block is zeroed without the packed codes ever being unpacked.
 
 TPU mapping
 -----------
+:func:`expert_quant_matmul_pallas`, the critical-mask kernel:
+
 * Grid ``(E, M/bm, N/bn, K/bk)`` — E/M/N parallel, K ``arbitrary`` (serial
   accumulation into a VMEM scratch accumulator).
 * The critical mask rides in as a **scalar-prefetch** operand
@@ -31,15 +33,22 @@ one combined buffer and one ``(E * P, M/bm, N/bn, K/bk)`` grid whose
 scalar-prefetch operand is a per-(expert, precision-group) live-slot
 watermark table — the second dispatch, the second weight unpack, and every
 dead row block (finished/evicted/padded slots) disappear from the grid.
-Where its x is bf16 and its row blocks are short (decode; admission waves
-of a few rows; :func:`_scale_after_dot`), its body feeds the MXU the codes
-themselves as bf16 integers and applies the f32 group scales to the dot's
-per-group partial sums: no weight-side dequant and no f32 dot.
+Its tiles come from the shapes (:func:`grouped_tiling`). Where its x is
+bf16 and its row blocks are short (decode; admission waves of a few rows;
+:func:`_scale_after_dot`), its body feeds the MXU the codes themselves as
+bf16 integers and applies the f32 group scales to the dot's per-group
+partial sums, and one grid step covers the whole output width N: at
+decode most steps are dead, and on a v5e chip a step's fixed cost
+(about 0.24 us) outweighs what the wider tile adds (about 0.25 us per
+1,024 columns of a dead step).
+A dead step, and the precision buffer a group does not read, repeat the
+block that buffer already holds (:func:`grouped_index_maps`), so only live
+blocks move over HBM.
 """
 from __future__ import annotations
 
 import functools
-from typing import Optional
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -49,7 +58,8 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.kernels.quant_matmul.quant_matmul import _unpack_dequant
 
 __all__ = ["expert_quant_matmul_pallas", "expert_quant_matmul_grouped_pallas",
-           "grouped_scales_after_dot"]
+           "grouped_scales_after_dot", "grouped_tiling", "GroupedTiling",
+           "grouped_tables", "grouped_index_maps"]
 
 
 def _dual_kernel(crit_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref, o_ref,
@@ -367,42 +377,153 @@ def expert_quant_matmul_pallas(
     return out[:, :m, :n]
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("cap_hi", "hi_bits", "lo_bits", "group_size",
-                     "block_m", "block_n", "block_k", "interpret",
-                     "out_dtype"),
-)
-def expert_quant_matmul_grouped_pallas(
-        x: jnp.ndarray, hi_packed: jnp.ndarray, hi_scales: jnp.ndarray,
-        lo_packed: Optional[jnp.ndarray], lo_scales: Optional[jnp.ndarray],
-        counts: jnp.ndarray, *, cap_hi: int, hi_bits: int, lo_bits: int,
-        group_size: int, block_m: int = 128, block_n: int = 128,
-        block_k: int = 512, interpret: bool = False,
-        out_dtype=jnp.bfloat16) -> jnp.ndarray:
-    """ONE dispatch over a combined dual-precision capacity buffer with a
-    live-row ragged grid.
+# What a grouped call's blocks, double-buffered, and its accumulator may
+# take of VMEM: the budget of the vmem-footprint lint (analysis/vmem.py),
+# also the v5e compiler's default limit.
+_VMEM_BUDGET = 16 * 2 ** 20
 
-    ``x`` (E, M, K) packs BOTH precision regions of the dual-buffer per-row
-    MoE dispatch per expert: high-precision slots occupy ``[0, cap_hi)``
-    and low-precision slots ``[cap_hi, M)``. The grid is
-    ``(E * P, M_region/bm, N/bn, K/bk)`` with P precision groups (2, or 1
-    when ``lo_packed is None`` — the "4/0" lo group is elided at grid
-    construction): each grid step streams exactly one precision's packed
-    codes, so both buffers execute in a single ``pallas_call`` with no
-    second dispatch and no second weight unpack.
 
-    ``counts`` (E, 2) int32 are per-(expert, precision-group) live-slot
-    watermarks: a group's m-blocks at or beyond ``ceil(count/bm)`` are
-    DEAD — their x/weight index maps pin to block (0, 0, 0) (consecutive
-    identical block indices elide the DMA) and the kernel body skips the
-    unpack + MXU work outright, so finished/evicted/padded rows cost no
-    FLOPs and no weight I/O. Contract: slots at or beyond a group's
-    watermark must be zero-filled (the dispatch scatter guarantees this),
-    so a skipped block's zero output equals its dot exactly.
+def _vmem_bytes(bm: int, bn: int, bk: int, hi_bits: int, lo_bits: int,
+                group_size: int, dtype, out_dtype) -> int:
+    """One grouped kernel call's blocks, double-buffered, and its f32
+    accumulator, as the vmem-footprint lint counts them (scale rows
+    padded to whole f32 sublane tiles)."""
+    scales = -(-(bk // group_size) // 8) * 8 * bn * 4
+    blocks = (bm * bk * jnp.dtype(dtype).itemsize
+              + bm * bn * jnp.dtype(out_dtype).itemsize)
+    for bits in (hi_bits, lo_bits) if lo_bits else (hi_bits,):
+        blocks += bn * bk * bits // 8 + scales
+    return 2 * blocks + bm * bn * 4
 
-    Returns (E, M, N) in ``out_dtype``, region layout matching ``x``.
-    """
+
+def _n_block(n: int, fits) -> int:
+    """N itself, else the widest multiple of 128 that divides N, for which
+    ``fits(bn)``; 128 (or N, where N is narrower) if none does."""
+    for bn in [n] + list(range(n - n % 128, 127, -128)):
+        if n % bn == 0 and fits(bn):
+            return bn
+    return min(128, n)
+
+
+class GroupedTiling(NamedTuple):
+    """The static tiling of one grouped kernel call: its row, N and K
+    tiles, the grid ``(E * P, row blocks, N tiles, K tiles)``, the body,
+    and the VMEM limit the call sets."""
+    bm: int
+    bn: int
+    bk: int
+    grid: Tuple[int, int, int, int]
+    scale_after_dot: bool
+    vmem_limit_bytes: int
+
+
+def grouped_tiling(e: int, cap_hi: int, cap_lo: int, n: int, k: int, *,
+                   hi_bits: int, lo_bits: int, group_size: int,
+                   block_m: int, block_k: int, dtype,
+                   out_dtype=jnp.bfloat16,
+                   n_block: Optional[int] = None) -> GroupedTiling:
+    """The tiling :func:`expert_quant_matmul_grouped_pallas` builds for E
+    experts with precision regions of ``cap_hi`` and ``cap_lo`` rows
+    (``cap_lo`` 0: the "4/0" deployment), contraction K and output width N.
+
+    Where the body applies the scales after the dot (every decode block,
+    admission waves of a few rows), one grid step covers the whole output
+    width: N itself, or the widest multiple of 128 dividing N whose working
+    blocks fit ``_VMEM_BUDGET``. Each grid step has a fixed cost, and a
+    decode call's steps are mostly dead, so fewer steps is the gain; K is
+    tiled as before, so every output sums the same partial products in the
+    same order. The dequantize-first body (128-row blocks) keeps 128
+    columns. ``n_block`` forces the N tile (measurements and tests)."""
+    p_ = 2 if cap_lo else 1
+    bm = _row_block(block_m, max(cap_hi, cap_lo), dtype, out_dtype)
+    bk = _k_block(block_k, k, group_size)
+    after = _scale_after_dot(bm, bk, group_size, dtype)
+    lo = lo_bits if cap_lo else 0
+
+    def need(bn):
+        return _vmem_bytes(bm, bn, bk, hi_bits, lo, group_size, dtype,
+                           out_dtype)
+
+    if n_block is not None:
+        bn = min(n_block, n)
+    elif after:
+        bn = _n_block(n, lambda c: need(c) <= _VMEM_BUDGET)
+    else:
+        bn = min(128, n)
+    grid = (e * p_, -(-max(cap_hi, cap_lo) // bm), -(-n // bn), -(-k // bk))
+    # as much again for the body's temporaries and the compiler's scratch
+    return GroupedTiling(bm, bn, bk, grid, after,
+                         max(_VMEM_BUDGET, 2 * need(bn)))
+
+
+def grouped_tables(counts: jnp.ndarray, cap_hi: int, cap_lo: int,
+                   bm: int) -> jnp.ndarray:
+    """The grouped kernel's scalar-prefetch table, four (E * P,) int32
+    rows laid end to end: each group's live row blocks ``nb``, then, for
+    the x blocks, the hi and the lo weight blocks, the last group at or
+    before each group that reads that buffer (-1 where none does)."""
+    p_ = 2 if cap_lo else 1
+    caps = jnp.asarray((cap_hi, cap_lo)[:p_], jnp.int32)
+    wm = jnp.clip(jnp.asarray(counts, jnp.int32)[:, :p_], 0, caps[None, :])
+    nb = ((wm + bm - 1) // bm).reshape(-1)
+    g = jnp.arange(nb.shape[0], dtype=jnp.int32)
+
+    def last(live):
+        return jax.lax.cummax(jnp.where(live, g, -1), axis=0)
+
+    live = nb > 0
+    return jnp.concatenate([nb, last(live), last(live & (g % p_ == 0)),
+                            last(live & (g % p_ == 1))])
+
+
+def grouped_index_maps(grid: Tuple[int, int, int, int], p_: int):
+    """Block index maps of the grouped kernel over ``grid``, with ``p_``
+    precision groups per expert, reading the table of
+    :func:`grouped_tables`: x, hi codes, hi scales, lo codes, lo scales
+    and the output, each ``f(g, i, j, kk, t)``.
+
+    A step that does not read a buffer (a dead row block, or the precision
+    its group does not use) maps it to the block it already holds: the
+    last block of the last group before it that read it, or block 0 where
+    none has. The pipeline elides the copy of a block index that repeats,
+    so only live blocks move, plus each buffer's first block."""
+    groups, nb_cap, nj, nk = grid
+
+    def pick(use, live, held):
+        return tuple(jnp.where(use, a, b) for a, b in zip(live, held))
+
+    def last(t, row, g):
+        q = t[row * groups + g]
+        return jnp.maximum(q, 0), q >= 0
+
+    def x_map(g, i, j, kk, t):
+        q, on = last(t, 1, g)
+        held = (q // p_, jnp.where(on, (q % p_) * nb_cap + t[q] - 1, 0),
+                jnp.where(on, nk - 1, 0))
+        return pick(i < t[g], (g // p_, (g % p_) * nb_cap + i, kk), held)
+
+    def weights(region):
+        def codes(g, i, j, kk, t):
+            q, on = last(t, 2 + region, g)
+            use = (g % p_ == region) & (i < t[g])
+            return pick(use, (g // p_, j, kk),
+                        (q // p_, jnp.where(on, nj - 1, 0),
+                         jnp.where(on, nk - 1, 0)))
+
+        def scales(g, i, j, kk, t):
+            e_, j_, k_ = codes(g, i, j, kk, t)
+            return e_, k_, j_
+        return codes, scales
+
+    def out_map(g, i, j, kk, t):
+        return g // p_, (g % p_) * nb_cap + i, j
+
+    return (x_map, *weights(0), *weights(1), out_map)
+
+
+def _grouped_pallas(x, hi_packed, hi_scales, lo_packed, lo_scales, counts, *,
+                    cap_hi, hi_bits, lo_bits, group_size, block_m, block_k,
+                    interpret, out_dtype, n_block=None):
     e, m, k = x.shape
     n = hi_packed.shape[1]
     vpb_hi = 8 // hi_bits
@@ -416,18 +537,19 @@ def expert_quant_matmul_grouped_pallas(
         vpb_lo = 8 // lo_bits
         assert lo_packed.shape == (e, n, k // vpb_lo)
         assert lo_scales.shape == (e, k // group_size, n)
+    assert k % group_size == 0, (k, group_size)
     p_ = 2 if has_lo else 1
 
-    cap = max(cap_hi, cap_lo)
-    bm = _row_block(block_m, cap, x.dtype, out_dtype)
-    bn, bk = min(block_n, n), _k_block(block_k, k, group_size)
-    assert k % group_size == 0, (k, group_size)
-
-    after = _scale_after_dot(bm, bk, group_size, x.dtype)
+    tiling = grouped_tiling(e, cap_hi, cap_lo, n, k, hi_bits=hi_bits,
+                            lo_bits=lo_bits, group_size=group_size,
+                            block_m=block_m, block_k=block_k, dtype=x.dtype,
+                            out_dtype=out_dtype, n_block=n_block)
+    bm, bn, bk, grid = tiling.bm, tiling.bn, tiling.bk, tiling.grid
+    after = tiling.scale_after_dot
+    _, nb_cap, nj, nk = grid
 
     # both regions are padded to the SAME m-block count so every group's
     # output tile index stays in range regardless of the cap split
-    nb_cap = -(-cap // bm)
     rows = nb_cap * bm
 
     def region(lo_, hi_, bits):
@@ -445,42 +567,11 @@ def expert_quant_matmul_grouped_pallas(
     if has_lo:
         lp = _pad_to(_pad_to(lo_packed, 1, bn), 2, bk // vpb_lo)
         ls = _pad_to(_pad_to(lo_scales, 1, bk // group_size), 2, bn)
-    kp_ = xp.shape[2]
-    np_ = hp.shape[1]
-    nk = kp_ // bk
-    grid = (e * p_, nb_cap, np_ // bn, nk)
+    np_ = nj * bn
 
-    # (E, P) watermarks -> (E*P,) live m-block counts, the scalar-prefetch
-    # table every index map consults
-    caps = jnp.asarray((cap_hi, cap_lo)[:p_], jnp.int32)
-    wm = jnp.clip(jnp.asarray(counts, jnp.int32)[:, :p_], 0, caps[None, :])
-    nb = ((wm + bm - 1) // bm).reshape(-1)
-
-    def x_map(g, i, j, kk, t):
-        use = i < t[g]
-        return (jnp.where(use, g // p_, 0),
-                jnp.where(use, (g % p_) * nb_cap + i, 0),
-                jnp.where(use, kk, 0))
-
-    def hi_map(g, i, j, kk, t):
-        use = (g % p_ == 0) & (i < t[g])
-        return (jnp.where(use, g // p_, 0), jnp.where(use, j, 0),
-                jnp.where(use, kk, 0))
-
-    def hi_s_map(g, i, j, kk, t):
-        use = (g % p_ == 0) & (i < t[g])
-        return (jnp.where(use, g // p_, 0), jnp.where(use, kk, 0),
-                jnp.where(use, j, 0))
-
-    def lo_map(g, i, j, kk, t):
-        use = (g % p_ == 1) & (i < t[g])
-        return (jnp.where(use, g // p_, 0), jnp.where(use, j, 0),
-                jnp.where(use, kk, 0))
-
-    def lo_s_map(g, i, j, kk, t):
-        use = (g % p_ == 1) & (i < t[g])
-        return (jnp.where(use, g // p_, 0), jnp.where(use, kk, 0),
-                jnp.where(use, j, 0))
+    table = grouped_tables(counts, cap_hi, cap_lo, bm)
+    x_map, hi_map, hi_s_map, lo_map, lo_s_map, out_map = \
+        grouped_index_maps(grid, p_)
 
     in_specs = [
         pl.BlockSpec((1, bm, bk), x_map),
@@ -506,18 +597,60 @@ def expert_quant_matmul_grouped_pallas(
         num_scalar_prefetch=1,
         grid=grid,
         in_specs=in_specs,
-        out_specs=pl.BlockSpec(
-            (1, bm, bn),
-            lambda g, i, j, kk, t: (g // p_, (g % p_) * nb_cap + i, j)),
+        out_specs=pl.BlockSpec((1, bm, bn), out_map),
         scratch_shapes=[pltpu.VMEM((bm, bn), jnp.float32)],
     )
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((e, p_ * rows, np_), out_dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=tiling.vmem_limit_bytes),
         interpret=interpret,
-    )(nb, *operands)
+    )(table, *operands)
     if has_lo:
         return jnp.concatenate(
             [out[:, :cap_hi, :n], out[:, rows:rows + cap_lo, :n]], axis=1)
     return out[:, :m, :n]
+
+
+@functools.partial(
+    jax.jit,
+    static_argnames=("cap_hi", "hi_bits", "lo_bits", "group_size",
+                     "block_m", "block_k", "interpret", "out_dtype"),
+)
+def expert_quant_matmul_grouped_pallas(
+        x: jnp.ndarray, hi_packed: jnp.ndarray, hi_scales: jnp.ndarray,
+        lo_packed: Optional[jnp.ndarray], lo_scales: Optional[jnp.ndarray],
+        counts: jnp.ndarray, *, cap_hi: int, hi_bits: int, lo_bits: int,
+        group_size: int, block_m: int = 128, block_k: int = 512,
+        interpret: bool = False, out_dtype=jnp.bfloat16) -> jnp.ndarray:
+    """ONE dispatch over a combined dual-precision capacity buffer with a
+    live-row ragged grid.
+
+    ``x`` (E, M, K) packs BOTH precision regions of the dual-buffer per-row
+    MoE dispatch per expert: high-precision slots occupy ``[0, cap_hi)``
+    and low-precision slots ``[cap_hi, M)``. The grid is
+    ``(E * P, M_region/bm, N/bn, K/bk)`` with P precision groups (2, or 1
+    when ``lo_packed is None`` — the "4/0" lo group is elided at grid
+    construction): each grid step streams exactly one precision's packed
+    codes, so both buffers execute in a single ``pallas_call`` with no
+    second dispatch and no second weight unpack. The tiles come from the
+    shapes (:func:`grouped_tiling`).
+
+    ``counts`` (E, 2) int32 are per-(expert, precision-group) live-slot
+    watermarks: a group's m-blocks at or beyond ``ceil(count/bm)`` are
+    DEAD — their index maps repeat the blocks already held
+    (:func:`grouped_index_maps`), so they move no bytes, and the kernel
+    body skips the unpack + MXU work outright, so finished/evicted/padded
+    rows cost no FLOPs and no weight I/O. Contract: slots at or beyond a
+    group's watermark must be zero-filled (the dispatch scatter guarantees
+    this), so a skipped block's zero output equals its dot exactly.
+
+    Returns (E, M, N) in ``out_dtype``, region layout matching ``x``.
+    """
+    return _grouped_pallas(
+        x, hi_packed, hi_scales, lo_packed, lo_scales, counts, cap_hi=cap_hi,
+        hi_bits=hi_bits, lo_bits=lo_bits, group_size=group_size,
+        block_m=block_m, block_k=block_k, interpret=interpret,
+        out_dtype=out_dtype)

@@ -181,8 +181,7 @@ def expert_quant_matmul_grouped(x: jnp.ndarray,
                                 counts: Optional[jnp.ndarray] = None, *,
                                 cap_hi: int, impl: Optional[str] = None,
                                 interpret: bool = False,
-                                block_m: int = 128, block_n: int = 128,
-                                block_k: int = 512,
+                                block_m: int = 128, block_k: int = 512,
                                 out_dtype=jnp.bfloat16) -> jnp.ndarray:
     """ONE fused dispatch for the dual-buffer per-row MoE.
 
@@ -221,8 +220,8 @@ def expert_quant_matmul_grouped(x: jnp.ndarray,
             lo.scales if lo is not None else None,
             jnp.asarray(counts, jnp.int32), cap_hi=cap_hi,
             hi_bits=hi.bits, lo_bits=lo_bits, group_size=hi.group_size,
-            block_m=block_m, block_n=block_n, block_k=block_k,
-            interpret=interpret, out_dtype=out_dtype)
+            block_m=block_m, block_k=block_k, interpret=interpret,
+            out_dtype=out_dtype)
     if impl == "ref":
         f = _grouped_rows_aware(hi.bits, lo_bits, hi.group_size, cap_hi,
                                 jnp.dtype(out_dtype).name, lo is not None)

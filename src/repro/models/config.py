@@ -34,10 +34,11 @@ class DyMoEPolicy:
     heavy_hitter_frac: float = 0.2  # top-k token fraction for Eq. (2)
     prefetch_topk: int = 2  # top-t experts prefetched per layer (Eq. 7/8)
     depth_schedule: str = "cosine"  # cosine | equal | linear
-    # Pallas tile sizes for the grouped/fused expert quant-matmuls.
-    # Edge-sized d_model/d_ff configs override these so tiny dispatches
-    # don't zero-pad to oversized tiles (see configs/qwen3_0p6b.py,
-    # configs/olmoe_1b_7b.py).
+    # Pallas tile sizes for the expert and dense quant-matmuls. Edge-sized
+    # d_model/d_ff configs override these so tiny dispatches don't
+    # zero-pad to oversized tiles (see configs/qwen3_0p6b.py,
+    # configs/olmoe_1b_7b.py). The grouped expert kernel reads block_m
+    # and block_k only: it chooses its N tile from the shapes.
     block_m: int = 128
     block_n: int = 128
     block_k: int = 512

@@ -225,15 +225,18 @@ def _expert_ffn_grouped(qweights: dict, xb: jnp.ndarray,
     instead of one dispatch per precision — and ``counts`` (E, 2)
     live-slot watermarks let the kernel skip dead row blocks outright
     (finished/evicted/padded slots cost no FLOPs and no weight I/O).
-    ``mesh``: see :func:`_over_local_experts`."""
+    The kernel takes its N tile from the shapes, so of ``blocks`` only
+    ``block_m`` and ``block_k`` reach it. ``mesh``: see
+    :func:`_over_local_experts`."""
     from repro.kernels.quant_matmul.ops import expert_quant_matmul_grouped
+
+    tiles = {k: blocks[k] for k in ("block_m", "block_k")} if blocks else {}
 
     def ffn(qw, xb, counts):
         def mm(name, h):
             return expert_quant_matmul_grouped(h, qw[name], counts,
                                                cap_hi=cap_hi,
-                                               out_dtype=xb.dtype,
-                                               **(blocks or {}))
+                                               out_dtype=xb.dtype, **tiles)
 
         h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
         return mm("w_down", h)

@@ -82,7 +82,7 @@ def test_grouped_pallas_interpret_matches_oracle_ragged(hi, lo):
                                       out_dtype=jnp.float32)
     pal = expert_quant_matmul_grouped(
         x, mp, jnp.asarray(counts), cap_hi=cap, impl="pallas",
-        interpret=True, block_m=4, block_n=16, block_k=32,
+        interpret=True, block_m=4, block_k=32,
         out_dtype=jnp.float32)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
                                atol=5e-4, rtol=1e-4)

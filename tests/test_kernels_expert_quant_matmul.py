@@ -11,7 +11,7 @@ from repro.analysis import count_pallas_calls, intermediate_avals
 from repro.analysis.rules import FLOAT_DTYPES
 from repro.kernels.quant_matmul.expert_quant_matmul import \
     _scale_after_dot, expert_quant_matmul_grouped_pallas, \
-    grouped_scales_after_dot
+    grouped_scales_after_dot, grouped_tiling
 from repro.kernels.quant_matmul.ops import expert_quant_matmul, force_impl
 from repro.kernels.quant_matmul.ref import expert_quant_matmul_grouped_ref
 from repro.models.config import DyMoEPolicy, ModelConfig
@@ -113,25 +113,51 @@ def test_vmaps_for_sharded_dispatch():
 
 # ------------------------------------- grouped kernel, scales after dot
 # Decode shapes of OLMoE-1B-7B (w_gate/w_up K=2048 N=1024, w_down K=1024
-# N=2048) and Qwen3-30B-A3B (N=768; w_down K=768, padded to two K tiles)
-# at 16 rows a region, group 64, the serving tiles (bn 128, bk 512).
+# N=2048), Qwen3-30B-A3B (N=768; w_down K=768, padded to two K tiles) and
+# Moonlight-16B-A3B (N=1408 = 11 x 128; w_down K=1408, padded to three K
+# tiles) at 16 rows a region, group 64, bk 512. The shape-chosen N tile is
+# the whole width N there; the 128-column tile is the one it replaced.
+
+_SERVED_KN = [(2048, 1024), (1024, 2048), (2048, 768), (768, 2048),
+              (2048, 1408), (1408, 2048)]
+# per-expert (hi, lo) watermarks at cap 16: "mixed" holds 0, partial and
+# full in each region (the 2-bit region live, as at admission prefill);
+# "alternate" has live and dead hi groups in turn, the lo region dead in
+# most, as at decode
+_WATERMARKS = {"mixed": [[0, 16], [5, 0], [16, 9]],
+               "alternate": [[16, 0], [0, 0], [7, 0], [0, 3], [16, 0]]}
 
 
+def _grouped_call(x, mp, counts, cap, lo, n_block=None):
+    from repro.kernels.quant_matmul.expert_quant_matmul import \
+        _grouped_pallas
+
+    lp, ls = (None, None) if lo is None else (mp.low.packed, mp.low.scales)
+    f = jax.jit(lambda *a: _grouped_pallas(
+        *a, cap_hi=cap, hi_bits=4, lo_bits=lo or 0, group_size=64,
+        block_m=32, block_k=512, interpret=True, out_dtype=jnp.float32,
+        n_block=n_block))
+    return np.asarray(f(x, mp.high.packed, mp.high.scales, lp, ls,
+                        jnp.asarray(counts)))
+
+
+@pytest.mark.parametrize("pattern", sorted(_WATERMARKS))
 @pytest.mark.parametrize("lo", [2, None], ids=["4/2", "4/0"])
-@pytest.mark.parametrize("k,n", [(2048, 1024), (1024, 2048), (2048, 768),
-                                 (768, 2048)])
-def test_grouped_scale_after_dot_matches_oracle(k, n, lo):
+@pytest.mark.parametrize("k,n", _SERVED_KN)
+def test_grouped_scale_after_dot_matches_oracle(k, n, lo, pattern):
     """bf16 codes on the MXU with the f32 group scales applied to the
     per-group partial sums match the dequantize-first f32 oracle up to f32
-    summation order. Three experts hold watermarks 0, partial and full in
-    each region; the 2-bit region is live, as at admission prefill."""
-    cap, e, gs = 16, 3, 64
+    summation order. The whole-width N tile the wrapper chooses gives the
+    128-column tile's output bit for bit: K is tiled alike, so every
+    output sums the same partial products in the same order."""
+    cap, gs = 16, 64
+    counts = np.array(_WATERMARKS[pattern], np.int32)
+    e = counts.shape[0]
     rng = np.random.default_rng(k + n)
     w = jnp.asarray(rng.standard_normal((e, k, n)) / np.sqrt(k),
                     jnp.float32)
     mp = MixedPrecisionWeights.build(w, 4, lo, gs)
     m = cap if lo is None else 2 * cap
-    counts = np.array([[0, cap], [5, 0], [cap, 9]], np.int32)
     if lo is None:
         counts[:, 1] = 0
     x = rng.standard_normal((e, m, k)).astype(np.float32)
@@ -141,16 +167,148 @@ def test_grouped_scale_after_dot_matches_oracle(k, n, lo):
     x = jnp.asarray(x, jnp.bfloat16)
     lp, ls = (None, None) if lo is None else (mp.low.packed, mp.low.scales)
     assert _scale_after_dot(16, 512, gs, x.dtype)
+    assert grouped_tiling(e, cap, 0 if lo is None else cap, n, k, hi_bits=4,
+                          lo_bits=lo or 0, group_size=gs, block_m=32,
+                          block_k=512, dtype=x.dtype).bn == n
     ref = np.asarray(expert_quant_matmul_grouped_ref(
         x, mp.high.packed, mp.high.scales, lp, ls, cap_hi=cap, hi_bits=4,
         lo_bits=lo or 0, group_size=gs, out_dtype=jnp.float32))
     pal = np.asarray(expert_quant_matmul_grouped_pallas(
         x, mp.high.packed, mp.high.scales, lp, ls, jnp.asarray(counts),
         cap_hi=cap, hi_bits=4, lo_bits=lo or 0, group_size=gs, block_m=32,
-        block_n=128, block_k=512, interpret=True, out_dtype=jnp.float32))
+        block_k=512, interpret=True, out_dtype=jnp.float32))
+    np.testing.assert_array_equal(
+        pal, _grouped_call(x, mp, counts, cap, lo, n_block=128))
     scale = np.abs(ref).max()
     np.testing.assert_allclose(pal, ref, rtol=1e-5, atol=1e-5 * scale)
-    assert not pal[0, :cap].any() and not pal[1, cap:].any()
+    for ei, (c_hi, c_lo) in enumerate(counts):   # dead rows: exact zero
+        assert not pal[ei, c_hi:cap].any() and not pal[ei, cap + c_lo:].any()
+
+
+# grid steps a call of w_gate, w_up and w_down at decode (16 rows a region,
+# 8 for Moonlight), and the MoE layers of each cell's depth
+_DECODE_STEPS = {"olmoe_1b_7b": ((512, 512, 256), 16, 16),
+                 "qwen3_30b_a3b": ((1024, 1024, 512), 16, 16),
+                 "moonlight_16b_a3b": ((512, 512, 384), 8, 15)}
+# each cell's largest admission wave: rows, longest prompt
+_LARGEST_WAVE = {"olmoe_1b_7b": (16, 256), "qwen3_30b_a3b": (16, 256),
+                 "moonlight_16b_a3b": (8, 2048)}
+
+
+@pytest.mark.parametrize("phase", ["decode", "wave"])
+@pytest.mark.parametrize("name", sorted(_DECODE_STEPS))
+def test_grouped_tile_and_grid_by_config(name, phase):
+    """The N tile is chosen from the shapes: N itself or a multiple of 128
+    that divides N. The grid the wrapper builds is the one
+    :func:`grouped_tiling` reports, at decode the table of steps a call
+    (OLMoE 20,480 a decode step of 16 layers, from 196,608 at 128
+    columns), and the call passes the vmem-footprint lint."""
+    from repro.analysis import estimate_pallas_vmem, iter_eqns
+    from repro.analysis.rules import LintTarget, run_rules
+    from repro.configs import get_config
+    from repro.models.layers.moe import _capacity
+
+    cfg = get_config(name)
+    pol, e = cfg.dymoe, cfg.num_experts
+    steps, rows, layers = _DECODE_STEPS[name]
+    if phase == "wave":
+        n_rows, prompt = _LARGEST_WAVE[name]
+        rows = n_rows * _capacity(cfg, prompt)
+    dm, dff = cfg.d_model, cfg.expert_d_ff
+    got = []
+    for k, n in ((dm, dff), (dm, dff), (dff, dm)):
+        t = grouped_tiling(e, rows, rows, n, k, hi_bits=pol.high_bits,
+                           lo_bits=pol.low_bits, group_size=pol.group_size,
+                           block_m=pol.block_m, block_k=pol.block_k,
+                           dtype=cfg.dtype)
+        assert t.bn == n or (t.bn % 128 == 0 and n % t.bn == 0), t
+        assert t.bn == n or not t.scale_after_dot, t
+        got.append(int(np.prod(t.grid)))
+
+        sds = jax.ShapeDtypeStruct
+        args = (sds((e, 2 * rows, k), jnp.bfloat16),
+                sds((e, n, k * pol.high_bits // 8), jnp.uint8),
+                sds((e, k // pol.group_size, n), jnp.float32),
+                sds((e, n, k * pol.low_bits // 8), jnp.uint8),
+                sds((e, k // pol.group_size, n), jnp.float32),
+                sds((e, 2), jnp.int32))
+        jaxpr = jax.make_jaxpr(
+            lambda *a: expert_quant_matmul_grouped_pallas(
+                *a, cap_hi=rows, hi_bits=pol.high_bits,
+                lo_bits=pol.low_bits, group_size=pol.group_size,
+                block_m=pol.block_m, block_k=pol.block_k))(*args)
+        (site,) = [s for s in iter_eqns(jaxpr, into_kernels=False)
+                   if s.eqn.primitive.name == "pallas_call"]
+        assert estimate_pallas_vmem(site.eqn).grid == t.grid
+        assert not run_rules(LintTarget(name=f"{name}/{phase}", cfg=cfg,
+                                        phase="decode_chunk", jaxpr=jaxpr),
+                             only=["vmem-footprint"])
+    if phase == "decode":
+        assert tuple(got) == steps
+        assert sum(got) * layers == {"olmoe_1b_7b": 20480,
+                                     "qwen3_30b_a3b": 40960,
+                                     "moonlight_16b_a3b": 21120}[name]
+
+
+def _fetches(maps, grid, table):
+    """Walk ``grid`` in grid order: per operand, the block indices the
+    pipeline copies in (the first step's, then every change)."""
+    out = [[] for _ in maps]
+    for g, i, j, kk in np.ndindex(*grid):
+        for f, seen in zip(maps, out):
+            blk = tuple(int(v) for v in f(g, i, j, kk, table))
+            if not seen or seen[-1] != blk:
+                seen.append(blk)
+    return out
+
+
+@pytest.mark.parametrize("case", ["decode", "wave"])
+def test_grouped_dead_steps_fetch_nothing(case):
+    """Dead steps, and the precision a group does not read, repeat the
+    block their buffer holds: the hi and lo weight buffers copy in only
+    the blocks of live steps, each once, plus at most one first block.
+    "decode": one row block, one N tile, four K tiles, no 2-bit group
+    live, live and dead hi groups mixed; "wave": row blocks beyond a
+    group's watermark and several N and K tiles."""
+    from repro.kernels.quant_matmul.expert_quant_matmul import \
+        grouped_index_maps, grouped_tables
+
+    rng = np.random.default_rng(7)
+    if case == "decode":
+        e, cap, bm, nj, nk = 16, 16, 16, 1, 4
+        counts = np.zeros((e, 2), np.int32)
+        counts[rng.choice(e, 9, replace=False), 0] = cap
+    else:
+        e, cap, bm, nj, nk = 6, 48, 16, 2, 3
+        counts = rng.integers(0, cap + 1, (e, 2)).astype(np.int32)
+        counts[1] = 0
+    nb_cap = -(-cap // bm)
+    grid = (2 * e, nb_cap, nj, nk)
+    table = grouped_tables(jnp.asarray(counts), cap, cap, bm)
+    x_map, hc, hs, lc, ls, _ = grouped_index_maps(grid, 2)
+    x_f, hc_f, hs_f, lc_f, ls_f = _fetches((x_map, hc, hs, lc, ls), grid,
+                                           table)
+
+    def live_blocks(region, order):
+        out = []
+        for g, i, j, kk in np.ndindex(*grid):
+            nb = -(-int(counts[g // 2, g % 2]) // bm)
+            if g % 2 == region and i < nb:
+                blk = (g // 2, j, kk) if order == "jk" else (g // 2, kk, j)
+                if not out or out[-1] != blk:
+                    out.append(blk)
+        return out
+
+    for region, codes, scales in ((0, hc_f, hs_f), (1, lc_f, ls_f)):
+        live = live_blocks(region, "jk")
+        assert codes in (live, codes[:1] + live), (region, codes, live)
+        assert scales in (live_blocks(region, "kj"),
+                          scales[:1] + live_blocks(region, "kj"))
+    live_x = sum(-(-int(c) // bm) for c in counts.reshape(-1)) * nj * nk
+    assert len(x_f) <= live_x + 1
+    if case == "decode":
+        assert lc_f == [(0, 0, 0)] and ls_f == [(0, 0, 0)]
+        assert len(hc_f) <= 9 * nk + 1
 
 
 def test_scale_after_dot_is_chosen_by_shape():

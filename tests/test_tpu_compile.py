@@ -74,8 +74,7 @@ def test_grouped_kernel_compiles_for_v5e(one_chip, cap, lo_bits):
     a prefill-sized one, with and without the low-bit region."""
     blocks = get_config("olmoe_1b_7b").dymoe
     kw = dict(cap_hi=cap, hi_bits=4, lo_bits=lo_bits, group_size=GS,
-              block_m=blocks.block_m, block_n=blocks.block_n,
-              block_k=blocks.block_k)
+              block_m=blocks.block_m, block_k=blocks.block_k)
     hp, hs = _store(4, one_chip)
     x = _sds((E, cap * (2 if lo_bits else 1), K), jnp.bfloat16, one_chip)
     counts = _sds((E, 2), jnp.int32, one_chip)
@@ -103,12 +102,42 @@ def test_grouped_kernel_compiles_for_v5e_at_qwen3_widths(one_chip, k, n,
     blocks = get_config("qwen3_30b_a3b").dymoe
     e = 128
     kw = dict(cap_hi=cap, hi_bits=4, lo_bits=2, group_size=GS,
-              block_m=blocks.block_m, block_n=blocks.block_n,
-              block_k=blocks.block_k)
+              block_m=blocks.block_m, block_k=blocks.block_k)
     assert grouped_scales_after_dot(cap, k, group_size=GS,
                                     block_m=blocks.block_m,
                                     block_k=blocks.block_k,
                                     dtype=jnp.bfloat16)
+    hp, hs = _store(4, one_chip, e=e, n=n, k=k)
+    lp, ls = _store(2, one_chip, e=e, n=n, k=k)
+    x = _sds((e, 2 * cap, k), jnp.bfloat16, one_chip)
+    counts = _sds((e, 2), jnp.int32, one_chip)
+    text = _compiled_text(
+        lambda x, hp, hs, lp, ls, c: expert_quant_matmul_grouped_pallas(
+            x, hp, hs, lp, ls, c, **kw), x, hp, hs, lp, ls, counts)
+    assert "tpu_custom_call" in text
+    assert "expert_quant_matmul_grouped_pallas" in text
+
+
+@pytest.mark.parametrize("cap", [8, 1920])
+@pytest.mark.parametrize("k,n", [(2048, 1408), (1408, 2048)],
+                         ids=["gate_up", "down"])
+def test_grouped_kernel_compiles_for_v5e_at_moonlight_widths(one_chip, k, n,
+                                                             cap):
+    """Moonlight-16B-A3B's experts (E=64, width 1408 = 11 x 128; w_down's
+    K=1408 pads to three K tiles) at decode (8 rows) and at an (8, 2048)
+    admission wave (240 rows a prompt): one N tile spans the whole width,
+    and Mosaic takes it within the VMEM limit the call sets."""
+    from repro.kernels.quant_matmul.expert_quant_matmul import \
+        grouped_tiling
+
+    blocks = get_config("moonlight_16b_a3b").dymoe
+    e = 64
+    kw = dict(cap_hi=cap, hi_bits=4, lo_bits=2, group_size=GS,
+              block_m=blocks.block_m, block_k=blocks.block_k)
+    tiling = grouped_tiling(e, cap, cap, n, k, hi_bits=4, lo_bits=2,
+                            group_size=GS, block_m=blocks.block_m,
+                            block_k=blocks.block_k, dtype=jnp.bfloat16)
+    assert tiling.bn == n and tiling.scale_after_dot
     hp, hs = _store(4, one_chip, e=e, n=n, k=k)
     lp, ls = _store(2, one_chip, e=e, n=n, k=k)
     x = _sds((e, 2 * cap, k), jnp.bfloat16, one_chip)
