@@ -248,11 +248,7 @@ def fused_vs_dual_decode(smoke: bool = False) -> List[dict]:
     across all three (a row never feels its dead neighbours, the shrink,
     or its slot index) and dead rows' tokens stay frozen. ``--smoke``
     asserts parity always; the straggler speedup only on >2-core runners
-    (tiny-model wall-clock is scheduler-noise-bound below that).
-    Alongside the measured walls, the modeled per-layer weight traffic
-    of the fused ragged dispatch vs the pre-fused dual-dispatch pair
-    (every expert's full blob, both precisions, every step) comes from
-    the cost model — the number the TPU-target latency model rides on."""
+    (tiny-model wall-clock is scheduler-noise-bound below that)."""
     import os
     from functools import partial
 
@@ -305,14 +301,9 @@ def fused_vs_dual_decode(smoke: bool = False) -> List[dict]:
                  for n in ("half_done", "half_done_livecap"))
     speedup = walls["all_live"] / walls["half_done_livecap"]
 
-    cost = EdgeCostModel(cfg, EdgeProfile())
-    dual_bytes = cost.dual_dispatch_weight_bytes(include_shared=False)
     rows = []
     for name in variants:
         n_live = int((~variants[name][0]).sum())
-        # fused ragged traffic: at most live*k experts hold live slots
-        n_hi = min(cfg.num_experts, n_live * cfg.num_experts_per_tok)
-        fused_bytes = cost.moe_weight_bytes(n_hi, 0, include_shared=False)
         rows.append(dict(
             bench="fused_vs_dual", arch=cfg.name, variant=name,
             live_rows=n_live, num_slots=b, decode_steps=steps,
@@ -322,10 +313,7 @@ def fused_vs_dual_decode(smoke: bool = False) -> List[dict]:
             if n_live else 0.0,
             straggler_speedup=(round(speedup, 2)
                                if name == "half_done_livecap" else None),
-            live_tokens_bitwise=parity, dead_tokens_frozen=frozen,
-            modeled_weight_bytes_fused=int(fused_bytes),
-            modeled_weight_bytes_dual=int(dual_bytes),
-            modeled_traffic_ratio=round(fused_bytes / dual_bytes, 4)))
+            live_tokens_bitwise=parity, dead_tokens_frozen=frozen))
     if smoke:
         assert parity, ("live rows' tokens changed under the ragged "
                         "live-row grid / live_cap shrink")

@@ -3,7 +3,6 @@
 
 | module                  | paper artifact                         |
 |-------------------------|----------------------------------------|
-| bench_kernels           | §5 quantized expert kernel             |
 | bench_uniform_quant     | Table 1 (uniform Int2/Int4/BF16)       |
 | bench_retention         | Table 2 / Fig. 11 (4/2 vs 4/0 × r)     |
 | bench_strategies        | Fig. 3 (retention strategies)          |
@@ -21,7 +20,6 @@ import sys
 import time
 
 MODULES = [
-    "bench_kernels",
     "bench_uniform_quant",
     "bench_retention",
     "bench_strategies",

@@ -23,11 +23,12 @@ The invariant contract
    per-block dequantized tile inside a Pallas kernel's VMEM. A dense
    float tensor at full dequantized-weight scale — (E, d_model, d_ff) for
    experts — must never appear as an XLA-materialized intermediate.
-2. **Fused dispatch budget** (PR 7). The fused dual-buffer MoE executes
-   gate/up/down in exactly 3 ``pallas_call`` dispatches per layer-scan
-   body (one per expert matmul; both precision regions inside each). The
-   dual-buffer oracle path is 6 (3 under "4/0"); dense FFN is 3 (swiglu)
-   or 2 (gelu); SSM projections are 2.
+2. **Fused dispatch budget** (PR 7). The MoE executes gate/up/down in
+   exactly 3 ``pallas_call`` dispatches per layer-scan body in every
+   phase and bit mix (one per expert matmul; both precision regions
+   inside each). Latent attention adds its decode kernel once per traced
+   layer body at decode. Dense FFN is 3 (swiglu) or 2 (gelu); SSM
+   projections are 2.
 3. **VMEM discipline**. Every kernel's working set — double-buffered
    blocks + accumulator scratch + scalar prefetch — fits the backend's
    VMEM budget (~16 MiB/core on TPU), for every config's

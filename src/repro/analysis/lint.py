@@ -261,7 +261,7 @@ def build_targets(name: str, cfg: ModelConfig, *,
                 continue
             targets.append(LintTarget(
                 name=tname, cfg=mcfg, phase=phase, jaxpr=jaxpr,
-                fused=True, forbidden_shapes=forbidden))
+                forbidden_shapes=forbidden))
     targets.append(LintTarget(
         name=f"{name}/scheduler/retrace", cfg=cfg, phase="retrace",
         slots=_DECODE_B, ladder=live_cap_for))

@@ -74,7 +74,6 @@ class LintTarget:
     cfg: Any                        # ModelConfig
     phase: str                      # prefill | admission | decode_chunk
     jaxpr: Optional[Any] = None     # ClosedJaxpr (None: accounting-only)
-    fused: bool = True
     backend: str = "tpu"
     # no-dense-dequant: float shapes that must never appear. None =
     # derive from cfg (expert-weight scale).
@@ -156,17 +155,15 @@ def forbidden_weight_shapes(cfg) -> frozenset:
     return frozenset(shapes)
 
 
-def expected_dispatch_count(cfg, *, phase: str, fused: bool = True) -> int:
+def expected_dispatch_count(cfg, *, phase: str) -> int:
     """Exact ``pallas_call`` count per layer-scan body for a quantized
     serving trace (the scan body traces once, so this is also the count
     for the whole jaxpr).
 
-    attn_moe: one grouped kernel per expert matmul — gate/up/down = 3.
-    The dual-dispatch oracle path (``fused=False``) launches one kernel
-    per precision buffer (6), except under "4/0" where the low buffer is
-    never built (3). The batch-shared prefill path (``moe_apply``) runs
-    the critical-masked kernel: both precisions inside ONE dispatch per
-    matmul — 3 regardless of the bit mix.
+    attn_moe: one kernel per expert matmul — gate/up/down = 3, in every
+    phase and bit mix: the per-row paths run the grouped kernel over both
+    precision regions, the batch-shared prefill (``moe_apply``) the
+    critical-masked kernel.
     attn_dense: one kernel per FFN matmul (swiglu 3, gelu 2).
     ssm/hybrid: the two quantized projections (in_proj / out_proj).
     Latent attention adds its decode kernel once per traced layer body
@@ -177,9 +174,7 @@ def expected_dispatch_count(cfg, *, phase: str, fused: bool = True) -> int:
     if kind == "attn_moe":
         mla = (1 + bool(cfg.first_k_dense)
                if cfg.is_mla and phase.startswith("decode") else 0)
-        if fused or cfg.dymoe.low_bits == 0:
-            return 3 + mla
-        return (3 if phase == "prefill" else 6) + mla
+        return 3 + mla
     if kind == "attn_dense":
         return 3 if cfg.mlp_type == "swiglu" else 2
     return 2
@@ -230,14 +225,12 @@ def check_no_dense_dequant(target: LintTarget) -> List[Finding]:
 @rule("pallas-dispatch-budget")
 def check_pallas_dispatch_budget(target: LintTarget) -> List[Finding]:
     """Exactly the budgeted number of fused kernel dispatches per
-    layer-scan body — one per expert matmul on the fused path (3), one
-    per (matmul, precision buffer) on the dual oracle path (6)."""
+    layer-scan body — one per expert matmul (3)."""
     if target.jaxpr is None:
         return []
     expected = target.expected_dispatches
     if expected is None:
-        expected = expected_dispatch_count(
-            target.cfg, phase=target.phase, fused=target.fused)
+        expected = expected_dispatch_count(target.cfg, phase=target.phase)
     sites = [s for s in iter_eqns(target.jaxpr, into_kernels=False)
              if s.eqn.primitive.name == "pallas_call"]
     if len(sites) == expected:

@@ -1,3 +1,4 @@
-from repro.kernels.quant_matmul.ops import quant_matmul, expert_quant_matmul
+from repro.kernels.quant_matmul.ops import expert_quant_matmul, \
+    expert_quant_matmul_grouped
 
-__all__ = ["quant_matmul", "expert_quant_matmul"]
+__all__ = ["expert_quant_matmul", "expert_quant_matmul_grouped"]

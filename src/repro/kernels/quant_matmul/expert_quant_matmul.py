@@ -55,11 +55,32 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.kernels.quant_matmul.quant_matmul import _unpack_dequant
-
 __all__ = ["expert_quant_matmul_pallas", "expert_quant_matmul_grouped_pallas",
            "grouped_scales_after_dot", "grouped_tiling", "GroupedTiling",
            "grouped_tables", "grouped_index_maps"]
+
+
+def _unpack_dequant(packed_tile: jnp.ndarray, scales_tile: jnp.ndarray,
+                    bits: int, group_size: int) -> jnp.ndarray:
+    """(bn, bk/vpb) uint8 codes + (bk/gs, bn) scales -> (bk, bn) f32 weights.
+
+    The codes are transposed first, so K runs along sublanes and N stays
+    on the 128 lanes: unpacking interleaves whole sublane rows and the
+    per-group scale is a sublane split ``(g, gs, bn)``. No step splits or
+    merges the lane dim, which Mosaic cannot relayout.
+    """
+    offset = 1 << (bits - 1)
+    codes = packed_tile.astype(jnp.int32).T                   # (bk/vpb, bn)
+    bkp, bn = codes.shape
+    vpb = 8 // bits
+    if vpb > 1:
+        mask = (1 << bits) - 1
+        parts = [(codes >> (bits * j)) & mask for j in range(vpb)]
+        codes = jnp.stack(parts, axis=1).reshape(bkp * vpb, bn)
+    bk = bkp * vpb
+    g = bk // group_size
+    q = (codes - offset).astype(jnp.float32).reshape(g, group_size, bn)
+    return (q * scales_tile[:, None, :]).reshape(bk, bn)
 
 
 def _dual_kernel(crit_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref, o_ref,

@@ -1,19 +1,12 @@
 """Pure-jnp oracles for the quant_matmul kernels.
 
-``expert_quant_matmul_ref`` streams ONE expert block at a time through a
-``lax.map`` and picks the high- or low-bit representation with a
-``lax.cond`` per expert, so — like the Pallas kernel and unlike the old
-dequantize-everything-and-where path — it never materializes a dense
-``(E, K, N)`` bf16/f32 weight tensor.
-
-``expert_quant_matmul_rows_ref`` is its row-batched twin for the
-continuous-batching decode, where every slot row carries its OWN critical
-mask (x (B, E, M, K), critical (B, E)). Naively vmapping the streaming
-oracle is catastrophic: vmap turns the per-expert ``lax.cond`` into a
-select that dequantizes BOTH precisions PER ROW — B× redundant unpacking
-of row-invariant weights. Here each expert is still streamed one at a
-time (never a dense (E, K, N) weight in flight), dequantized ONCE for all
-rows, and the hi/lo product is selected per (row, expert)."""
+``quant_matmul_ref`` is the plain dequantize-then-dot of one packed
+matrix. ``expert_quant_matmul_ref`` streams ONE expert block at a time
+through a ``lax.map`` and picks the high- or low-bit representation with
+a ``lax.cond`` per expert, so — like the Pallas kernel — it never
+materializes a dense ``(E, K, N)`` weight tensor.
+``expert_quant_matmul_grouped_ref`` is the oracle of the grouped kernel's
+combined dual-precision buffer."""
 from __future__ import annotations
 
 from typing import Optional
@@ -24,9 +17,7 @@ import jax.numpy as jnp
 from repro.quant.quantize import dequantize_tensor
 
 __all__ = ["quant_matmul_ref", "expert_quant_matmul_ref",
-           "expert_quant_matmul_rows_ref", "expert_quant_matmul_fixed_ref",
-           "expert_quant_matmul_grouped_ref",
-           "expert_quant_matmul_grouped_rows_ref"]
+           "expert_quant_matmul_grouped_ref"]
 
 
 def quant_matmul_ref(x: jnp.ndarray, packed: jnp.ndarray, scales: jnp.ndarray,
@@ -78,136 +69,37 @@ def expert_quant_matmul_ref(
     return y.astype(out_dtype)
 
 
-def expert_quant_matmul_fixed_ref(
-        x: jnp.ndarray, packed: jnp.ndarray, scales: jnp.ndarray, *,
-        bits: int, group_size: int, out_dtype=jnp.bfloat16) -> jnp.ndarray:
-    """Grouped matmul with EVERY expert at one fixed precision:
-    x (E, M, K) -> (E, M, N). The dual-buffer per-row MoE dispatch
-    (:func:`repro.models.layers.moe.moe_apply_rows`) splits tokens into a
-    high buffer and a low buffer, so each buffer's grouped matmul needs no
-    per-expert precision branch at all — just the streamed
-    dequantize-and-dot, fully unrolled (tiny independent expert blocks; a
-    sequential while loop's dispatch would dominate them). Per-expert
-    math is identical to the branched oracle's chosen arm."""
-    def one(carry, args):
-        xe, pk, sc = args
-        w = dequantize_tensor(pk, sc, bits, group_size, jnp.float32)
-        return carry, jnp.dot(xe.astype(jnp.float32), w,
-                              preferred_element_type=jnp.float32)
-    _, y = jax.lax.scan(one, None, (x, packed, scales),
-                        unroll=x.shape[0])
-    return y.astype(out_dtype)
-
-
 def expert_quant_matmul_grouped_ref(
         x: jnp.ndarray, hi_packed: jnp.ndarray, hi_scales: jnp.ndarray,
         lo_packed: Optional[jnp.ndarray], lo_scales: Optional[jnp.ndarray],
         *, cap_hi: int, hi_bits: int, lo_bits: int, group_size: int,
         out_dtype=jnp.bfloat16) -> jnp.ndarray:
-    """Single-pass oracle for the fused grouped kernel: ``x`` (E, M, K) is
-    ONE combined capacity buffer per expert — high-precision slots in
-    ``[0, cap_hi)``, low-precision slots in ``[cap_hi, M)``. Each expert
-    streams once and each precision's codes unpack once; the two
-    region-sliced dots have exactly the dual-dispatch path's operand
-    shapes and values, so the fused output is BITWISE the composition of
-    the two :func:`expert_quant_matmul_fixed_ref` calls it replaces.
-    ``lo_packed is None`` ("4/0"): ``cap_hi == M`` and the graph IS the
-    fixed-precision oracle's."""
+    """Oracle for the grouped kernel: ``x`` (E, M, K) is ONE combined
+    capacity buffer per expert — high-precision slots in ``[0, cap_hi)``,
+    low-precision slots in ``[cap_hi, M)``. Each expert streams once, each
+    precision's codes unpack once and multiply their own region slice.
+    ``lo_packed is None`` ("4/0"): ``cap_hi == M`` and the whole buffer is
+    the high region. The scan is fully unrolled: the expert blocks are
+    tiny and independent, and a sequential loop's dispatch would dominate
+    them."""
+    def mm(xe, packed, scales, bits):
+        w = dequantize_tensor(packed, scales, bits, group_size, jnp.float32)
+        return jnp.dot(xe.astype(jnp.float32), w,
+                       preferred_element_type=jnp.float32)
+
     if lo_packed is None:
         assert cap_hi == x.shape[1], (cap_hi, x.shape)
-        return expert_quant_matmul_fixed_ref(
-            x, hi_packed, hi_scales, bits=hi_bits, group_size=group_size,
-            out_dtype=out_dtype)
 
-    def one(carry, args):
-        xe, hp, hs, lp, ls = args
-        w_hi = dequantize_tensor(hp, hs, hi_bits, group_size, jnp.float32)
-        y_hi = jnp.dot(xe[:cap_hi].astype(jnp.float32), w_hi,
-                       preferred_element_type=jnp.float32)
-        w_lo = dequantize_tensor(lp, ls, lo_bits, group_size, jnp.float32)
-        y_lo = jnp.dot(xe[cap_hi:].astype(jnp.float32), w_lo,
-                       preferred_element_type=jnp.float32)
-        return carry, jnp.concatenate([y_hi, y_lo], axis=0)
-
-    _, y = jax.lax.scan(one, None, (x, hi_packed, hi_scales, lo_packed,
-                                    lo_scales), unroll=x.shape[0])
-    return y.astype(out_dtype)
-
-
-def expert_quant_matmul_grouped_rows_ref(
-        x: jnp.ndarray, hi_packed: jnp.ndarray, hi_scales: jnp.ndarray,
-        lo_packed: Optional[jnp.ndarray], lo_scales: Optional[jnp.ndarray],
-        *, cap_hi: int, hi_bits: int, lo_bits: int, group_size: int,
-        out_dtype=jnp.bfloat16) -> jnp.ndarray:
-    """Row-batched twin of :func:`expert_quant_matmul_grouped_ref` for
-    callers that vmap a per-slot program over the combined buffer:
-    x (B, E, M, K) -> (B, E, M, N). Weights carry no batch dim; each
-    expert's codes unpack exactly once per precision, amortized over all
-    B rows (same rationale as :func:`expert_quant_matmul_rows_ref`)."""
-    xt = jnp.moveaxis(x, 1, 0)                            # (E, B, M, K)
-
-    def mm(xe, packed, scales, bits):
-        w = dequantize_tensor(packed, scales, bits, group_size, jnp.float32)
-        return jnp.einsum("bmk,kn->bmn", xe.astype(jnp.float32), w,
-                          preferred_element_type=jnp.float32)
-
-    if lo_packed is None:
-        assert cap_hi == x.shape[2], (cap_hi, x.shape)
-
-        def one(args):
+        def one(carry, args):
             xe, hp, hs = args
-            return mm(xe, hp, hs, hi_bits)
-        xs = (xt, hi_packed, hi_scales)
+            return carry, mm(xe, hp, hs, hi_bits)
+        xs = (x, hi_packed, hi_scales)
     else:
-        def one(args):
+        def one(carry, args):
             xe, hp, hs, lp, ls = args
-            return jnp.concatenate(
-                [mm(xe[:, :cap_hi], hp, hs, hi_bits),
-                 mm(xe[:, cap_hi:], lp, ls, lo_bits)], axis=1)
-        xs = (xt, hi_packed, hi_scales, lo_packed, lo_scales)
-    _, y = jax.lax.scan(lambda c, a: (c, one(a)), None, xs,
-                        unroll=xt.shape[0])
-    return jnp.moveaxis(y, 1, 0).astype(out_dtype)        # (B, E, M, N)
-
-
-def expert_quant_matmul_rows_ref(
-        x: jnp.ndarray, hi_packed: jnp.ndarray, hi_scales: jnp.ndarray,
-        lo_packed: Optional[jnp.ndarray], lo_scales: Optional[jnp.ndarray],
-        critical: jnp.ndarray, *, hi_bits: int, lo_bits: int,
-        group_size: int, out_dtype=jnp.bfloat16) -> jnp.ndarray:
-    """Row-batched per-expert quant-matmul: x (B, E, M, K), critical
-    (B, E) -> (B, E, M, N). Weights carry no batch dim — they are the
-    same store every row reads; each expert's blob is unpacked exactly
-    once per call, amortized over all B rows. With differing per-row
-    masks an expert generally needs BOTH precisions anyway, so both
-    products are formed and selected per row (under "x/0", ``lo_packed
-    is None``, sub-critical rows take exact zeros and only the high blob
-    is ever read)."""
-    crit = jnp.moveaxis(jnp.asarray(critical).astype(jnp.int32), 1, 0)
-    xt = jnp.moveaxis(x, 1, 0)                            # (E, B, M, K)
-
-    def mm(xe, packed, scales, bits):
-        w = dequantize_tensor(packed, scales, bits, group_size, jnp.float32)
-        return jnp.einsum("bmk,kn->bmn", xe.astype(jnp.float32), w,
-                          preferred_element_type=jnp.float32)
-
-    if lo_packed is None:
-        def one(args):
-            xe, hp, hs, ce = args
-            y_hi = mm(xe, hp, hs, hi_bits)
-            return jnp.where(ce[:, None, None] > 0, y_hi,
-                             jnp.zeros_like(y_hi))
-        xs = (xt, hi_packed, hi_scales, crit)
-    else:
-        def one(args):
-            xe, hp, hs, lp, ls, ce = args
-            y_hi = mm(xe, hp, hs, hi_bits)
-            y_lo = mm(xe, lp, ls, lo_bits)
-            return jnp.where(ce[:, None, None] > 0, y_hi, y_lo)
-        xs = (xt, hi_packed, hi_scales, lo_packed, lo_scales, crit)
-    # fully-unrolled scan, not lax.map: the per-expert blocks are tiny and
-    # independent, and a sequential while loop's per-iteration dispatch
-    # would dominate them (E is small and static on every call site)
-    _, y = jax.lax.scan(lambda c, a: (c, one(a)), None, xs,
-                        unroll=xt.shape[0])
-    return jnp.moveaxis(y, 1, 0).astype(out_dtype)        # (B, E, M, N)
+            return carry, jnp.concatenate(
+                [mm(xe[:cap_hi], hp, hs, hi_bits),
+                 mm(xe[cap_hi:], lp, ls, lo_bits)], axis=0)
+        xs = (x, hi_packed, hi_scales, lo_packed, lo_scales)
+    _, y = jax.lax.scan(one, None, xs, unroll=x.shape[0])
+    return y.astype(out_dtype)

@@ -121,13 +121,6 @@ def build_specs(cfg: ModelConfig, shape: str, mesh,
     if "dymoe_40" in opts:  # the paper's 4/0 policy: skip sub-critical
         cfg = dataclasses.replace(
             cfg, dymoe=dataclasses.replace(cfg.dymoe, low_bits=0))
-    if "local_dispatch" in opts and cfg.is_moe:
-        axes = tuple(a for a in ("pod", "data") if a in mesh.shape)
-        shards = 1
-        for a in axes:
-            shards *= mesh.shape[a]
-        cfg = dataclasses.replace(cfg, moe_dispatch_shards=shards,
-                                  moe_dispatch_axes=axes)
     key = jax.random.PRNGKey(0)
 
     params = jax.eval_shape(lambda k: init_params(cfg, k), key)
@@ -331,7 +324,7 @@ def main() -> None:
     ap.add_argument("--expert-parallel", action="store_true")
     ap.add_argument("--opt", action="append", default=[],
                     choices=["attn_skip", "bf16_attn", "zero1", "seq_acts",
-                             "local_dispatch", "dymoe_40"],
+                             "dymoe_40"],
                     help="perf levers (repeatable); see §Perf hillclimb")
     ap.add_argument("--save-dir", default="experiments/dryrun")
     args = ap.parse_args()

@@ -77,10 +77,6 @@ class ModelConfig:
     attn_compute_dtype: str = "float32"  # qk/pv einsum precision
     act_seq_shard: bool = False      # sequence-shard the residual carry
                                      # (bounds remat-saved activations)
-    moe_dispatch_shards: int = 0     # data-local MoE dispatch: split tokens
-                                     # into this many shards so capacity
-                                     # buffers shard along the data axis
-    moe_dispatch_axes: Tuple[str, ...] = ()  # mesh axes of those shards
     expert_mesh: Any = None          # expert parallelism: a (Abstract)Mesh
                                      # whose "model" axis holds the routed
                                      # experts; the quantized expert FFN

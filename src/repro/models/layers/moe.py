@@ -5,7 +5,7 @@ Dispatch is scatter/gather based (no (T, E, C) one-hot einsum) so the HLO
 FLOP count reflects *active* compute — essential for honest rooflines:
 tokens are routed top-k, assigned a position inside their expert's capacity
 buffer via a cumulative count, scattered to an (E, C, d) buffer, processed by
-vmapped expert FFNs, and gathered back weighted by their gates.
+grouped expert FFNs, and gathered back weighted by their gates.
 
 DyMoE integration (paper §4):
   * ``critical_mask`` (E,) selects per-expert precision at runtime —
@@ -36,8 +36,8 @@ from repro.quant.qtensor import MixedPrecisionWeights
 from repro.sharding.partition import MODEL_AXIS
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_rows",
-           "moe_apply_prefill_rows", "moe_apply_sharded", "quantize_moe",
-           "scales_after_dot", "route", "selection_scores", "MoEStats"]
+           "moe_apply_prefill_rows", "quantize_moe", "scales_after_dot",
+           "route", "selection_scores", "MoEStats"]
 
 
 @jax.tree_util.register_dataclass
@@ -141,9 +141,9 @@ def _capacity(cfg: ModelConfig, t: int) -> int:
             / cfg.num_experts)
     # An expert can receive at most one capacity slot per token, so c > t
     # buys nothing: min(t, ·) OUTSIDE the floor keeps tiny dispatches tiny
-    # (decode: t=1 -> capacity 1, not 8 — 8x less expert compute per row in
-    # the row-vmapped continuous-batching decode) and can never introduce
-    # drops that the old max(8, min(t, c)) floor would have avoided.
+    # (decode: t=1 -> capacity 1, not 8 — 8x less expert compute per row)
+    # and can never introduce drops that the old max(8, min(t, c)) floor
+    # would have avoided.
     return min(t, max(8, c))
 
 
@@ -194,25 +194,6 @@ def _over_local_experts(mesh, f, *args):
     spec = jax.sharding.PartitionSpec(MODEL_AXIS)
     return jax.shard_map(f, mesh=mesh, in_specs=(spec,) * len(args),
                          out_specs=spec, check_vma=False)(*args)
-
-
-def _expert_ffn_fixed(qweights: dict, prec: str, xb: jnp.ndarray,
-                      blocks: Optional[dict] = None) -> jnp.ndarray:
-    """SwiGLU with EVERY expert at one fixed precision (``prec`` ∈
-    {"high", "low"}) — branch-free grouped streaming; the capacity buffer
-    already encodes the per-token precision selection. Shared by both
-    dual-buffer dispatches (decode rows and prefill rows); kept as the
-    bit-parity oracle of the fused single-dispatch path
-    (:func:`_expert_ffn_grouped`)."""
-    from repro.kernels.quant_matmul.ops import expert_quant_matmul_fixed
-
-    def mm(name, h):
-        return expert_quant_matmul_fixed(h, getattr(qweights[name], prec),
-                                         out_dtype=xb.dtype,
-                                         **(blocks or {}))
-
-    h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
-    return mm("w_down", h)
 
 
 def _expert_ffn_grouped(qweights: dict, xb: jnp.ndarray,
@@ -383,7 +364,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                    critical_rows: jnp.ndarray, qweights: dict, *,
                    live: Optional[jnp.ndarray] = None,
                    capacity: Optional[int] = None,
-                   fused: bool = True) -> Tuple[jnp.ndarray, dict]:
+                   ) -> Tuple[jnp.ndarray, dict]:
     """Decode-time MoE where every row carries its OWN Critical mask.
 
     The continuous-batching decode needs per-request precision selection
@@ -413,10 +394,6 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
     never drop a token — buffer memory and the dispatch scatter shrink
     with occupancy.
 
-    ``fused=False`` keeps the original two-dispatch path (one grouped
-    matmul per precision buffer) as the bit-parity oracle the fused path
-    is tested against.
-
     x: (B, dm) one token per row; critical_rows: (B, E) bool.
     Returns (y (B, dm), per-row stats: {"active" (B, E) bool,
     "gate_mean" (B, E), "router_logits" (B, E)}).
@@ -444,7 +421,6 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         return jnp.clip(pos_in_e, 0, c - 1), jnp.minimum(ohs.sum(axis=0), c)
 
     skip_low = qweights["w_gate"].low is None            # "4/0"
-    blocks = _moe_blocks(cfg)
     with jax.named_scope("moe_dispatch"):
         crit_tok = jnp.take_along_axis(critical_rows.astype(bool), idx,
                                        axis=1)
@@ -460,45 +436,28 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         tok = jnp.repeat(jnp.arange(b), k)
         slot_hi, n_hi = place(sel_hi)
         xb_hi = jnp.where(sel_hi[:, None], x[tok], 0)
-    if fused:
-        with jax.named_scope("moe_dispatch"):
-            width = c if skip_low else 2 * c
-            buf = jnp.zeros((e, width, dm), x.dtype).at[
-                flat_e, slot_hi].add(xb_hi.astype(x.dtype), mode="drop")
-            if skip_low:
-                counts = jnp.stack([n_hi, jnp.zeros_like(n_hi)], axis=1)
-            else:
-                slot_lo, n_lo = place(sel_lo)
-                xb_lo = jnp.where(sel_lo[:, None], x[tok], 0)
-                buf = buf.at[flat_e, c + slot_lo].add(
-                    xb_lo.astype(x.dtype), mode="drop")
-                counts = jnp.stack([n_hi, n_lo], axis=1)
-        with jax.named_scope("experts"):
-            yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
-                                     blocks=blocks, mesh=cfg.expert_mesh)
-        with jax.named_scope("moe_combine"):
-            if skip_low:
-                ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi], 0.0)
-            else:
-                ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi],
-                               jnp.where(sel_lo[:, None],
-                                         yb[flat_e, c + slot_lo], 0.0))
-    else:
-        buf_hi = jnp.zeros((e, c, dm), x.dtype).at[flat_e, slot_hi].add(
-            xb_hi.astype(x.dtype), mode="drop")
-        y_hi = _expert_ffn_fixed(qweights, "high", buf_hi, blocks)
+        width = c if skip_low else 2 * c
+        buf = jnp.zeros((e, width, dm), x.dtype).at[
+            flat_e, slot_hi].add(xb_hi.astype(x.dtype), mode="drop")
         if skip_low:
-            ye = jnp.where(sel_hi[:, None], y_hi[flat_e, slot_hi], 0.0)
+            counts = jnp.stack([n_hi, jnp.zeros_like(n_hi)], axis=1)
         else:
-            slot_lo, _ = place(sel_lo)
+            slot_lo, n_lo = place(sel_lo)
             xb_lo = jnp.where(sel_lo[:, None], x[tok], 0)
-            buf_lo = jnp.zeros((e, c, dm), x.dtype).at[
-                flat_e, slot_lo].add(xb_lo.astype(x.dtype), mode="drop")
-            y_lo = _expert_ffn_fixed(qweights, "low", buf_lo, blocks)
-            ye = jnp.where(sel_hi[:, None], y_hi[flat_e, slot_hi],
-                           jnp.where(sel_lo[:, None],
-                                     y_lo[flat_e, slot_lo], 0.0))
+            buf = buf.at[flat_e, c + slot_lo].add(
+                xb_lo.astype(x.dtype), mode="drop")
+            counts = jnp.stack([n_hi, n_lo], axis=1)
+    with jax.named_scope("experts"):
+        yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
+                                 blocks=_moe_blocks(cfg),
+                                 mesh=cfg.expert_mesh)
     with jax.named_scope("moe_combine"):
+        if skip_low:
+            ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi], 0.0)
+        else:
+            ye = jnp.where(sel_hi[:, None], yb[flat_e, slot_hi],
+                           jnp.where(sel_lo[:, None],
+                                     yb[flat_e, c + slot_lo], 0.0))
         ye = ye * gates.reshape(-1, 1).astype(x.dtype)
         y = ye.reshape(b, k, dm).sum(axis=1)
 
@@ -523,7 +482,6 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                            hh_mask: Optional[jnp.ndarray] = None,
                            token_valid: Optional[jnp.ndarray] = None,
                            row_capacities: Optional[jnp.ndarray] = None,
-                           fused: bool = True,
                            ) -> Tuple[jnp.ndarray, dict]:
     """Prefill-shaped MoE where every ROW carries its own Critical mask —
     :func:`moe_apply_rows`' dual-buffer trick at prefill shapes.
@@ -537,12 +495,10 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
     expert, and every expert matmul is a SINGLE fused grouped dispatch
     (``expert_quant_matmul_grouped``) walking both regions in one kernel
     grid, so weights still unpack once per precision stream regardless of
-    how many admissions share the batch and the second dispatch of the
-    old per-precision pair is gone. Per-(expert, region) occupancy
+    how many admissions share the batch. Per-(expert, region) occupancy
     watermarks let the kernel skip slot blocks beyond the highest
     occupied slot — padded tokens of a ragged admission wave cost no
-    FLOPs and no weight I/O. ``fused=False`` keeps the original
-    two-dispatch path as the bit-parity oracle.
+    FLOPs and no weight I/O.
 
     Solo-parity details the scheduler's admission path relies on:
       * capacity is enforced PER ROW at the row's own solo budget
@@ -588,14 +544,6 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         keep = select & (pos_in_e < c_row[row_rep])
         return pos_in_e, keep
 
-    def dispatch(select):
-        pos_in_e, keep = stream_pos(select)
-        slot = row_rep * cmax + jnp.clip(pos_in_e, 0, cmax - 1)
-        xb = jnp.where(keep[:, None], x[tok_of], 0)
-        buf = jnp.zeros((e, b * cmax, dm), x.dtype).at[flat_e, slot].add(
-            xb.astype(x.dtype), mode="drop")
-        return buf, slot, keep
-
     with jax.named_scope("moe_dispatch"):
         flat_e = idx.reshape(-1)                             # (T*k,)
         row_rep = jnp.repeat(jnp.arange(b), s * k)      # (T*k,) token's row
@@ -623,66 +571,48 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         sel_hi = flat_c & valid_rep
         sel_lo = ~flat_c & valid_rep
     skip_low = qweights["w_gate"].low is None            # "4/0"
-    blocks = _moe_blocks(cfg)
-    if fused:
-        cap = b * cmax
+    cap = b * cmax
 
-        def watermark(keep, slot):
-            """Highest occupied slot + 1 per expert — regions are
-            row-local here (not packed from 0), so the watermark, not the
-            occupancy count, bounds the kernel's live blocks."""
-            return jnp.zeros((e,), jnp.int32).at[flat_e].max(
-                jnp.where(keep, slot + 1, 0).astype(jnp.int32),
-                mode="drop")
+    def watermark(keep, slot):
+        """Highest occupied slot + 1 per expert — regions are row-local
+        here (not packed from 0), so the watermark, not the occupancy
+        count, bounds the kernel's live blocks."""
+        return jnp.zeros((e,), jnp.int32).at[flat_e].max(
+            jnp.where(keep, slot + 1, 0).astype(jnp.int32), mode="drop")
 
-        with jax.named_scope("moe_dispatch"):
-            pos_hi, keep_hi = stream_pos(sel_hi)
-            slot_hi = row_rep * cmax + jnp.clip(pos_hi, 0, cmax - 1)
-            xbh = jnp.where(keep_hi[:, None], x[tok_of], 0)
-            width = cap if skip_low else 2 * cap
-            buf = jnp.zeros((e, width, dm), x.dtype).at[
-                flat_e, slot_hi].add(xbh.astype(x.dtype), mode="drop")
-            if skip_low:
-                counts = jnp.stack([watermark(keep_hi, slot_hi),
-                                    jnp.zeros((e,), jnp.int32)], axis=1)
-            else:
-                pos_lo, keep_lo = stream_pos(sel_lo)
-                slot_lo = row_rep * cmax + jnp.clip(pos_lo, 0, cmax - 1)
-                xbl = jnp.where(keep_lo[:, None], x[tok_of], 0)
-                buf = buf.at[flat_e, cap + slot_lo].add(
-                    xbl.astype(x.dtype), mode="drop")
-                counts = jnp.stack([watermark(keep_hi, slot_hi),
-                                    watermark(keep_lo, slot_lo)], axis=1)
-        with jax.named_scope("experts"):
-            y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
-                                        blocks=blocks,
-                                        mesh=cfg.expert_mesh)
-        with jax.named_scope("moe_combine"):
-            if skip_low:
-                ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
-                               0.0)
-            else:
-                ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
-                               jnp.where(keep_lo[:, None],
-                                         y_all[flat_e, cap + slot_lo],
-                                         0.0))
+    with jax.named_scope("moe_dispatch"):
+        pos_hi, keep_hi = stream_pos(sel_hi)
+        slot_hi = row_rep * cmax + jnp.clip(pos_hi, 0, cmax - 1)
+        xbh = jnp.where(keep_hi[:, None], x[tok_of], 0)
+        width = cap if skip_low else 2 * cap
+        buf = jnp.zeros((e, width, dm), x.dtype).at[
+            flat_e, slot_hi].add(xbh.astype(x.dtype), mode="drop")
         if skip_low:
-            with jax.named_scope("router"):
-                # stats only: solo counts these
-                _, keep_lo = stream_pos(sel_lo)
-    else:
-        buf_hi, slot_hi, keep_hi = dispatch(sel_hi)
-        y_hi = _expert_ffn_fixed(qweights, "high", buf_hi, blocks)
-        ye_hi = jnp.where(keep_hi[:, None], y_hi[flat_e, slot_hi], 0.0)
-        if skip_low:
-            ye = ye_hi
-            _, keep_lo = stream_pos(sel_lo)  # stats only
+            counts = jnp.stack([watermark(keep_hi, slot_hi),
+                                jnp.zeros((e,), jnp.int32)], axis=1)
         else:
-            buf_lo, slot_lo, keep_lo = dispatch(sel_lo)
-            y_lo = _expert_ffn_fixed(qweights, "low", buf_lo, blocks)
-            ye = jnp.where(flat_c[:, None], ye_hi,
+            pos_lo, keep_lo = stream_pos(sel_lo)
+            slot_lo = row_rep * cmax + jnp.clip(pos_lo, 0, cmax - 1)
+            xbl = jnp.where(keep_lo[:, None], x[tok_of], 0)
+            buf = buf.at[flat_e, cap + slot_lo].add(
+                xbl.astype(x.dtype), mode="drop")
+            counts = jnp.stack([watermark(keep_hi, slot_hi),
+                                watermark(keep_lo, slot_lo)], axis=1)
+    with jax.named_scope("experts"):
+        y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
+                                    blocks=_moe_blocks(cfg),
+                                    mesh=cfg.expert_mesh)
+    with jax.named_scope("moe_combine"):
+        if skip_low:
+            ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi], 0.0)
+        else:
+            ye = jnp.where(keep_hi[:, None], y_all[flat_e, slot_hi],
                            jnp.where(keep_lo[:, None],
-                                     y_lo[flat_e, slot_lo], 0.0))
+                                     y_all[flat_e, cap + slot_lo], 0.0))
+    if skip_low:
+        with jax.named_scope("router"):
+            # stats only: solo counts these
+            _, keep_lo = stream_pos(sel_lo)
     with jax.named_scope("moe_combine"):
         ye = ye * gates.reshape(-1, 1).astype(x.dtype)
         y = ye.reshape(t, k, dm).sum(axis=1)
@@ -723,55 +653,3 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                      gate_mean=gate_mean, router_logits=logits,
                      aux_loss=aux, dropped_frac=dropped)
     return y, stats
-
-
-def moe_apply_sharded(p, cfg: ModelConfig, x: jnp.ndarray, *,
-                      hh_mask: Optional[jnp.ndarray] = None,
-                      critical_mask: Optional[jnp.ndarray] = None,
-                      qweights: Optional[dict] = None,
-                      token_valid: Optional[jnp.ndarray] = None,
-                      ) -> Tuple[jnp.ndarray, MoEStats]:
-    """Data-local MoE dispatch (§Perf hillclimb A2).
-
-    The plain scatter-based dispatch builds one GLOBAL (E, C, dm) capacity
-    buffer; its token-derived C dim cannot be partitioned by GSPMD, so every
-    model shard chews through global capacity (~data_shards x the useful
-    FLOPs). Here tokens are reshaped to (D, T/D, dm) with D pinned to the
-    data(-and-pod) mesh axes by a sharding constraint, and the whole
-    dispatch-compute-combine runs under vmap — each data shard dispatches
-    only ITS tokens, restoring per-device FLOPs to the active-expert count.
-
-    Falls back to :func:`moe_apply` when ``cfg.moe_dispatch_shards`` <= 1 or
-    does not divide the token count.
-    """
-    d = cfg.moe_dispatch_shards
-    t = x.shape[0]
-    if d <= 1 or t % d != 0:
-        return moe_apply(p, cfg, x, hh_mask=hh_mask,
-                         critical_mask=critical_mask, qweights=qweights,
-                         token_valid=token_valid)
-    xs = x.reshape(d, t // d, -1)
-    if cfg.moe_dispatch_axes:
-        from jax.sharding import PartitionSpec as P
-        u = P.UNCONSTRAINED
-        xs = jax.lax.with_sharding_constraint(
-            xs, P(tuple(cfg.moe_dispatch_axes), u, u))
-    hh = hh_mask.reshape(d, t // d) if hh_mask is not None else None
-    tv = token_valid.reshape(d, t // d) if token_valid is not None else None
-
-    def one(xi, hhi, tvi):
-        return moe_apply(p, cfg, xi, hh_mask=hhi,
-                         critical_mask=critical_mask, qweights=qweights,
-                         token_valid=tvi)
-
-    y, st = jax.vmap(one, in_axes=(0, None if hh is None else 0,
-                                   None if tv is None else 0))(xs, hh, tv)
-    stats = MoEStats(
-        router_logits=st.router_logits.reshape(t, -1),
-        expert_load=st.expert_load.sum(0),
-        expert_hh_load=st.expert_hh_load.sum(0),
-        gate_mean=st.gate_mean.mean(0),
-        aux_loss=st.aux_loss.mean(),
-        dropped_frac=st.dropped_frac.mean(),
-    )
-    return y.reshape(t, -1), stats

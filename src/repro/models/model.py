@@ -53,8 +53,8 @@ from repro.models.layers.attention import attention_decode, attention_train, \
     init_attention
 from repro.models.layers.mla import init_mla, mla_decode, mla_train
 from repro.models.layers.mlp import init_mlp, mlp, mlp_quantized, quantize_mlp
-from repro.models.layers.moe import init_moe, moe_apply_prefill_rows, \
-    moe_apply_rows, moe_apply_sharded, quantize_moe, route, \
+from repro.models.layers.moe import init_moe, moe_apply, \
+    moe_apply_prefill_rows, moe_apply_rows, quantize_moe, route, \
     selection_scores
 from repro.models.layers.norms import init_rmsnorm, rmsnorm
 from repro.models.layers.rotary import sinusoidal_embedding
@@ -400,7 +400,7 @@ def forward(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                 with jax.named_scope("dense_ffn"):
                     x = x + mlp(lp["mlp"], cfg, h)
             else:
-                y, stats = moe_apply_sharded(lp["moe"], cfg, h.reshape(b * s, -1))
+                y, stats = moe_apply(lp["moe"], cfg, h.reshape(b * s, -1))
                 x = x + y.reshape(b, s, -1)
                 aux = aux + stats.aux_loss
         else:
@@ -651,7 +651,7 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                         aux_t, dropped_t = (rstats["aux_loss"],
                                             rstats["dropped_frac"])
                     else:
-                        y, stats = moe_apply_sharded(
+                        y, stats = moe_apply(
                             lp["moe"], cfg, hflat, token_valid=vflat)
                         critical_rows = jnp.ones((b, e), bool)
                         active_rows = load_rows > 0
@@ -664,7 +664,7 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                                 load_rows, 1.0)
                         aux_t, dropped_t = stats.aux_loss, stats.dropped_frac
                 else:
-                    y, stats = moe_apply_sharded(
+                    y, stats = moe_apply(
                         lp["moe"], cfg, hflat, hh_mask=hh,
                         critical_mask=critical,
                         qweights=xs_l["q"]["moe"] if dymoe_on else None,
@@ -896,7 +896,7 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
                         capacity=moe_capacity)
                     active = rstats["active"]
                 elif per_row_moe:
-                    y, stats = moe_apply_sharded(lp["moe"], cfg, hflat)
+                    y, stats = moe_apply(lp["moe"], cfg, hflat)
                     # full precision: rows are independent already; only
                     # the telemetry needs the per-row shape
                     with jax.named_scope("router"):
@@ -907,7 +907,7 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
                         active = oh.sum(axis=1) > 0
                         critical = jnp.ones(active.shape, bool)
                 else:
-                    y, stats = moe_apply_sharded(
+                    y, stats = moe_apply(
                         lp["moe"], cfg, hflat, critical_mask=critical,
                         qweights=xs_l["q"]["moe"] if dymoe_on else None)
                     active = stats.expert_load > 0

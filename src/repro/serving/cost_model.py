@@ -84,25 +84,6 @@ class EdgeCostModel:
             b += cfg.num_shared_experts * expert_bytes(cfg, 16)
         return b
 
-    def dual_dispatch_weight_bytes(self, include_shared: bool = True):
-        """Weight traffic of the PRE-FUSED dual-dispatch path per MoE
-        layer: two separate grouped kernel launches (one per precision
-        buffer), each streaming its ENTIRE packed expert blob — all E
-        experts at high bits plus, when ``low_bits`` is on, all E again
-        at low bits — regardless of which experts hold live rows. The
-        fused single-dispatch kernel's ragged grid reads only blocks
-        with live rows, priced by :meth:`moe_weight_bytes`; the ratio of
-        the two is the modeled traffic win reported by the kernel
-        benchmark's fused-vs-dual rows."""
-        cfg = self.cfg
-        e = cfg.num_experts
-        b = e * expert_bytes(cfg, cfg.dymoe.high_bits)
-        if cfg.dymoe.low_bits:
-            b += e * expert_bytes(cfg, cfg.dymoe.low_bits)
-        if include_shared:
-            b += cfg.num_shared_experts * expert_bytes(cfg, 16)
-        return b
-
     def layer_compute_s(self, *, phase: str, s_ctx, s_q,
                         active_experts_hi=0,
                         active_experts_lo=0,

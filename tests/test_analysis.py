@@ -3,8 +3,8 @@ correct provenance) and stays silent on the healthy path, and the shipped
 edge configs lint clean end-to-end.
 
 The fixtures deliberately commit each forbidden pattern — dense dequant
-materialization inside a layer scan, the dual-dispatch path claiming the
-fused budget, an oversized block override blowing VMEM, a traced f64
+materialization inside a layer scan, one dispatch per precision region
+claiming the fused budget, an oversized block override blowing VMEM, a traced f64
 leak, an XLA-graph packed-code unpack, a host callback, a non-pow2
 live_cap ladder — and assert the structured finding points at it."""
 import dataclasses
@@ -17,7 +17,8 @@ from repro.analysis import count_pallas_calls, iter_eqns
 from repro.analysis.lint import forbidden_shapes_from_qparams, lint_config
 from repro.analysis.rules import LintTarget, RULES, run_rules
 from repro.configs import ANALYSIS_SMOKE_CONFIGS, get_config
-from repro.kernels.quant_matmul.ops import expert_quant_matmul, force_impl
+from repro.kernels.quant_matmul.ops import expert_quant_matmul, \
+    expert_quant_matmul_grouped, force_impl
 from repro.models.config import DyMoEPolicy, ModelConfig
 from repro.models.layers.moe import init_moe, moe_apply_rows, quantize_moe
 from repro.quant import MixedPrecisionWeights, mixed_precision_matmul
@@ -88,31 +89,41 @@ def test_no_dense_dequant_clean_on_packed_path():
 
 
 def test_dispatch_budget_fires_on_dual_path_claiming_fused():
-    """The extra-dispatch fixture: the dual-buffer oracle path launches 6
-    kernels; a target claiming the fused budget (3) must fail with both
-    counts in the message."""
+    """The extra-dispatch fixture: one grouped kernel per precision region
+    for each of the three expert matmuls launches 6 kernels; the MoE
+    budget (3) must fail with both counts in the message, and the
+    row-local layer, both regions in each dispatch, must pass it."""
     cfg = _cfg()
     p = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
     qw = quantize_moe(p, cfg)
-    x = jax.random.normal(jax.random.PRNGKey(1), (8, cfg.d_model),
+    cap = 8
+    x = jax.random.normal(jax.random.PRNGKey(1), (cap, cfg.d_model),
                           jnp.float32)
     crit = jax.random.bernoulli(jax.random.PRNGKey(2), 0.5,
-                                (8, cfg.num_experts))
+                                (cap, cfg.num_experts))
+    xb = jax.random.normal(jax.random.PRNGKey(3),
+                           (cfg.num_experts, 2 * cap, cfg.d_model),
+                           jnp.float32)
 
-    def run(fused):
-        with force_impl("pallas"):
-            return jax.make_jaxpr(
-                lambda xi: moe_apply_rows(p, cfg, xi, crit, qweights=qw,
-                                          fused=fused)[0])(x)
+    def per_region(xb):
+        def mm(name, h):
+            return jnp.concatenate([expert_quant_matmul_grouped(
+                h[:, rows], MixedPrecisionWeights(high=qt, low=None),
+                cap_hi=cap, out_dtype=h.dtype)
+                for qt, rows in ((qw[name].high, slice(0, cap)),
+                                 (qw[name].low, slice(cap, None)))], axis=1)
+        return mm("w_down", jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb))
 
-    dual = run(False)
+    with force_impl("pallas"):
+        dual = jax.make_jaxpr(per_region)(xb)
+        fused = jax.make_jaxpr(
+            lambda xi: moe_apply_rows(p, cfg, xi, crit, qweights=qw)[0])(x)
     assert count_pallas_calls(dual) == 6
-    findings = run_rules(_target(cfg, dual, fused=True),
-                         only=["pallas-dispatch-budget"])
+    findings = run_rules(_target(cfg, dual), only=["pallas-dispatch-budget"])
     assert len(findings) == 1
     assert "6" in findings[0].message and "3" in findings[0].message
 
-    assert not run_rules(_target(cfg, run(True), fused=True),
+    assert not run_rules(_target(cfg, fused),
                          only=["pallas-dispatch-budget"])
 
 

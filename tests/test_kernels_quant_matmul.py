@@ -1,23 +1,39 @@
-"""quant_matmul Pallas kernel vs pure-jnp oracle (interpret mode), swept
-over shapes / bit-widths / block shapes / dtypes."""
+"""Dense quantized matmul vs the pure-jnp dequantize-then-dot oracle,
+swept over shapes / bit-widths / block shapes / dtypes.
+
+Dense quantized layers (the dense MLP, a DeepSeek-V3 dense lead layer,
+the SSM projections) run one packed matrix through
+``mixed_precision_matmul``, which sends it to the mask-select expert
+kernel as a 1-expert group; here that kernel runs in interpret mode."""
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels.quant_matmul.ops import quant_matmul
 from repro.kernels.quant_matmul.ref import quant_matmul_ref
-from repro.quant import QuantizedTensor
+from repro.quant import MixedPrecisionWeights, mixed_precision_matmul
+
+
+def _weights(k, n, bits, group, seed):
+    w = np.random.default_rng(seed).standard_normal((k, n))
+    return MixedPrecisionWeights.build(jnp.asarray(w, jnp.float32), bits,
+                                       None, group)
+
+
+def _pallas(x, mp, bm, bn, bk):
+    return mixed_precision_matmul(x, mp, True, skip_to_zero=False,
+                                  impl="pallas", interpret=True, block_m=bm,
+                                  block_n=bn, block_k=bk,
+                                  out_dtype=jnp.float32)
 
 
 def _case(m, k, n, bits, group, bm, bn, bk, seed=0, dtype=jnp.float32):
     rng = np.random.default_rng(seed)
     x = jnp.asarray(rng.standard_normal((m, k)), dtype)
-    w = jnp.asarray(rng.standard_normal((k, n)), jnp.float32)
-    qt = QuantizedTensor.quantize(w, bits, group)
+    mp = _weights(k, n, bits, group, seed + 1)
+    qt = mp.high
     ref = quant_matmul_ref(x, qt.packed, qt.scales, bits=bits,
                            group_size=group, out_dtype=jnp.float32)
-    pal = quant_matmul(x, qt, impl="pallas", interpret=True, block_m=bm,
-                       block_n=bn, block_k=bk, out_dtype=jnp.float32)
+    pal = _pallas(x, mp, bm, bn, bk)
     np.testing.assert_allclose(np.asarray(ref), np.asarray(pal),
                                atol=5e-4, rtol=1e-4)
 
@@ -42,11 +58,11 @@ def test_block_sweep(bm, bn, bk):
 def test_dtype_sweep(dtype):
     rng = np.random.default_rng(4)
     x = jnp.asarray(rng.standard_normal((8, 64)), dtype)
-    w = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
-    qt = QuantizedTensor.quantize(w, 4, 32)
-    ref = quant_matmul(x, qt, impl="ref", out_dtype=jnp.float32)
-    pal = quant_matmul(x, qt, impl="pallas", interpret=True, block_m=8,
-                       block_n=16, block_k=32, out_dtype=jnp.float32)
+    mp = _weights(64, 16, 4, 32, 5)
+    qt = mp.high
+    ref = quant_matmul_ref(x, qt.packed, qt.scales, bits=4, group_size=32,
+                           out_dtype=jnp.float32)
+    pal = _pallas(x, mp, 8, 16, 32)
     np.testing.assert_allclose(np.asarray(ref, np.float32),
                                np.asarray(pal, np.float32),
                                atol=2e-2, rtol=2e-2)
@@ -60,7 +76,11 @@ def test_multi_k_blocks_accumulate():
 def test_leading_dims_reshape():
     rng = np.random.default_rng(5)
     x = jnp.asarray(rng.standard_normal((2, 3, 64)), jnp.float32)
-    w = jnp.asarray(rng.standard_normal((64, 16)), jnp.float32)
-    qt = QuantizedTensor.quantize(w, 4, 32)
-    y = quant_matmul(x, qt, impl="ref", out_dtype=jnp.float32)
+    mp = _weights(64, 16, 4, 32, 6)
+    qt = mp.high
+    y = _pallas(x, mp, 8, 16, 32)
     assert y.shape == (2, 3, 16)
+    ref = quant_matmul_ref(x.reshape(6, 64), qt.packed, qt.scales, bits=4,
+                           group_size=32, out_dtype=jnp.float32)
+    np.testing.assert_allclose(np.asarray(y).reshape(6, 16),
+                               np.asarray(ref), atol=5e-4, rtol=1e-4)

@@ -1,16 +1,14 @@
 """§Perf hillclimb levers must preserve semantics exactly (or within dtype
-tolerance): causal chunk skipping, bf16 attention, data-local MoE dispatch,
-scan vs unrolled layer stacks."""
+tolerance): causal chunk skipping, bf16 attention, scan vs unrolled layer
+stacks."""
 import dataclasses
 
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.models import init_params, prefill, decode_step, loss_fn
 from repro.models.config import ModelConfig
-from repro.models.layers.moe import init_moe, moe_apply, moe_apply_sharded
 
 
 def _dense_cfg(**kw):
@@ -74,31 +72,3 @@ def test_unrolled_decode_consistency(dense_setup):
     dec, _, _ = decode_step(params, c, toks[:, 31], caches)
     # compare decode-after-31 against full prefill of 32
     assert np.abs(np.asarray(full) - np.asarray(dec)).max() < 2e-3
-
-
-def test_local_dispatch_matches_plain():
-    cfg = ModelConfig(
-        name="m", arch_type="moe", num_layers=1, d_model=64, vocab_size=64,
-        num_heads=4, num_kv_heads=2, head_dim=16, num_experts=8,
-        num_experts_per_tok=2, moe_d_ff=64, capacity_factor=8.0,
-        dtype="float32")
-    p = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (64, 64))
-    y0, s0 = moe_apply(p, cfg, x)
-    c4 = dataclasses.replace(cfg, moe_dispatch_shards=4)
-    y1, s1 = moe_apply_sharded(p, c4, x)
-    np.testing.assert_allclose(np.asarray(y0), np.asarray(y1), atol=1e-5)
-    np.testing.assert_array_equal(np.asarray(s0.expert_load),
-                                  np.asarray(s1.expert_load))
-
-
-def test_local_dispatch_nondivisible_falls_back():
-    cfg = ModelConfig(
-        name="m", arch_type="moe", num_layers=1, d_model=64, vocab_size=64,
-        num_heads=4, num_kv_heads=2, head_dim=16, num_experts=8,
-        num_experts_per_tok=2, moe_d_ff=64, capacity_factor=8.0,
-        dtype="float32", moe_dispatch_shards=7)
-    p = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
-    x = jax.random.normal(jax.random.PRNGKey(1), (64, 64))
-    y, _ = moe_apply_sharded(p, cfg, x)  # 64 % 7 != 0 -> plain path
-    assert y.shape == (64, 64)
