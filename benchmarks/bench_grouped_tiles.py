@@ -6,12 +6,15 @@ share, at the decode shapes of the served configurations.
 Each call is one expert matmul of a decode step: E experts, a 4-bit and a
 2-bit region of ``rows`` rows each, the 2-bit region dead (as at decode),
 and the 4-bit region live in none, in a cell's share, or in all of the
-experts. The N tile is forced to each of 128, 256, 512 and N that divide
-N, so the time of a dead grid step and the extra time of a live one can
-be read apart: a dead step's cost from the calls with nothing live, a
-live step's from the difference to the calls with everything live. It
-also checks that every tile's output equals the 128-column tile's, bit
-for bit. Prints one JSON object per call and exits non-zero without a TPU.
+experts. The kernel reads one layer in place from a stack of ``LAYERS``,
+at the K the stores are kept at (``expert_store_k``: Qwen3's and
+Moonlight's ``w_down`` padded once to 1024 and 1536). The N tile is
+forced to each of 128, 256, 512 and N that divide N, so the time of a
+dead grid step and the extra time of a live one can be read apart: a
+dead step's cost from the calls with nothing live, a live step's from
+the difference to the calls with everything live. It also checks that
+every tile's output equals the 128-column tile's, bit for bit. Prints
+one JSON object per call and exits non-zero without a TPU.
 """
 from __future__ import annotations
 
@@ -28,8 +31,10 @@ import numpy as np
 
 eqm = importlib.import_module(
     "repro.kernels.quant_matmul.expert_quant_matmul")
+store_k = importlib.import_module("repro.models.layers.moe").expert_store_k
 
 GROUP = 64
+LAYERS = 4
 # (config, experts, rows a region, experts live at a decode step, (K, N)s)
 SHAPES = (
     ("olmoe-1b-7b", 64, 16, 55, ((2048, 1024), (1024, 2048))),
@@ -43,11 +48,12 @@ def tiles(n: int):
 
 
 def operands(rng, e, rows, k, n):
+    """x, then the stacked 4-bit and 2-bit codes and scales."""
     def store(bits):
-        return (jnp.asarray(rng.integers(0, 256, (e, n, k * bits // 8),
-                                         dtype=np.uint8)),
-                jnp.asarray(rng.uniform(0.01, 0.02, (e, k // GROUP, n)),
-                            jnp.float32))
+        return (jnp.asarray(rng.integers(
+                    0, 256, (LAYERS, e, n, k * bits // 8), dtype=np.uint8)),
+                jnp.asarray(rng.uniform(
+                    0.01, 0.02, (LAYERS, e, k // GROUP, n)), jnp.float32))
     x = jnp.asarray(rng.standard_normal((e, 2 * rows, k)), jnp.bfloat16)
     return (x, *store(4), *store(2))
 
@@ -87,6 +93,7 @@ def main(argv=None) -> int:
     ok = True
     for cfg, e, rows, live_cell, shapes in SHAPES:
         for k, n in shapes:
+            k = store_k(k, GROUP)
             ops = operands(rng, e, rows, k, n)
             lives = {0: counts_for(rng, e, rows, 0),
                      live_cell: counts_for(rng, e, rows, live_cell),
@@ -103,7 +110,8 @@ def main(argv=None) -> int:
                     dtype=jnp.bfloat16, n_block=bn)
                 steps = int(np.prod(t.grid))
                 for live, c in lives.items():
-                    sec, out = timed(f, (*ops[:5], c), args.reps)
+                    sec, out = timed(f, (*ops[:5], c, jnp.int32(1)),
+                                     args.reps)
                     if bn == 128:
                         base[live] = out
                     same = bool(np.array_equal(out, base[live]))

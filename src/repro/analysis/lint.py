@@ -224,6 +224,18 @@ def _trace_decode_chunk_ep(cfg, params, qparams):
          None, None, None, None))
 
 
+def _stacked_layers(cfg: ModelConfig, phase: str, qparams
+                    ) -> Optional[int]:
+    """L of the quantized layer stack where the phase's MoE layers run
+    the grouped expert kernel (the per-row admission and decode paths),
+    which reads the whole stack in place; None elsewhere (the solo
+    prefill's mask-select kernel, dense and SSM projections, take one
+    layer's slice)."""
+    if cfg.stack_kind != "attn_moe" or phase.startswith("prefill"):
+        return None
+    return jax.tree.leaves(qparams["layers"])[0].shape[0]
+
+
 def build_targets(name: str, cfg: ModelConfig, *,
                   mixes: Sequence[str] = ("mixed", "4/0"),
                   ) -> List[LintTarget]:
@@ -261,7 +273,8 @@ def build_targets(name: str, cfg: ModelConfig, *,
                 continue
             targets.append(LintTarget(
                 name=tname, cfg=mcfg, phase=phase, jaxpr=jaxpr,
-                forbidden_shapes=forbidden))
+                forbidden_shapes=forbidden,
+                stacked_layers=_stacked_layers(mcfg, phase, qparams)))
     targets.append(LintTarget(
         name=f"{name}/scheduler/retrace", cfg=cfg, phase="retrace",
         slots=_DECODE_B, ladder=live_cap_for))

@@ -12,7 +12,9 @@ Rule catalog (see the package docstring for the full invariant contract):
 
   no-dense-dequant      no float intermediate at dense dequantized weight
                         scale anywhere outside kernel bodies
-  pallas-dispatch-budget  exact ``pallas_call`` count per layer-scan body
+  pallas-dispatch-budget  exact ``pallas_call`` count per layer-scan body;
+                        the grouped expert kernels read the stacked
+                        packed stores in place
   vmem-footprint        every pallas_call's estimated VMEM working set
                         fits the per-backend budget
   dtype-discipline      no f64 avals; no packed-code upcast outside
@@ -80,6 +82,11 @@ class LintTarget:
     forbidden_shapes: Optional[frozenset] = None
     # pallas-dispatch-budget: exact expected count. None = derive.
     expected_dispatches: Optional[int] = None
+    # pallas-dispatch-budget: the layers of the quantized stack, where the
+    # program's MoE layers run the grouped expert kernel (the per-row
+    # admission and decode paths), which must read the whole stack in
+    # place. None: no kernel is held to that.
+    stacked_layers: Optional[int] = None
     # vmem-footprint budget override (bytes). None = per-backend table.
     vmem_budget: Optional[int] = None
     # dtype-discipline: packed operands at/above this byte size must not
@@ -225,7 +232,12 @@ def check_no_dense_dequant(target: LintTarget) -> List[Finding]:
 @rule("pallas-dispatch-budget")
 def check_pallas_dispatch_budget(target: LintTarget) -> List[Finding]:
     """Exactly the budgeted number of fused kernel dispatches per
-    layer-scan body — one per expert matmul (3)."""
+    layer-scan body — one per expert matmul (3) — and, where the MoE
+    layers run the grouped expert kernel (``stacked_layers`` set), each
+    kernel reads the packed stores of the whole layer stack in place:
+    every packed (uint8) operand leads with the stack's L layers, none is
+    one layer's slice (the scan closes over the stores and carries the
+    layer index, which the kernel takes as scalar prefetch)."""
     if target.jaxpr is None:
         return []
     expected = target.expected_dispatches
@@ -233,14 +245,28 @@ def check_pallas_dispatch_budget(target: LintTarget) -> List[Finding]:
         expected = expected_dispatch_count(target.cfg, phase=target.phase)
     sites = [s for s in iter_eqns(target.jaxpr, into_kernels=False)
              if s.eqn.primitive.name == "pallas_call"]
-    if len(sites) == expected:
-        return []
-    where = sorted({s.provenance() for s in sites})
-    return [_finding(
-        target, "pallas-dispatch-budget",
-        f"{len(sites)} pallas_call dispatches per layer body, expected "
-        f"exactly {expected} (sites: {where})",
-        sites[0] if sites else None)]
+    out: List[Finding] = []
+    if len(sites) != expected:
+        where = sorted({s.provenance() for s in sites})
+        out.append(_finding(
+            target, "pallas-dispatch-budget",
+            f"{len(sites)} pallas_call dispatches per layer body, expected "
+            f"exactly {expected} (sites: {where})",
+            sites[0] if sites else None))
+    if target.stacked_layers is None:
+        return out
+    for site in sites:
+        for v in site.eqn.invars:
+            aval = v.aval
+            if getattr(aval, "dtype", None) not in PACKED_DTYPES:
+                continue
+            if aval.ndim != 4 or aval.shape[0] != target.stacked_layers:
+                out.append(_finding(
+                    target, "pallas-dispatch-budget",
+                    f"packed operand {aval.shape} is not the stack of "
+                    f"{target.stacked_layers} layers: a per-layer copy of "
+                    "the store feeds the kernel", site, aval))
+    return out
 
 
 @rule("vmem-footprint")

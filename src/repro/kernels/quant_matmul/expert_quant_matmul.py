@@ -43,7 +43,10 @@ decode most steps are dead, and on a v5e chip a step's fixed cost
 1,024 columns of a dead step).
 A dead step, and the precision buffer a group does not read, repeat the
 block that buffer already holds (:func:`grouped_index_maps`), so only live
-blocks move over HBM.
+blocks move over HBM. Its weights are the whole layer stack,
+``(L, E, ...)``, and the layer rides in the scalar prefetch beside the
+watermarks: a layer scan hands the kernel the resident stores and the
+layer index, so no layer's store is sliced out or padded before a call.
 """
 from __future__ import annotations
 
@@ -222,10 +225,10 @@ def _grouped_dual_kernel(nb_ref, x_ref, hp_ref, hs_ref, lp_ref, ls_ref,
     def _compute():
         acc_ref[...] += jax.lax.cond(
             g % 2 == 0,
-            lambda: _grouped_tile(x_ref[0], hp_ref[0], hs_ref[0], hi_bits,
-                                  group_size, scale_after_dot),
-            lambda: _grouped_tile(x_ref[0], lp_ref[0], ls_ref[0], lo_bits,
-                                  group_size, scale_after_dot))
+            lambda: _grouped_tile(x_ref[0], hp_ref[0, 0], hs_ref[0, 0],
+                                  hi_bits, group_size, scale_after_dot),
+            lambda: _grouped_tile(x_ref[0], lp_ref[0, 0], ls_ref[0, 0],
+                                  lo_bits, group_size, scale_after_dot))
 
     @pl.when(kk == nk - 1)
     def _done():
@@ -244,7 +247,7 @@ def _grouped_skip_kernel(nb_ref, x_ref, hp_ref, hs_ref, o_ref, acc_ref, *,
 
     @pl.when(i < nb_ref[g])
     def _compute():
-        acc_ref[...] += _grouped_tile(x_ref[0], hp_ref[0], hs_ref[0],
+        acc_ref[...] += _grouped_tile(x_ref[0], hp_ref[0, 0], hs_ref[0, 0],
                                       hi_bits, group_size, scale_after_dot)
 
     @pl.when(kk == nk - 1)
@@ -262,8 +265,13 @@ def _row_block(block_m: int, rows: int, *dtypes) -> int:
 
 
 def _k_block(block_k: int, k: int, group_size: int) -> int:
-    """Contraction tile: at most ``block_k``, a whole number of groups."""
-    return max(group_size, (min(block_k, k) // group_size) * group_size)
+    """Contraction tile: at most ``block_k``, a whole number of groups, or
+    K itself where that tile does not divide K: the grouped kernel reads
+    its stores in place, unpadded, and a block may span an array's whole
+    extent. (The MoE layer stores every K that 512 does not divide padded
+    once to one it does: ``models.layers.moe.expert_store_k``.)"""
+    bk = max(group_size, (min(block_k, k) // group_size) * group_size)
+    return bk if k % bk == 0 else k
 
 
 def grouped_scales_after_dot(cap: int, k: int, *, group_size: int,
@@ -419,11 +427,11 @@ def _vmem_bytes(bm: int, bn: int, bk: int, hi_bits: int, lo_bits: int,
 
 def _n_block(n: int, fits) -> int:
     """N itself, else the widest multiple of 128 that divides N, for which
-    ``fits(bn)``; 128 (or N, where N is narrower) if none does."""
-    for bn in [n] + list(range(n - n % 128, 127, -128)):
-        if n % bn == 0 and fits(bn):
-            return bn
-    return min(128, n)
+    ``fits(bn)``; the narrowest such tile if none fits. Every tile divides
+    N, so the stores are read unpadded."""
+    tiles = [bn for bn in [n] + list(range(n - n % 128, 127, -128))
+             if n % bn == 0]
+    return next((bn for bn in tiles if fits(bn)), tiles[-1])
 
 
 class GroupedTiling(NamedTuple):
@@ -454,7 +462,9 @@ def grouped_tiling(e: int, cap_hi: int, cap_lo: int, n: int, k: int, *,
     decode call's steps are mostly dead, so fewer steps is the gain; K is
     tiled as before, so every output sums the same partial products in the
     same order. The dequantize-first body (128-row blocks) keeps 128
-    columns. ``n_block`` forces the N tile (measurements and tests)."""
+    columns. Both tiles divide their extent (:func:`_k_block`,
+    :func:`_n_block`), so the kernel reads the stores unpadded, in place.
+    ``n_block`` forces the N tile (measurements and tests)."""
     p_ = 2 if cap_lo else 1
     bm = _row_block(block_m, max(cap_hi, cap_lo), dtype, out_dtype)
     bk = _k_block(block_k, k, group_size)
@@ -470,19 +480,21 @@ def grouped_tiling(e: int, cap_hi: int, cap_lo: int, n: int, k: int, *,
     elif after:
         bn = _n_block(n, lambda c: need(c) <= _VMEM_BUDGET)
     else:
-        bn = min(128, n)
-    grid = (e * p_, -(-max(cap_hi, cap_lo) // bm), -(-n // bn), -(-k // bk))
+        bn = _n_block(n, lambda c: c <= 128)
+    assert n % bn == 0 and k % bk == 0, (n, bn, k, bk)
+    grid = (e * p_, -(-max(cap_hi, cap_lo) // bm), n // bn, k // bk)
     # as much again for the body's temporaries and the compiler's scratch
     return GroupedTiling(bm, bn, bk, grid, after,
                          max(_VMEM_BUDGET, 2 * need(bn)))
 
 
 def grouped_tables(counts: jnp.ndarray, cap_hi: int, cap_lo: int,
-                   bm: int) -> jnp.ndarray:
-    """The grouped kernel's scalar-prefetch table, four (E * P,) int32
-    rows laid end to end: each group's live row blocks ``nb``, then, for
-    the x blocks, the hi and the lo weight blocks, the last group at or
-    before each group that reads that buffer (-1 where none does)."""
+                   bm: int, layer) -> jnp.ndarray:
+    """The grouped kernel's scalar-prefetch table: four (E * P,) int32
+    rows laid end to end, then the layer its weights are read from. The
+    rows: each group's live row blocks ``nb``, then, for the x blocks, the
+    hi and the lo weight blocks, the last group at or before each group
+    that reads that buffer (-1 where none does)."""
     p_ = 2 if cap_lo else 1
     caps = jnp.asarray((cap_hi, cap_lo)[:p_], jnp.int32)
     wm = jnp.clip(jnp.asarray(counts, jnp.int32)[:, :p_], 0, caps[None, :])
@@ -494,20 +506,25 @@ def grouped_tables(counts: jnp.ndarray, cap_hi: int, cap_lo: int,
 
     live = nb > 0
     return jnp.concatenate([nb, last(live), last(live & (g % p_ == 0)),
-                            last(live & (g % p_ == 1))])
+                            last(live & (g % p_ == 1)),
+                            jnp.reshape(jnp.asarray(layer, jnp.int32),
+                                        (1,))])
 
 
 def grouped_index_maps(grid: Tuple[int, int, int, int], p_: int):
     """Block index maps of the grouped kernel over ``grid``, with ``p_``
     precision groups per expert, reading the table of
     :func:`grouped_tables`: x, hi codes, hi scales, lo codes, lo scales
-    and the output, each ``f(g, i, j, kk, t)``.
+    and the output, each ``f(g, i, j, kk, t)``. The weight maps lead with
+    the table's layer: the stores are the whole stack, ``(L, E, ...)``,
+    read in place.
 
     A step that does not read a buffer (a dead row block, or the precision
     its group does not use) maps it to the block it already holds: the
     last block of the last group before it that read it, or block 0 where
-    none has. The pipeline elides the copy of a block index that repeats,
-    so only live blocks move, plus each buffer's first block."""
+    none has, at the same layer. The pipeline elides the copy of a block
+    index that repeats, so only live blocks move, plus each buffer's first
+    block."""
     groups, nb_cap, nj, nk = grid
 
     def pick(use, live, held):
@@ -527,13 +544,14 @@ def grouped_index_maps(grid: Tuple[int, int, int, int], p_: int):
         def codes(g, i, j, kk, t):
             q, on = last(t, 2 + region, g)
             use = (g % p_ == region) & (i < t[g])
-            return pick(use, (g // p_, j, kk),
-                        (q // p_, jnp.where(on, nj - 1, 0),
-                         jnp.where(on, nk - 1, 0)))
+            return (t[4 * groups],) + pick(
+                use, (g // p_, j, kk),
+                (q // p_, jnp.where(on, nj - 1, 0),
+                 jnp.where(on, nk - 1, 0)))
 
         def scales(g, i, j, kk, t):
-            e_, j_, k_ = codes(g, i, j, kk, t)
-            return e_, k_, j_
+            l_, e_, j_, k_ = codes(g, i, j, kk, t)
+            return l_, e_, k_, j_
         return codes, scales
 
     def out_map(g, i, j, kk, t):
@@ -542,22 +560,23 @@ def grouped_index_maps(grid: Tuple[int, int, int, int], p_: int):
     return (x_map, *weights(0), *weights(1), out_map)
 
 
-def _grouped_pallas(x, hi_packed, hi_scales, lo_packed, lo_scales, counts, *,
-                    cap_hi, hi_bits, lo_bits, group_size, block_m, block_k,
-                    interpret, out_dtype, n_block=None):
+def _grouped_pallas(x, hi_packed, hi_scales, lo_packed, lo_scales, counts,
+                    layer, *, cap_hi, hi_bits, lo_bits, group_size, block_m,
+                    block_k, interpret, out_dtype, n_block=None):
     e, m, k = x.shape
-    n = hi_packed.shape[1]
+    n_layers, _, n, _ = hi_packed.shape
     vpb_hi = 8 // hi_bits
-    assert hi_packed.shape == (e, n, k // vpb_hi), (hi_packed.shape, e, n, k)
-    assert hi_scales.shape == (e, k // group_size, n)
+    assert hi_packed.shape == (n_layers, e, n, k // vpb_hi), \
+        (hi_packed.shape, e, n, k)
+    assert hi_scales.shape == (n_layers, e, k // group_size, n)
     has_lo = lo_packed is not None
     cap_lo = m - cap_hi
     assert 0 < cap_hi <= m, (cap_hi, m)
     assert has_lo == (cap_lo > 0), (cap_hi, m, has_lo)
     if has_lo:
         vpb_lo = 8 // lo_bits
-        assert lo_packed.shape == (e, n, k // vpb_lo)
-        assert lo_scales.shape == (e, k // group_size, n)
+        assert lo_packed.shape == (n_layers, e, n, k // vpb_lo)
+        assert lo_scales.shape == (n_layers, e, k // group_size, n)
     assert k % group_size == 0, (k, group_size)
     p_ = 2 if has_lo else 1
 
@@ -576,36 +595,31 @@ def _grouped_pallas(x, hi_packed, hi_scales, lo_packed, lo_scales, counts, *,
     def region(lo_, hi_, bits):
         r = x[:, lo_:hi_]
         pad = rows - r.shape[1]
-        r = _pad_to(jnp.pad(r, ((0, 0), (0, pad), (0, 0))) if pad else r,
-                    2, bk)
+        r = jnp.pad(r, ((0, 0), (0, pad), (0, 0))) if pad else r
         return _plane_major(r, bk, bits) if after else r
 
     xp = region(0, cap_hi, hi_bits)
     if has_lo:
         xp = jnp.concatenate([xp, region(cap_hi, m, lo_bits)], axis=1)
-    hp = _pad_to(_pad_to(hi_packed, 1, bn), 2, bk // vpb_hi)
-    hs = _pad_to(_pad_to(hi_scales, 1, bk // group_size), 2, bn)
-    if has_lo:
-        lp = _pad_to(_pad_to(lo_packed, 1, bn), 2, bk // vpb_lo)
-        ls = _pad_to(_pad_to(lo_scales, 1, bk // group_size), 2, bn)
-    np_ = nj * bn
 
-    table = grouped_tables(counts, cap_hi, cap_lo, bm)
+    table = grouped_tables(counts, cap_hi, cap_lo, bm, layer)
     x_map, hi_map, hi_s_map, lo_map, lo_s_map, out_map = \
         grouped_index_maps(grid, p_)
 
+    # the weights are the whole (L, E, ...) stack, read in place: a block
+    # is one layer's and one expert's tile
     in_specs = [
         pl.BlockSpec((1, bm, bk), x_map),
-        pl.BlockSpec((1, bn, bk // vpb_hi), hi_map),
-        pl.BlockSpec((1, bk // group_size, bn), hi_s_map),
+        pl.BlockSpec((1, 1, bn, bk // vpb_hi), hi_map),
+        pl.BlockSpec((1, 1, bk // group_size, bn), hi_s_map),
     ]
-    operands = [xp, hp, hs]
+    operands = [xp, hi_packed, hi_scales]
     if has_lo:
         in_specs += [
-            pl.BlockSpec((1, bn, bk // vpb_lo), lo_map),
-            pl.BlockSpec((1, bk // group_size, bn), lo_s_map),
+            pl.BlockSpec((1, 1, bn, bk // vpb_lo), lo_map),
+            pl.BlockSpec((1, 1, bk // group_size, bn), lo_s_map),
         ]
-        operands += [lp, ls]
+        operands += [lo_packed, lo_scales]
         kernel = functools.partial(_grouped_dual_kernel, hi_bits=hi_bits,
                                    lo_bits=lo_bits, group_size=group_size,
                                    nk=nk, scale_after_dot=after)
@@ -624,15 +638,15 @@ def _grouped_pallas(x, hi_packed, hi_scales, lo_packed, lo_scales, counts, *,
     out = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((e, p_ * rows, np_), out_dtype),
+        out_shape=jax.ShapeDtypeStruct((e, p_ * rows, n), out_dtype),
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=tiling.vmem_limit_bytes),
         interpret=interpret,
     )(table, *operands)
     if has_lo:
         return jnp.concatenate(
-            [out[:, :cap_hi, :n], out[:, rows:rows + cap_lo, :n]], axis=1)
-    return out[:, :m, :n]
+            [out[:, :cap_hi], out[:, rows:rows + cap_lo]], axis=1)
+    return out[:, :m]
 
 
 @functools.partial(
@@ -643,11 +657,19 @@ def _grouped_pallas(x, hi_packed, hi_scales, lo_packed, lo_scales, counts, *,
 def expert_quant_matmul_grouped_pallas(
         x: jnp.ndarray, hi_packed: jnp.ndarray, hi_scales: jnp.ndarray,
         lo_packed: Optional[jnp.ndarray], lo_scales: Optional[jnp.ndarray],
-        counts: jnp.ndarray, *, cap_hi: int, hi_bits: int, lo_bits: int,
-        group_size: int, block_m: int = 128, block_k: int = 512,
-        interpret: bool = False, out_dtype=jnp.bfloat16) -> jnp.ndarray:
+        counts: jnp.ndarray, layer, *, cap_hi: int, hi_bits: int,
+        lo_bits: int, group_size: int, block_m: int = 128,
+        block_k: int = 512, interpret: bool = False,
+        out_dtype=jnp.bfloat16) -> jnp.ndarray:
     """ONE dispatch over a combined dual-precision capacity buffer with a
-    live-row ragged grid.
+    live-row ragged grid, reading layer ``layer`` of a layer-stacked store
+    in place.
+
+    The stores are the whole stack: ``hi_packed`` (L, E, N, K / vpb),
+    ``hi_scales`` (L, E, K / gs, N), the low twins alike. ``layer`` (an
+    int32 scalar, traced) rides in the scalar prefetch and leads every
+    weight block index, so no layer's store is sliced out, and none is
+    padded, before the call.
 
     ``x`` (E, M, K) packs BOTH precision regions of the dual-buffer per-row
     MoE dispatch per expert: high-precision slots occupy ``[0, cap_hi)``
@@ -671,7 +693,7 @@ def expert_quant_matmul_grouped_pallas(
     Returns (E, M, N) in ``out_dtype``, region layout matching ``x``.
     """
     return _grouped_pallas(
-        x, hi_packed, hi_scales, lo_packed, lo_scales, counts, cap_hi=cap_hi,
-        hi_bits=hi_bits, lo_bits=lo_bits, group_size=group_size,
-        block_m=block_m, block_k=block_k, interpret=interpret,
-        out_dtype=out_dtype)
+        x, hi_packed, hi_scales, lo_packed, lo_scales, counts, layer,
+        cap_hi=cap_hi, hi_bits=hi_bits, lo_bits=lo_bits,
+        group_size=group_size, block_m=block_m, block_k=block_k,
+        interpret=interpret, out_dtype=out_dtype)

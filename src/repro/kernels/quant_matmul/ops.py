@@ -77,7 +77,8 @@ def _low_store(weights: MixedPrecisionWeights):
 def expert_quant_matmul_grouped(x: jnp.ndarray,
                                 weights: MixedPrecisionWeights,
                                 counts: Optional[jnp.ndarray] = None, *,
-                                cap_hi: int, impl: Optional[str] = None,
+                                cap_hi: int, layer=None,
+                                impl: Optional[str] = None,
                                 interpret: bool = False,
                                 block_m: int = 128, block_k: int = 512,
                                 out_dtype=jnp.bfloat16) -> jnp.ndarray:
@@ -95,32 +96,40 @@ def expert_quant_matmul_grouped(x: jnp.ndarray,
     (``cap_hi == M``) and the lo precision group is elided at grid
     construction.
 
+    ``layer`` (an int32 scalar, may be traced): ``weights`` is the whole
+    layer stack, leaves (L, E, ...), and the kernel reads layer ``layer``
+    of it in place. None: ``weights`` is one layer's store, leaves
+    (E, ...), which the kernel reads as a stack of one.
+
     The jnp oracle ignores ``counts``: dead slots are zero-filled by the
     dispatch, so their dot is exact zero and the oracle's output is
     bitwise the watermark-pruned kernel's. Returns (E, M, N).
     """
     impl = _resolve_impl(impl)
+    if impl not in ("pallas", "ref"):
+        raise ValueError(f"unknown impl {impl!r}")
+    if impl == "pallas" and layer is None:
+        weights, layer = jax.tree.map(lambda a: a[None], weights), 0
+    elif impl == "ref" and layer is not None:
+        weights = jax.tree.map(lambda a: a[layer], weights)
     hi = weights.high
     lp, ls, lo_bits = _low_store(weights)
     e, m, _ = x.shape
     assert (lp is None) == (cap_hi == m), (cap_hi, m, lp is None)
-    if impl == "pallas":
-        if counts is None:
-            counts = jnp.stack(
-                [jnp.full((e,), cap_hi, jnp.int32),
-                 jnp.full((e,), m - cap_hi, jnp.int32)], axis=1)
-        return expert_quant_matmul_grouped_pallas(
-            x, hi.packed, hi.scales, lp, ls,
-            jnp.asarray(counts, jnp.int32), cap_hi=cap_hi,
-            hi_bits=hi.bits, lo_bits=lo_bits, group_size=hi.group_size,
-            block_m=block_m, block_k=block_k, interpret=interpret,
-            out_dtype=out_dtype)
     if impl == "ref":
         return expert_quant_matmul_grouped_ref(
             x, hi.packed, hi.scales, lp, ls, cap_hi=cap_hi,
             hi_bits=hi.bits, lo_bits=lo_bits, group_size=hi.group_size,
             out_dtype=out_dtype)
-    raise ValueError(f"unknown impl {impl!r}")
+    if counts is None:
+        counts = jnp.stack(
+            [jnp.full((e,), cap_hi, jnp.int32),
+             jnp.full((e,), m - cap_hi, jnp.int32)], axis=1)
+    return expert_quant_matmul_grouped_pallas(
+        x, hi.packed, hi.scales, lp, ls, jnp.asarray(counts, jnp.int32),
+        jnp.asarray(layer, jnp.int32), cap_hi=cap_hi, hi_bits=hi.bits,
+        lo_bits=lo_bits, group_size=hi.group_size, block_m=block_m,
+        block_k=block_k, interpret=interpret, out_dtype=out_dtype)
 
 
 def expert_quant_matmul(x: jnp.ndarray, weights: MixedPrecisionWeights,

@@ -25,6 +25,7 @@ selected by every token), so they run in high precision unconditionally.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
@@ -36,8 +37,8 @@ from repro.quant.qtensor import MixedPrecisionWeights
 from repro.sharding.partition import MODEL_AXIS
 
 __all__ = ["init_moe", "moe_apply", "moe_apply_rows",
-           "moe_apply_prefill_rows", "quantize_moe", "scales_after_dot",
-           "route", "selection_scores", "MoEStats"]
+           "moe_apply_prefill_rows", "quantize_moe", "expert_store_k",
+           "scales_after_dot", "route", "selection_scores", "MoEStats"]
 
 
 @jax.tree_util.register_dataclass
@@ -117,17 +118,44 @@ def route(p, cfg: ModelConfig, logits: jnp.ndarray
     return aff, gates, idx
 
 
+def expert_store_k(k: int, group_size: int) -> int:
+    """K of a routed expert's packed store: K rounded up to a multiple of
+    512 values and of 8 groups, where K is longer than that (shorter K
+    are one tile as they are). Then the 2-bit codes fill whole 128-lane
+    rows and the f32 scales whole 8-row tiles, so a TPU lays the stacked
+    stores out row-major, as the expert kernels read them in place, and
+    the kernels' 512-value K tile divides K: no per-call pad and no
+    relayout copy. Qwen3-30B-A3B's ``w_down`` (K 768) is stored at 1024,
+    Moonlight-16B-A3B's (1408) at 1536; OLMoE-1B-7B's keep their K."""
+    align = math.lcm(512, 8 * group_size)
+    return k if k <= align else -(-k // align) * align
+
+
+def _to_store_k(h: jnp.ndarray, qw: MixedPrecisionWeights) -> jnp.ndarray:
+    """Activations (..., K) zero-padded to the K of the store ``qw``
+    (:func:`expert_store_k`): the added columns meet the store's zero
+    rows."""
+    pad = qw.high.k - h.shape[-1]
+    return jnp.pad(h, [(0, 0)] * (h.ndim - 1) + [(0, pad)]) if pad else h
+
+
 def quantize_moe(p, cfg: ModelConfig) -> dict:
     """Mixed-precision variants of the routed expert weights (paper §5:
     quantization focuses exclusively on expert layers). Router and shared
-    experts stay in working precision."""
+    experts stay in working precision. Each weight's K is padded once,
+    here, with zero rows to :func:`expert_store_k`: a zero group
+    quantizes to zero codes of scale 0, so the rows add nothing."""
     pol = cfg.dymoe
     low = pol.low_bits or None
-    return {
-        name: MixedPrecisionWeights.build(p[name], pol.high_bits, low,
-                                          pol.group_size)
-        for name in ("w_gate", "w_up", "w_down")
-    }
+
+    def store(w):
+        k = w.shape[-2]
+        pad = [(0, 0)] * (w.ndim - 2) + [
+            (0, expert_store_k(k, pol.group_size) - k), (0, 0)]
+        return MixedPrecisionWeights.build(jnp.pad(w, pad), pol.high_bits,
+                                           low, pol.group_size)
+
+    return {name: store(p[name]) for name in ("w_gate", "w_up", "w_down")}
 
 
 def _capacity(cfg: ModelConfig, t: int) -> int:
@@ -174,13 +202,16 @@ def scales_after_dot(cfg: ModelConfig, capacity: int) -> bool:
 
     pol = cfg.dymoe
     return all(grouped_scales_after_dot(
-        capacity, k, group_size=pol.group_size, block_m=pol.block_m,
+        capacity, expert_store_k(k, pol.group_size),
+        group_size=pol.group_size, block_m=pol.block_m,
         block_k=pol.block_k, dtype=cfg.dtype)
         for k in (cfg.d_model, cfg.expert_d_ff))
 
 
-def _over_local_experts(mesh, f, *args):
-    """``f(*args)`` where every leaf of ``args`` has a leading expert dim E.
+def _over_local_experts(mesh, f, *args, expert_dims=None):
+    """``f(*args)`` where every leaf of ``args[i]`` has its expert dim E at
+    ``expert_dims[i]`` (default: a leading E on every arg; None: an arg
+    every device takes whole, such as a layer index).
 
     With an expert-parallel ``mesh`` (``ModelConfig.expert_mesh``), ``f``
     runs under ``shard_map`` over its ``"model"`` axis: each device applies
@@ -191,13 +222,15 @@ def _over_local_experts(mesh, f, *args):
     activations cross chips. With ``mesh=None`` ``f`` runs as is."""
     if mesh is None:
         return f(*args)
-    spec = jax.sharding.PartitionSpec(MODEL_AXIS)
-    return jax.shard_map(f, mesh=mesh, in_specs=(spec,) * len(args),
-                         out_specs=spec, check_vma=False)(*args)
+    P = jax.sharding.PartitionSpec
+    specs = tuple(P() if d is None else P(*(None,) * d, MODEL_AXIS)
+                  for d in (expert_dims or (0,) * len(args)))
+    return jax.shard_map(f, mesh=mesh, in_specs=specs,
+                         out_specs=P(MODEL_AXIS), check_vma=False)(*args)
 
 
 def _expert_ffn_grouped(qweights: dict, xb: jnp.ndarray,
-                        counts: jnp.ndarray, *, cap_hi: int,
+                        counts: jnp.ndarray, *, cap_hi: int, layer=None,
                         blocks: Optional[dict] = None,
                         mesh=None) -> jnp.ndarray:
     """SwiGLU over ONE combined dual-precision capacity buffer: each
@@ -206,23 +239,29 @@ def _expert_ffn_grouped(qweights: dict, xb: jnp.ndarray,
     instead of one dispatch per precision — and ``counts`` (E, 2)
     live-slot watermarks let the kernel skip dead row blocks outright
     (finished/evicted/padded slots cost no FLOPs and no weight I/O).
-    The kernel takes its N tile from the shapes, so of ``blocks`` only
-    ``block_m`` and ``block_k`` reach it. ``mesh``: see
+    ``layer``: ``qweights`` is the whole layer stack (leaves (L, E, ...))
+    and the kernels read that layer of it in place; None: one layer's
+    store. The kernel takes its N tile from the shapes, so of ``blocks``
+    only ``block_m`` and ``block_k`` reach it. ``mesh``: see
     :func:`_over_local_experts`."""
     from repro.kernels.quant_matmul.ops import expert_quant_matmul_grouped
 
     tiles = {k: blocks[k] for k in ("block_m", "block_k")} if blocks else {}
+    if layer is None:      # one layer's store: a stack of one
+        qweights, layer = jax.tree.map(lambda a: a[None], qweights), 0
 
-    def ffn(qw, xb, counts):
+    def ffn(qw, xb, counts, layer):
         def mm(name, h):
-            return expert_quant_matmul_grouped(h, qw[name], counts,
-                                               cap_hi=cap_hi,
-                                               out_dtype=xb.dtype, **tiles)
+            return expert_quant_matmul_grouped(
+                _to_store_k(h, qw[name]), qw[name], counts, cap_hi=cap_hi,
+                layer=layer, out_dtype=xb.dtype, **tiles)
 
         h = jax.nn.silu(mm("w_gate", xb)) * mm("w_up", xb)
         return mm("w_down", h)
 
-    return _over_local_experts(mesh, ffn, qweights, xb, counts)
+    return _over_local_experts(mesh, ffn, qweights, xb, counts,
+                               jnp.asarray(layer, jnp.int32),
+                               expert_dims=(1, 0, 0, None))
 
 
 def _shared_experts(p, x: jnp.ndarray) -> jnp.ndarray:
@@ -245,7 +284,8 @@ def _expert_ffn_quantized(qw: dict, critical: jnp.ndarray, xb: jnp.ndarray,
     :func:`_over_local_experts`."""
     def ffn(qw, critical, xb):
         def mm(name, h):
-            return mixed_precision_matmul(h, qw[name], critical,
+            return mixed_precision_matmul(_to_store_k(h, qw[name]),
+                                          qw[name], critical,
                                           skip_to_zero=True,
                                           out_dtype=xb.dtype,
                                           **(blocks or {}))
@@ -362,7 +402,7 @@ def moe_apply(p, cfg: ModelConfig, x: jnp.ndarray, *,
 
 def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                    critical_rows: jnp.ndarray, qweights: dict, *,
-                   live: Optional[jnp.ndarray] = None,
+                   layer=None, live: Optional[jnp.ndarray] = None,
                    capacity: Optional[int] = None,
                    ) -> Tuple[jnp.ndarray, dict]:
     """Decode-time MoE where every row carries its OWN Critical mask.
@@ -395,6 +435,8 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
     with occupancy.
 
     x: (B, dm) one token per row; critical_rows: (B, E) bool.
+    ``layer``: ``qweights`` is the whole layer stack, read in place at
+    that layer (:func:`_expert_ffn_grouped`); None: one layer's store.
     Returns (y (B, dm), per-row stats: {"active" (B, E) bool,
     "gate_mean" (B, E), "router_logits" (B, E)}).
     """
@@ -449,7 +491,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
             counts = jnp.stack([n_hi, n_lo], axis=1)
     with jax.named_scope("experts"):
         yb = _expert_ffn_grouped(qweights, buf, counts, cap_hi=c,
-                                 blocks=_moe_blocks(cfg),
+                                 layer=layer, blocks=_moe_blocks(cfg),
                                  mesh=cfg.expert_mesh)
     with jax.named_scope("moe_combine"):
         if skip_low:
@@ -478,7 +520,7 @@ def moe_apply_rows(p, cfg: ModelConfig, x: jnp.ndarray,
 
 def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                            critical_rows: jnp.ndarray, qweights: dict, *,
-                           rows: int,
+                           rows: int, layer=None,
                            hh_mask: Optional[jnp.ndarray] = None,
                            token_valid: Optional[jnp.ndarray] = None,
                            row_capacities: Optional[jnp.ndarray] = None,
@@ -512,7 +554,8 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
         sub-critical experts.
 
     x: (T, dm) tokens flattened from (rows, S) row-major; critical_rows:
-    (rows, E) bool; hh_mask/token_valid: (T,). ``row_capacities`` (rows,)
+    (rows, E) bool; hh_mask/token_valid: (T,). ``layer``: as in
+    :func:`moe_apply_rows`. ``row_capacities`` (rows,)
     overrides the in-graph capacity computation with host-computed
     ``_capacity(cfg, len_i)`` values — the in-graph fallback runs the
     formula in f32, whose truncation can differ from the host's f64 by
@@ -600,7 +643,7 @@ def moe_apply_prefill_rows(p, cfg: ModelConfig, x: jnp.ndarray,
                                 watermark(keep_lo, slot_lo)], axis=1)
     with jax.named_scope("experts"):
         y_all = _expert_ffn_grouped(qweights, buf, counts, cap_hi=cap,
-                                    blocks=_moe_blocks(cfg),
+                                    layer=layer, blocks=_moe_blocks(cfg),
                                     mesh=cfg.expert_mesh)
     with jax.named_scope("moe_combine"):
         if skip_low:

@@ -82,6 +82,21 @@ def _index_tree(tree, i):
     return _tmap(lambda x: x[i], tree)
 
 
+def _layer_index(qparams) -> jnp.ndarray:
+    """(L,) int32 layer indices of the quantized layer stack, scanned
+    beside the layers in place of the stack itself."""
+    n = jax.tree.leaves(qparams["layers"])[0].shape[0]
+    return jnp.arange(n, dtype=jnp.int32)
+
+
+def _layer_store(qparams, xs_l) -> dict:
+    """The scanned layer's slice of the quantized stack, for the kernels
+    that take one layer's store (the mask-select expert kernel, the dense
+    and SSM projections); the grouped expert kernel reads the stack in
+    place at ``xs_l["layer"]``."""
+    return _index_tree(qparams["layers"], xs_l["layer"])
+
+
 def _scan_blocks(cfg: ModelConfig, body, carry0, xs):
     """lax.scan over the layer stack, or an unrolled Python loop when
     ``cfg.scan_layers`` is False (used by the dry-run to recover per-layer
@@ -91,7 +106,10 @@ def _scan_blocks(cfg: ModelConfig, body, carry0, xs):
     body (``attention``, ``router``, ``moe_dispatch``, ``experts``,
     ``moe_combine``) nest inside it, and the scan's own bookkeeping (the
     per-layer slices of ``xs``, the stacking of ``ys``) reads as
-    ``layers`` with no inner scope."""
+    ``layers`` with no inner scope. The quantized stores are not in
+    ``xs``: the body closes over the whole stack and ``xs`` carries the
+    layer index (:func:`_layer_index`), so the grouped expert kernel
+    reads its layer in place instead of from a per-layer copy."""
     with jax.named_scope("layers"):
         if cfg.scan_layers:
             return jax.lax.scan(body, carry0, xs)
@@ -531,7 +549,7 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
 
     xs: Dict[str, Any] = {"block": params["layers"]}
     if dymoe_on:
-        xs["q"] = qparams["layers"]
+        xs["layer"] = _layer_index(qparams)
         xs["tier"] = _layer_tier_flags(cfg)
         if kind == "attn_moe":
             xs["t_l"] = _t_l_array(cfg)
@@ -584,9 +602,10 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
             if kind == "attn_dense":
                 with jax.named_scope("dense_ffn"):
                     h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
-                    if dymoe_on and "q" in xs_l:
-                        y = mlp_quantized(xs_l["q"]["mlp"], cfg, h,
-                                          xs_l["tier"])
+                    if dymoe_on and "layer" in xs_l:
+                        y = mlp_quantized(
+                            _layer_store(qparams, xs_l)["mlp"], cfg, h,
+                            xs_l["tier"])
                     else:
                         y = mlp(lp["mlp"], cfg, h)
                     x = x + y
@@ -642,7 +661,8 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                     if dymoe_on:
                         y, rstats = moe_apply_prefill_rows(
                             lp["moe"], cfg, hflat, critical_rows,
-                            xs_l["q"]["moe"], rows=b, hh_mask=hh,
+                            qparams["layers"]["moe"], rows=b,
+                            layer=xs_l["layer"], hh_mask=hh,
                             token_valid=vflat,
                             row_capacities=row_capacities)
                         active_rows = rstats["active"]
@@ -667,7 +687,8 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
                     y, stats = moe_apply(
                         lp["moe"], cfg, hflat, hh_mask=hh,
                         critical_mask=critical,
-                        qweights=xs_l["q"]["moe"] if dymoe_on else None,
+                        qweights=(_layer_store(qparams, xs_l)["moe"]
+                                  if dymoe_on else None),
                         token_valid=vflat)
                 with jax.named_scope("moe_combine"):
                     x = x + y.reshape(b, s, -1)
@@ -714,7 +735,8 @@ def prefill(params, cfg: ModelConfig, tokens: Optional[jnp.ndarray] = None,
             h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
             sp = lp["ssm"]
             if dymoe_on:
-                sp = _q_ssm(sp, xs_l["q"]["ssm"], xs_l["tier"])
+                sp = _q_ssm(sp, _layer_store(qparams, xs_l)["ssm"],
+                            xs_l["tier"])
             y, cache = mamba_prefill(sp, cfg, h, init_ssm_cache(cfg, b, dt))
             x = x + y
 
@@ -820,7 +842,7 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
     xs: Dict[str, Any] = {"block": params["layers"],
                           "cache": caches["layers"]}
     if dymoe_on:
-        xs["q"] = qparams["layers"]
+        xs["layer"] = _layer_index(qparams)
         xs["tier"] = _layer_tier_flags(cfg)
     if kind == "attn_moe":
         xs["t_l"] = _t_l_array(cfg)
@@ -864,9 +886,10 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
             if kind == "attn_dense":
                 with jax.named_scope("dense_ffn"):
                     h = rmsnorm(lp["norm2"], x, cfg.norm_eps)
-                    if dymoe_on and "q" in xs_l:
-                        y = mlp_quantized(xs_l["q"]["mlp"], cfg, h,
-                                          xs_l["tier"])
+                    if dymoe_on and "layer" in xs_l:
+                        y = mlp_quantized(
+                            _layer_store(qparams, xs_l)["mlp"], cfg, h,
+                            xs_l["tier"])
                     else:
                         y = mlp(lp["mlp"], cfg, h)
                     x = x + y
@@ -892,7 +915,8 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
                 if per_row_moe and dymoe_on:
                     y, rstats = moe_apply_rows(
                         lp["moe"], cfg, hflat, critical,
-                        qweights=xs_l["q"]["moe"], live=live_rows,
+                        qweights=qparams["layers"]["moe"],
+                        layer=xs_l["layer"], live=live_rows,
                         capacity=moe_capacity)
                     active = rstats["active"]
                 elif per_row_moe:
@@ -909,7 +933,8 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
                 else:
                     y, stats = moe_apply(
                         lp["moe"], cfg, hflat, critical_mask=critical,
-                        qweights=xs_l["q"]["moe"] if dymoe_on else None)
+                        qweights=(_layer_store(qparams, xs_l)["moe"]
+                                  if dymoe_on else None))
                     active = stats.expert_load > 0
                     if critical is None:
                         critical = jnp.ones((e,), bool)
@@ -930,7 +955,8 @@ def decode_step(params, cfg: ModelConfig, tokens: jnp.ndarray,
             h = rmsnorm(lp["norm1"], x, cfg.norm_eps)
             sp = lp["ssm"]
             if dymoe_on:
-                sp = _q_ssm(sp, xs_l["q"]["ssm"], xs_l["tier"])
+                sp = _q_ssm(sp, _layer_store(qparams, xs_l)["ssm"],
+                            xs_l["tier"])
             y, cache = mamba_decode(sp, cfg, h, cache)
             x = x + y
 

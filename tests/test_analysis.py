@@ -127,6 +127,44 @@ def test_dispatch_budget_fires_on_dual_path_claiming_fused():
                          only=["pallas-dispatch-budget"])
 
 
+def test_dispatch_budget_fires_on_per_layer_store_copy():
+    """The grouped kernels must read the stacked store in place: a layer
+    scan that takes each layer's store through ``xs`` (a per-layer copy)
+    fires the rule at every packed operand, naming the stack's depth; the
+    scan that closes over the stack and carries the layer index passes.
+    Without ``stacked_layers`` (the solo prefill) a slice is allowed."""
+    cfg = _cfg()
+    layers = 2
+    p = init_moe(cfg, jax.random.PRNGKey(0), jnp.float32)
+    qw = jax.tree.map(lambda *a: jnp.stack(a),
+                      *[quantize_moe(p, cfg)] * layers)
+    x = jax.random.normal(jax.random.PRNGKey(1), (8, cfg.d_model),
+                          jnp.float32)
+    crit = jax.random.bernoulli(jax.random.PRNGKey(2), 0.5,
+                                (8, cfg.num_experts))
+
+    def sliced(x):
+        def body(h, q):
+            return moe_apply_rows(p, cfg, h, crit, qweights=q)[0], None
+        return jax.lax.scan(body, x, qw)[0]
+
+    def in_place(x):
+        def body(h, layer):
+            return moe_apply_rows(p, cfg, h, crit, qweights=qw,
+                                  layer=layer)[0], None
+        return jax.lax.scan(body, x, jnp.arange(layers))[0]
+
+    with force_impl("pallas"):
+        bad, good = (jax.make_jaxpr(f)(x) for f in (sliced, in_place))
+    findings = run_rules(_target(cfg, bad, stacked_layers=layers),
+                         only=["pallas-dispatch-budget"])
+    assert len(findings) == 6, findings      # 2 packed operands x 3 calls
+    assert all("stack of 2 layers" in f.message for f in findings)
+    assert not run_rules(_target(cfg, good, stacked_layers=layers),
+                         only=["pallas-dispatch-budget"])
+    assert not run_rules(_target(cfg, bad), only=["pallas-dispatch-budget"])
+
+
 # ------------------------------------------------------------------ vmem
 
 

@@ -12,7 +12,8 @@ from repro.analysis.rules import FLOAT_DTYPES
 from repro.kernels.quant_matmul.expert_quant_matmul import \
     _scale_after_dot, expert_quant_matmul_grouped_pallas, \
     grouped_scales_after_dot, grouped_tiling
-from repro.kernels.quant_matmul.ops import expert_quant_matmul, force_impl
+from repro.kernels.quant_matmul.ops import expert_quant_matmul, \
+    expert_quant_matmul_grouped, force_impl
 from repro.kernels.quant_matmul.ref import expert_quant_matmul_grouped_ref
 from repro.models.config import DyMoEPolicy, ModelConfig
 from repro.models.layers.moe import init_moe, moe_apply, quantize_moe
@@ -98,10 +99,12 @@ def test_dense_one_expert_path():
 
 # ------------------------------------- grouped kernel, scales after dot
 # Decode shapes of OLMoE-1B-7B (w_gate/w_up K=2048 N=1024, w_down K=1024
-# N=2048), Qwen3-30B-A3B (N=768; w_down K=768, padded to two K tiles) and
-# Moonlight-16B-A3B (N=1408 = 11 x 128; w_down K=1408, padded to three K
-# tiles) at 16 rows a region, group 64, bk 512. The shape-chosen N tile is
-# the whole width N there; the 128-column tile is the one it replaced.
+# N=2048), Qwen3-30B-A3B (N=768; w_down K=768, one K tile: 512 does not
+# divide it) and Moonlight-16B-A3B (N=1408 = 11 x 128; w_down K=1408, one
+# K tile) at 16 rows a region, group 64, bk 512; the MoE layer keeps those
+# two stores padded to 1024 and 1536 (``expert_store_k``), but the kernel
+# takes any K. The shape-chosen N tile is the whole width N there; the
+# 128-column tile is the one it replaced.
 
 _SERVED_KN = [(2048, 1024), (1024, 2048), (2048, 768), (768, 2048),
               (2048, 1408), (1408, 2048)]
@@ -117,13 +120,14 @@ def _grouped_call(x, mp, counts, cap, lo, n_block=None):
     from repro.kernels.quant_matmul.expert_quant_matmul import \
         _grouped_pallas
 
+    mp = jax.tree.map(lambda a: a[None], mp)      # a stack of one layer
     lp, ls = (None, None) if lo is None else (mp.low.packed, mp.low.scales)
     f = jax.jit(lambda *a: _grouped_pallas(
         *a, cap_hi=cap, hi_bits=4, lo_bits=lo or 0, group_size=64,
         block_m=32, block_k=512, interpret=True, out_dtype=jnp.float32,
         n_block=n_block))
     return np.asarray(f(x, mp.high.packed, mp.high.scales, lp, ls,
-                        jnp.asarray(counts)))
+                        jnp.asarray(counts), jnp.int32(0)))
 
 
 @pytest.mark.parametrize("pattern", sorted(_WATERMARKS))
@@ -134,7 +138,9 @@ def test_grouped_scale_after_dot_matches_oracle(k, n, lo, pattern):
     per-group partial sums match the dequantize-first f32 oracle up to f32
     summation order. The whole-width N tile the wrapper chooses gives the
     128-column tile's output bit for bit: K is tiled alike, so every
-    output sums the same partial products in the same order."""
+    output sums the same partial products in the same order. Where 512
+    does not divide K (``w_down`` of Qwen3 and Moonlight), K is one tile,
+    read unpadded."""
     cap, gs = 16, 64
     counts = np.array(_WATERMARKS[pattern], np.int32)
     e = counts.shape[0]
@@ -158,10 +164,9 @@ def test_grouped_scale_after_dot_matches_oracle(k, n, lo, pattern):
     ref = np.asarray(expert_quant_matmul_grouped_ref(
         x, mp.high.packed, mp.high.scales, lp, ls, cap_hi=cap, hi_bits=4,
         lo_bits=lo or 0, group_size=gs, out_dtype=jnp.float32))
-    pal = np.asarray(expert_quant_matmul_grouped_pallas(
-        x, mp.high.packed, mp.high.scales, lp, ls, jnp.asarray(counts),
-        cap_hi=cap, hi_bits=4, lo_bits=lo or 0, group_size=gs, block_m=32,
-        block_k=512, interpret=True, out_dtype=jnp.float32))
+    pal = np.asarray(expert_quant_matmul_grouped(
+        x, mp, jnp.asarray(counts), cap_hi=cap, impl="pallas",
+        interpret=True, block_m=32, block_k=512, out_dtype=jnp.float32))
     np.testing.assert_array_equal(
         pal, _grouped_call(x, mp, counts, cap, lo, n_block=128))
     scale = np.abs(ref).max()
@@ -170,8 +175,62 @@ def test_grouped_scale_after_dot_matches_oracle(k, n, lo, pattern):
         assert not pal[ei, c_hi:cap].any() and not pal[ei, cap + c_lo:].any()
 
 
+# w_down's K of each served configuration, scaled down by 4 with the K
+# tile (512 -> 128): OLMoE's 1024 keeps two tiles; Qwen3's 768 and
+# Moonlight's 1408 (192, 352) are Ks the tile does not divide: one tile
+_LAYER_KS = {"olmoe": 256, "qwen3": 192, "moonlight": 352}
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("lo", [2, None], ids=["4/2", "4/0"])
+@pytest.mark.parametrize("shape", sorted(_LAYER_KS))
+def test_grouped_reads_layer_of_stack_in_place(shape, lo, dtype):
+    """Given the whole (L, E, ...) store and a layer index, the grouped
+    kernel equals its call on that layer's slice bit for bit, at every
+    layer of a 3-layer stack, with dead and partly live groups in both
+    regions, in the body that scales after the dot (bf16) and the one
+    that dequantizes first (f32); and it reads that layer's weights (the
+    oracle on the slice agrees)."""
+    k, n, e, cap, gs, layers = _LAYER_KS[shape], 64, 4, 8, 32, 3
+    rng = np.random.default_rng(k)
+    w = jnp.asarray(rng.standard_normal((layers, e, k, n)) / np.sqrt(k),
+                    jnp.float32)
+    mp = MixedPrecisionWeights.build(w, 4, lo, gs)
+    counts = np.array([[cap, 0], [0, 5], [3, cap], [0, 0]], np.int32)
+    m = cap if lo is None else 2 * cap
+    if lo is None:
+        counts[:, 1] = 0
+    x = rng.standard_normal((e, m, k)).astype(np.float32)
+    for ei, (c_hi, c_lo) in enumerate(counts):   # zero beyond watermarks
+        x[ei, c_hi:cap] = 0.0
+        x[ei, cap + c_lo:] = 0.0
+    x, counts = jnp.asarray(x, dtype), jnp.asarray(counts)
+    tiling = grouped_tiling(e, cap, 0 if lo is None else cap, n, k,
+                            hi_bits=4, lo_bits=lo or 0, group_size=gs,
+                            block_m=16, block_k=128, dtype=dtype)
+    assert tiling.bk == (128 if shape == "olmoe" else k), tiling
+    assert tiling.scale_after_dot == (dtype == jnp.bfloat16), tiling
+    kw = dict(cap_hi=cap, impl="pallas", interpret=True, block_m=16,
+              block_k=128, out_dtype=jnp.float32)
+    stacked_call = jax.jit(lambda x, w, c, l: expert_quant_matmul_grouped(
+        x, w, c, layer=l, **kw))
+    for layer in range(layers):
+        one = jax.tree.map(lambda a: a[layer], mp)
+        got = np.asarray(stacked_call(x, mp, counts, jnp.int32(layer)))
+        np.testing.assert_array_equal(
+            got, np.asarray(expert_quant_matmul_grouped(x, one, counts,
+                                                        **kw)))
+        ref = np.asarray(expert_quant_matmul_grouped(
+            x, one, cap_hi=cap, impl="ref", out_dtype=jnp.float32))
+        np.testing.assert_allclose(got, ref, rtol=1e-5,
+                                   atol=1e-5 * np.abs(ref).max())
+
+
 # grid steps a call of w_gate, w_up and w_down at decode (16 rows a region,
-# 8 for Moonlight), and the MoE layers of each cell's depth
+# 8 for Moonlight), and the MoE layers of each cell's depth; w_down of
+# Qwen3 (K 768) and Moonlight (K 1408) is stored at K 1024 and 1536
+# (``expert_store_k``), two and three K tiles
 _DECODE_STEPS = {"olmoe_1b_7b": ((512, 512, 256), 16, 16),
                  "qwen3_30b_a3b": ((1024, 1024, 512), 16, 16),
                  "moonlight_16b_a3b": ((512, 512, 384), 8, 15)}
@@ -184,14 +243,16 @@ _LARGEST_WAVE = {"olmoe_1b_7b": (16, 256), "qwen3_30b_a3b": (16, 256),
 @pytest.mark.parametrize("name", sorted(_DECODE_STEPS))
 def test_grouped_tile_and_grid_by_config(name, phase):
     """The N tile is chosen from the shapes: N itself or a multiple of 128
-    that divides N. The grid the wrapper builds is the one
-    :func:`grouped_tiling` reports, at decode the table of steps a call
-    (OLMoE 20,480 a decode step of 16 layers, from 196,608 at 128
-    columns), and the call passes the vmem-footprint lint."""
+    that divides N; the K tile is ``block_k``, which divides the K every
+    store is kept at. The grid the wrapper builds over a layer-stacked
+    store is the one :func:`grouped_tiling` reports, at decode the table
+    of steps a call (OLMoE 20,480 a decode step of 16 layers, from
+    196,608 at 128 columns), and the call passes the vmem-footprint
+    lint."""
     from repro.analysis import estimate_pallas_vmem, iter_eqns
     from repro.analysis.rules import LintTarget, run_rules
     from repro.configs import get_config
-    from repro.models.layers.moe import _capacity
+    from repro.models.layers.moe import _capacity, expert_store_k
 
     cfg = get_config(name)
     pol, e = cfg.dymoe, cfg.num_experts
@@ -202,21 +263,23 @@ def test_grouped_tile_and_grid_by_config(name, phase):
     dm, dff = cfg.d_model, cfg.expert_d_ff
     got = []
     for k, n in ((dm, dff), (dm, dff), (dff, dm)):
+        k = expert_store_k(k, pol.group_size)
         t = grouped_tiling(e, rows, rows, n, k, hi_bits=pol.high_bits,
                            lo_bits=pol.low_bits, group_size=pol.group_size,
                            block_m=pol.block_m, block_k=pol.block_k,
                            dtype=cfg.dtype)
         assert t.bn == n or (t.bn % 128 == 0 and n % t.bn == 0), t
         assert t.bn == n or not t.scale_after_dot, t
+        assert t.bk == pol.block_k and k % t.bk == 0, t
         got.append(int(np.prod(t.grid)))
 
         sds = jax.ShapeDtypeStruct
         args = (sds((e, 2 * rows, k), jnp.bfloat16),
-                sds((e, n, k * pol.high_bits // 8), jnp.uint8),
-                sds((e, k // pol.group_size, n), jnp.float32),
-                sds((e, n, k * pol.low_bits // 8), jnp.uint8),
-                sds((e, k // pol.group_size, n), jnp.float32),
-                sds((e, 2), jnp.int32))
+                sds((layers, e, n, k * pol.high_bits // 8), jnp.uint8),
+                sds((layers, e, k // pol.group_size, n), jnp.float32),
+                sds((layers, e, n, k * pol.low_bits // 8), jnp.uint8),
+                sds((layers, e, k // pol.group_size, n), jnp.float32),
+                sds((e, 2), jnp.int32), sds((), jnp.int32))
         jaxpr = jax.make_jaxpr(
             lambda *a: expert_quant_matmul_grouped_pallas(
                 *a, cap_hi=rows, hi_bits=pol.high_bits,
@@ -254,7 +317,8 @@ def test_grouped_dead_steps_fetch_nothing(case):
     the blocks of live steps, each once, plus at most one first block.
     "decode": one row block, one N tile, four K tiles, no 2-bit group
     live, live and dead hi groups mixed; "wave": row blocks beyond a
-    group's watermark and several N and K tiles."""
+    group's watermark and several N and K tiles. Every weight block is
+    read at the table's layer, live or held."""
     from repro.kernels.quant_matmul.expert_quant_matmul import \
         grouped_index_maps, grouped_tables
 
@@ -269,7 +333,8 @@ def test_grouped_dead_steps_fetch_nothing(case):
         counts[1] = 0
     nb_cap = -(-cap // bm)
     grid = (2 * e, nb_cap, nj, nk)
-    table = grouped_tables(jnp.asarray(counts), cap, cap, bm)
+    layer = 2
+    table = grouped_tables(jnp.asarray(counts), cap, cap, bm, layer)
     x_map, hc, hs, lc, ls, _ = grouped_index_maps(grid, 2)
     x_f, hc_f, hs_f, lc_f, ls_f = _fetches((x_map, hc, hs, lc, ls), grid,
                                            table)
@@ -279,7 +344,8 @@ def test_grouped_dead_steps_fetch_nothing(case):
         for g, i, j, kk in np.ndindex(*grid):
             nb = -(-int(counts[g // 2, g % 2]) // bm)
             if g % 2 == region and i < nb:
-                blk = (g // 2, j, kk) if order == "jk" else (g // 2, kk, j)
+                blk = ((layer, g // 2, j, kk) if order == "jk"
+                       else (layer, g // 2, kk, j))
                 if not out or out[-1] != blk:
                     out.append(blk)
         return out
@@ -292,7 +358,7 @@ def test_grouped_dead_steps_fetch_nothing(case):
     live_x = sum(-(-int(c) // bm) for c in counts.reshape(-1)) * nj * nk
     assert len(x_f) <= live_x + 1
     if case == "decode":
-        assert lc_f == [(0, 0, 0)] and ls_f == [(0, 0, 0)]
+        assert lc_f == [(layer, 0, 0, 0)] and ls_f == [(layer, 0, 0, 0)]
         assert len(hc_f) <= 9 * nk + 1
 
 
